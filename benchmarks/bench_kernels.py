@@ -99,6 +99,9 @@ def run(csv_rows: list) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     rows = []
     run(rows)
     for r in rows:

@@ -42,6 +42,7 @@ from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
 from repro.data import synthetic
 from repro.serve import BatchPolicy, ServingEngine
+from repro.launch.cache import use_compile_cache
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
 
@@ -231,6 +232,7 @@ if __name__ == "__main__":
                     help="toy training sizes — the ci/run_tests.sh --bench "
                          "tier (the committed reference scale)")
     args = ap.parse_args()
+    use_compile_cache()
 
     scale = 0.125 if args.smoke else 1.0
     for case, task, strategy, h, knob in TASK_CASES:

@@ -50,6 +50,8 @@ from repro.core.kernelfn import KernelSpec
 from repro.core.multiclass import MulticlassHSSSVMTrainer
 from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_data_mesh
 
 PRESETS = {
     "crude": CompressionParams.crude(),        # rtol 1e-2, cap 32
@@ -179,7 +181,7 @@ def run_sharded(csv_rows: list, scale: float = 1.0) -> None:
     cases = [("local", None)]
     if jax.device_count() > 1:
         cases.append(
-            ("mesh", jax.make_mesh((jax.device_count(),), ("data",))))
+            ("mesh", make_data_mesh()))
     accs = {}
     for label, mesh in cases:
         engine, model, rep, cold = _steady_fit(
@@ -543,7 +545,7 @@ def run_scaling(csv_rows: list, smoke: bool = False, slow: bool = False
             "blobs", n_train, n_test, seed=0, n_features=8, sep=1.6)
         mesh = None
         if n_train >= 10 ** 6 and jax.device_count() > 1:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+            mesh = make_data_mesh()
         variants = [("streamed", StreamParams(batch_leaves=16))]
         if n_train <= SCALING_RESIDENT_MAX:
             variants.append(("resident", None))
@@ -710,6 +712,7 @@ if __name__ == "__main__":
                          "--smoke (how the committed reference is generated: "
                          "--smoke --full-scaling --slow)")
     args = ap.parse_args()
+    use_compile_cache()
 
     scale = 0.125 if args.smoke else 1.0
     rows: list = []

@@ -15,27 +15,26 @@ from __future__ import annotations
 
 import sys
 import time
-import traceback
 
 
 def main() -> None:
     from benchmarks import bench_baselines, bench_grid, bench_kernels, \
         bench_svm
+    from repro.launch.cache import use_compile_cache
 
+    use_compile_cache()
     rows: list = []
     print("name,us_per_call,derived")
+    # A failing module stops the run with its traceback and a non-zero
+    # exit: a suite that carries on would report a partial table as a pass.
     for mod in (bench_kernels, bench_svm, bench_baselines, bench_grid):
         t0 = time.time()
-        try:
-            start = len(rows)
-            mod.run(rows)
-            for r in rows[start:]:
-                print(",".join(str(x) for x in r), flush=True)
-            print(f"# {mod.__name__} done in {time.time()-t0:.1f}s",
-                  file=sys.stderr)
-        except Exception:   # noqa: BLE001 — keep the suite going
-            traceback.print_exc()
-            print(f"{mod.__name__},0,ERROR", flush=True)
+        start = len(rows)
+        mod.run(rows)
+        for r in rows[start:]:
+            print(",".join(str(x) for x in r), flush=True)
+        print(f"# {mod.__name__} done in {time.time()-t0:.1f}s",
+              file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -1,0 +1,386 @@
+"""Bring-up smoke run: the HSS+ADMM trainer and its serving path on a TPU.
+
+    python chip_smoke.py                # one chip: train, correct, serve, pallas
+    python chip_smoke.py --four-chips   # four-chip host: mesh engine vs one chip
+
+One process drives the chip.  The phases call the same code as
+``python -m repro.launch.train --task svm`` (``build_svm_engine`` /
+``fit_svm_grid``) and ``python -m repro.launch.serve --task svm``
+(``serve_requests``):
+
+  train    SUSY-shaped data (18 features, ``synthetic.susy_like``), tree ->
+           compress -> factorize -> warm-started 2-point C grid -> bias ->
+           holdout predict; per-stage times, compile time apart, ranks,
+           kernel evaluations, holdout accuracy, peak device bytes.
+  correct  a 4,096-row slice against the dense exact-kernel ADMM reference
+           (``core.baselines``) at the same h, C, beta and iteration count.
+  serve    the trained model through ``ServingEngine.score``; served
+           predictions must equal ``model.predict`` on the same rows.
+  pallas   the compression stage on a 65,536-row slice with the Pallas
+           kernels and with XLA, for the gaussian and laplacian kernels.
+
+Everything is placed on ``jax.devices()[0]``, with no mesh.  ``--four-chips``
+runs only the mesh engine over four devices and the same engine on device 0.
+``--rows`` sets the training rows (default 2^18).
+Any failed phase raises; the last stdout line is a JSON object naming the
+device only when every phase passed.  Off a TPU it exits non-zero at once.
+The times printed are one bring-up run, not a benchmark.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "src"))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.core import admm as admm_mod  # noqa: E402
+from repro.core import baselines, compression, tree as tree_mod  # noqa: E402
+from repro.core.kernelfn import KernelSpec, kernel_block  # noqa: E402
+from repro.data import synthetic  # noqa: E402
+from repro.launch.serve import serve_requests  # noqa: E402
+from repro.launch.train import build_svm_engine, fit_svm_grid  # noqa: E402
+
+# The bring-up size.  2^20 rows was the target; the host NEAR-proxy
+# KD-tree query (18 dimensions, ~N^1.8) takes longer than the run's time
+# limit there, so the run uses 2^18 (see CHANGES.md).
+N_TRAIN = 2 ** 18
+N_TEST = 65_536
+LEAF, RANK = 256, 32          # the launch driver's defaults
+H = 3.0                       # bandwidth for 18 standardised-scale features
+H_LAPLACIAN = 10.0
+C_GRID = (1.0, 10.0)
+N_CORRECT = 4096
+N_PALLAS = 65_536
+SEED = 0
+
+# Tolerances.  Accuracy: the CPU parity test's 0.03 budget
+# (tests/test_svm.py::test_hss_matches_dense_exact_kernel_accuracy), also
+# used for the share of holdout labels that may differ.  Decision gap: a
+# CPU run of this phase at the same size gives ||f_hss - f_dense|| /
+# ||f_dense|| = 0.117 (HSS rank 32 and 10 ADMM iterations); 0.25 leaves room
+# for the chip's arithmetic without admitting a broken solve.
+ACC_TOL = 0.03
+DECISION_GAP_TOL = 0.25
+# Four-chip parity: the 8-device CPU engine test's bounds
+# (tests/test_engine.py::test_engine_end_to_end_1_vs_8_devices).  They are
+# f32 bounds, so both engines run at f32 matmul precision: at the TPU's
+# default (one bf16 pass) the jitted mesh programs and the eager one-device
+# build differ by ~3e-2 in the scores even on a one-device mesh with equal
+# pivots, and by 9.5e-7 at f32 (a v5e run at 2^16 rows, CHANGES.md).
+MESH_SCORE_TOL = 1e-4
+MESH_ACC_TOL = 0.004
+MESH_PRECISION = "float32"
+
+
+class SmokeCheckFailed(RuntimeError):
+    """A phase missed its check; never caught."""
+
+
+def check(ok: bool, msg: str) -> None:
+    if not ok:
+        raise SmokeCheckFailed(msg)
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+class CompileClock:
+    """Collects jax's compile events with their wall-clock end times, so a
+    stage's compile seconds can be read off its time window."""
+
+    _PREFIX = "/jax/core/compile/"
+
+    def __init__(self):
+        self.events: list[tuple[float, float]] = []
+
+    def __call__(self, event: str, duration: float, **_kw) -> None:
+        if event.startswith(self._PREFIX):
+            self.events.append((time.perf_counter(), duration))
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self)
+
+    def within(self, t0: float, t1: float) -> float:
+        return sum(d for t, d in self.events if t0 <= t <= t1)
+
+
+def peak_bytes(device) -> str:
+    stats = device.memory_stats()
+    if not stats or "peak_bytes_in_use" not in stats:
+        return "not reported by this backend"
+    return str(stats["peak_bytes_in_use"])
+
+
+def make_data(n_train: int, n_test: int, seed: int = SEED):
+    """SUSY-shaped binary data (LIBSVM SUSY: 18 features, ±1 labels)."""
+    return synthetic.train_test("susy_like", n_train, n_test, seed=seed)
+
+
+# --------------------------------------------------------------------- #
+# phases                                                                 #
+# --------------------------------------------------------------------- #
+def phase_train(xtr, ytr, xte, yte, *, h=H, leaf=LEAF, rank=RANK,
+                c_grid=C_GRID, min_acc=0.9, mesh=None) -> dict:
+    """The launch driver's train path, with per-stage times."""
+    engine = build_svm_engine("svm", h, rank, leaf, mesh=mesh)
+    marks = []
+
+    def mark(msg: str) -> None:
+        marks.append(time.perf_counter())
+        log("  " + msg)
+
+    with CompileClock() as clock:
+        t0 = time.perf_counter()
+        results = fit_svm_grid(engine, xtr, ytr, xte, yte, list(c_grid),
+                               log=mark)
+    rep = engine.report
+    t_prep = marks[0]
+    prep = t_prep - t0
+    host = prep - rep.compression_s - rep.factorization_s
+    t_comp0 = t0 + host
+    t_fact0 = t_comp0 + rep.compression_s
+    stages = [
+        ("host prep (pad, tree, labels)", t0, t_comp0),
+        ("compress (NEAR KD-tree, proxies, IDs)", t_comp0, t_fact0),
+        ("factorize", t_fact0, t_prep),
+        ("ADMM grid + bias + predict C[0]", t_prep, marks[1]),
+    ]
+    stages += [(f"predict C[{i}]", marks[i], marks[i + 1])
+               for i in range(1, len(marks) - 1)]
+    for name, a, b in stages:
+        log(f"  stage {name}: {b - a:.3f} s (compile {clock.within(a, b):.3f}"
+            f" s)")
+    log(f"  ADMM (inside the grid stage, {len(c_grid)} C values, "
+        f"{engine.max_it} iterations each): {rep.admm_s:.3f} s")
+    compile_s = clock.within(t0, marks[-1])
+    log(f"  total {marks[-1] - t0:.3f} s, compile {compile_s:.3f} s in "
+        f"{len(clock.events)} compile events")
+    log(f"  levels {rep.hss_levels}, ranks {rep.ranks_post} (sum "
+        f"{rep.rank_sum_post}), kernel_evals {rep.kernel_evals}, HSS "
+        f"{rep.memory_mb:.1f} MB, beta {rep.beta:g}")
+    for c, model, acc in results:
+        scores = np.asarray(model.decision_function(jnp.asarray(xte)))
+        check(scores.shape == (xte.shape[0],) and np.isfinite(scores).all(),
+              f"C={c}: decision values not finite / wrong shape")
+        check(acc >= min_acc, f"C={c}: holdout accuracy {acc} < {min_acc}")
+    return dict(engine=engine, results=results)
+
+
+def phase_correct(xtr, ytr, xte, yte, *, n=N_CORRECT, h=H, leaf=LEAF,
+                  rank=RANK, c=C_GRID[0]) -> dict:
+    """HSS engine vs the dense exact-kernel ADMM on an n-row slice."""
+    x, y = xtr[:n], ytr[:n]
+    engine = build_svm_engine("svm", h, rank, leaf)
+    model = engine.fit(x, y, c_value=c)
+    beta = engine.report.beta
+    check(beta == admm_mod.paper_beta(n), "engine beta is not the paper rule")
+    spec = KernelSpec(h=h)
+    xj, yj, xq = jnp.asarray(x), jnp.asarray(y), jnp.asarray(xte)
+    z, bias = baselines.dense_admm_fit(xj, yj, spec, c, beta,
+                                       max_it=engine.max_it)
+    f_dense = np.asarray(kernel_block(spec, xq, xj) @ (yj * z) + bias)
+    pred_dense = np.asarray(baselines.dense_predict(xj, yj, z, bias, spec,
+                                                    xq))
+    f_hss = np.asarray(model.decision_function(xq))
+    pred_hss = np.asarray(model.predict(xq))
+    acc_d = float(np.mean(pred_dense == yte))
+    acc_h = float(np.mean(pred_hss == yte))
+    agree = float(np.mean(pred_dense == pred_hss))
+    gap = float(np.linalg.norm(f_hss - f_dense) / np.linalg.norm(f_dense))
+    log(f"  n={n} h={h} C={c} beta={beta:g} iters={engine.max_it}: "
+        f"acc hss {acc_h:.4f} dense {acc_d:.4f}, label agreement "
+        f"{agree:.4f}, decision gap {gap:.4f}")
+    check(np.isfinite(f_hss).all() and np.isfinite(f_dense).all(),
+          "non-finite decision values")
+    check(abs(acc_h - acc_d) <= ACC_TOL, f"accuracy gap {acc_h - acc_d}")
+    check(agree >= 1.0 - ACC_TOL, f"label agreement {agree}")
+    check(gap <= DECISION_GAP_TOL, f"decision gap {gap}")
+    return dict(acc_hss=acc_h, acc_dense=acc_d, agree=agree, gap=gap)
+
+
+def phase_serve(model, xq, *, n_requests=20, batch=256) -> dict:
+    """The serving driver's request loop; predictions must equal
+    ``model.predict`` on the same rows."""
+    served, lat_ms, qps = serve_requests(model, xq, n_requests, batch)
+    for idx, pred in served:
+        want = np.asarray(model.predict(jnp.asarray(xq[idx])))
+        check(np.array_equal(np.asarray(pred), want),
+              "served predictions differ from model.predict")
+    log(f"  {n_requests} requests x {batch} rows: p50 "
+        f"{lat_ms[len(lat_ms) // 2]:.3f} ms, max {lat_ms[-1]:.3f} ms, "
+        f"{qps:.0f} points/s (one bring-up run)")
+    return dict(lat_ms=lat_ms, qps=qps)
+
+
+def _hlo_has_custom_call(fn, *args) -> bool:
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def phase_pallas(xtr, *, n=N_PALLAS, leaf=LEAF, rank=RANK,
+                 impl="pallas") -> dict:
+    """Compression with the Pallas kernels vs XLA on an n-row slice."""
+    from repro.kernels.compress import ops as cops
+
+    x = xtr[:n]
+    x_pad, _, _, levels = tree_mod.pad_dataset(
+        x, np.ones(n, np.float32), leaf)
+    t = tree_mod.build_tree(x_pad, leaf, levels)
+    xp = x_pad[t.perm]
+    params = compression.CompressionParams(rank=rank, n_near=48, n_far=64)
+    v = jnp.asarray(np.random.default_rng(SEED).normal(size=(xp.shape[0], 1)),
+                    jnp.float32)
+    out = {}
+    for name, h in (("gaussian", H), ("laplacian", H_LAPLACIAN)):
+        built = {}
+        for which in ("xla", impl):
+            spec = KernelSpec(name=name, h=h, impl=which)
+            with compression.counting_kernel_evals() as cnt:
+                t0 = time.perf_counter()
+                hss = compression.compress(xp, t, spec, params)
+                jax.block_until_ready(hss.d_leaf)
+                dt = time.perf_counter() - t0
+            built[which] = (hss, cnt["count"], dt)
+        (hx, nx, tx), (hp, np_, tp) = built["xla"], built[impl]
+        piv_agree = float(np.mean(np.sort(np.asarray(hx.skel_leaf), 1)
+                                  == np.sort(np.asarray(hp.skel_leaf), 1)))
+        ref = np.asarray(hx.matmat(v))
+        got = np.asarray(hp.matmat(v))
+        mv_gap = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+        log(f"  {name}: kernel_evals xla {nx} {impl} {np_}; leaf pivots "
+            f"agree {piv_agree:.4f}; matvec gap {mv_gap:.2e} (max-abs over "
+            f"max|ref|); compress xla {tx:.3f} s, {impl} {tp:.3f} s "
+            f"(cold, one run)")
+        check(nx == np_, f"{name}: kernel_evals differ")
+        check(piv_agree >= 0.99, f"{name}: leaf pivot agreement {piv_agree}")
+        check(mv_gap <= 1e-2, f"{name}: matvec gap {mv_gap}")
+        if impl == "pallas":
+            xl = jnp.asarray(xp.reshape(-1, leaf, xp.shape[1]))
+            xprox = xl[:, :params.n_proxy]
+            check(_hlo_has_custom_call(
+                lambda a, b, name=name, h=h: cops.batched_assemble_id(
+                    a, b, rank, kernel_name=name, h=h, rtol=1e-5,
+                    adaptive=False), xl, xprox),
+                f"{name}: fused assemble+ID is not a Mosaic kernel")
+            check(_hlo_has_custom_call(
+                lambda a, b, name=name, h=h: kernel_block(
+                    KernelSpec(name=name, h=h, impl="pallas"), a, b),
+                xl[0], xl[1]),
+                f"{name}: block kernel is not a Mosaic kernel")
+        out[name] = dict(piv_agree=piv_agree, mv_gap=mv_gap)
+    return out
+
+
+def phase_four_chips(xtr, ytr, xte, yte, devices, *, h=H, leaf=LEAF,
+                     rank=RANK, c_grid=C_GRID) -> dict:
+    """The mesh engine over ``devices`` vs the same engine on device 0,
+    both at f32 matmul precision (see MESH_PRECISION)."""
+    with jax.default_matmul_precision(MESH_PRECISION):
+        return _four_chips(xtr, ytr, xte, yte, devices, h=h, leaf=leaf,
+                           rank=rank, c_grid=c_grid)
+
+
+def _four_chips(xtr, ytr, xte, yte, devices, *, h, leaf, rank, c_grid):
+    from repro.launch.mesh import make_data_mesh
+
+    mesh = make_data_mesh(devices)
+    ndev = len(devices)
+    log(f"mesh engine over {ndev} devices, matmul precision "
+        f"{MESH_PRECISION}")
+    t0 = time.perf_counter()
+    sharded = phase_train(xtr, ytr, xte, yte, h=h, leaf=leaf, rank=rank,
+                          c_grid=c_grid, mesh=mesh)
+    log(f"  mesh engine total {time.perf_counter() - t0:.3f} s")
+    eng = sharded["engine"]
+    leaves = dict(d_leaf=eng.hss.d_leaf, u_leaf=eng.hss.u_leaf,
+                  e_leaf=eng.fac.e_leaf, g_leaf=eng.fac.g_leaf,
+                  z_y=sharded["results"][-1][1].z_y)
+    for name, a in leaves.items():
+        devs = {s.device for s in a.addressable_shards}
+        check(len(devs) == ndev and not a.sharding.is_fully_replicated,
+              f"{name} is not sharded over {ndev} devices: {a.sharding}")
+        log(f"  {name} {tuple(a.shape)}: {len(devs)} devices, shard "
+            f"{tuple(a.addressable_shards[0].data.shape)}")
+    log("same engine, no mesh, on device 0")
+    with jax.default_device(devices[0]):
+        t0 = time.perf_counter()
+        local = phase_train(xtr, ytr, xte, yte, h=h, leaf=leaf, rank=rank,
+                            c_grid=c_grid)
+        log(f"  one-device engine total {time.perf_counter() - t0:.3f} s")
+        xq = jnp.asarray(xte)
+        for (c, m_sh, acc_sh), (_, m_lo, acc_lo) in zip(
+                sharded["results"], local["results"]):
+            s_sh = np.asarray(m_sh.decision_function(xq))
+            s_lo = np.asarray(m_lo.decision_function(xq))
+            rel = float(np.linalg.norm(s_sh - s_lo)
+                        / max(np.linalg.norm(s_lo), 1e-30))
+            log(f"  C={c:g}: score gap {rel:.3e}, acc mesh {acc_sh:.4f} "
+                f"one device {acc_lo:.4f}")
+            check(rel <= MESH_SCORE_TOL, f"C={c}: mesh score gap {rel}")
+            check(abs(acc_sh - acc_lo) <= MESH_ACC_TOL,
+                  f"C={c}: mesh accuracy gap {acc_sh - acc_lo}")
+    return dict(sharded=sharded, local=local)
+
+
+# --------------------------------------------------------------------- #
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run only the four-device mesh path and its "
+                         "one-device comparison")
+    ap.add_argument("--rows", type=int, default=N_TRAIN,
+                    help=f"training rows (default {N_TRAIN})")
+    args = ap.parse_args(argv)
+
+    dev0 = jax.devices()[0]
+    if dev0.platform != "tpu":
+        raise SystemExit(f"chip_smoke needs a TPU; jax found "
+                         f"{dev0.platform!r}")
+    from repro.launch.cache import use_compile_cache
+
+    log(f"compile cache: {use_compile_cache()}")
+    log(f"device: {dev0.device_kind}, {len(jax.devices())} visible, "
+        f"jax {jax.__version__}")
+    t0 = time.perf_counter()
+    xtr, ytr, xte, yte = make_data(args.rows, N_TEST)
+    log(f"data: susy_like {xtr.shape} train, {xte.shape} holdout in "
+        f"{time.perf_counter() - t0:.2f} s")
+
+    if args.four_chips:
+        devices = jax.devices()[:4]
+        check(len(devices) == 4, f"--four-chips needs 4 devices, found "
+              f"{len(jax.devices())}")
+        phase_four_chips(xtr, ytr, xte, yte, devices)
+        count = len(devices)
+    else:
+        with jax.default_device(dev0):
+            log("phase train")
+            trained = phase_train(xtr, ytr, xte, yte)
+            log(f"  peak device bytes after train: {peak_bytes(dev0)}")
+            log("phase correct")
+            phase_correct(xtr, ytr, xte, yte)
+            log("phase serve")
+            phase_serve(trained["results"][0][1], xte)
+            log("phase pallas")
+            phase_pallas(xtr)
+            log(f"  peak device bytes at end: {peak_bytes(dev0)}")
+        count = 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev0.platform, "kind": dev0.device_kind,
+        "count": count}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -26,6 +26,7 @@ from repro.core.compression import CompressionParams
 from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
 from repro.data import synthetic
+from repro.launch.mesh import make_data_mesh
 
 
 def main():
@@ -34,7 +35,7 @@ def main():
     xtr, ytr, xte, yte = synthetic.train_test(
         "blobs", n, 2048, seed=0, n_features=8, sep=1.8)
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_data_mesh()
     engine = HSSSVMEngine(
         spec=KernelSpec(h=1.0),
         comp=CompressionParams(rank=32, n_near=48, n_far=64),

@@ -473,6 +473,7 @@ def check_mesh_placement() -> list[Finding]:
 
     from repro.core.distributed import fac_shardings
     from repro.dist import api as dist_api
+    from repro.launch.mesh import make_data_mesh
 
     ndev = len(jax.devices())
     if ndev < 2 or ndev & (ndev - 1):
@@ -480,7 +481,7 @@ def check_mesh_placement() -> list[Finding]:
             "mesh", f"skipped: needs a power-of-two multi-device setup, "
             f"have {ndev} device(s) — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=8")]
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = make_data_mesh()
     hss, fac, _ = build_probe(n=32 * ndev * 2, leaf=32, mesh=mesh)
     findings = []
 

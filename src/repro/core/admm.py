@@ -226,10 +226,14 @@ def admm_boxqp(
         from repro.kernels.admm_update import ops as admm_ops
 
         c_flat = hi_mat.reshape(-1)                # the Pallas kernel is 1-D
+        # Compiled by Mosaic on a TPU; elsewhere only the interpreter can
+        # run a TPU kernel.
+        interpret = jax.default_backend() != "tpu"
 
         def zmu_update(x, mu):
             z_f, mu_f = admm_ops.fused_zmu_update(
-                x.reshape(-1), mu.reshape(-1), c_flat, beta)
+                x.reshape(-1), mu.reshape(-1), c_flat, beta,
+                interpret=interpret)
             return z_f.reshape(x.shape), mu_f.reshape(x.shape)
     else:
         if task.l1 is None:

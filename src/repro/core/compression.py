@@ -252,7 +252,10 @@ def _host_leaf_near(
         x_f32 = np.asarray(x_perm, np.float32)
         kdt = cKDTree(x_f32)
         k_query = min(max(2 * params.n_near // m + 4, 4), tree.n)
-        _, nbr = kdt.query(x_f32, k=k_query)   # (n, k) incl. self
+        # workers=-1: one query thread per host core.  In 18 dimensions the
+        # KD-tree prunes little and the query grows ~N^1.8; it is the
+        # host stage that bounds the build's size.
+        _, nbr = kdt.query(x_f32, k=k_query, workers=-1)  # (n, k) incl. self
         leaf_of = np.arange(tree.n) // m
         # Vectorized over ALL leaves at once (the per-leaf Python loop was
         # the host-preprocessing serial bottleneck at large n_leaf): each

@@ -202,9 +202,7 @@ class HSSSVMEngine:
     _report: FitReport | None = None
     _jit_admm: object = None
     _jit_bias: object = None
-    # The EFFECTIVE mesh: self.mesh, or None when the tree cannot shard
-    # evenly over it (non-power-of-two device count) — then every stage
-    # falls back to the local path instead of crashing on placement.
+    # The mesh the prepared stages were placed on (self.mesh at prepare).
     _mesh: Mesh | None = None
     # multilevel warm start inputs + adaptive-ρ machinery
     _x_raw: np.ndarray | None = None
@@ -226,12 +224,19 @@ class HSSSVMEngine:
                 yield
 
     def _min_levels(self) -> int:
-        """Force enough splits that the leaf axis divides the device count."""
+        """Force enough splits that the leaf axis divides the device count.
+
+        A mesh whose device count is not a power of two cannot divide the
+        perfect tree's 2^L leaves, so it is refused rather than dropped: a
+        run asked to use N devices must not quietly run on one.
+        """
         if self.mesh is None:
             return 0
         ndev = mesh_ndev(self.mesh)
         if ndev & (ndev - 1):
-            return 0            # non-power-of-two mesh: local-build fallback
+            raise ValueError(
+                f"the HSS tree shards over a power-of-two number of devices; "
+                f"this mesh has {ndev}")
         levels = 0
         while 2 ** levels < ndev:
             levels += 1
@@ -270,10 +275,7 @@ class HSSSVMEngine:
         x_pad, y_pad, mask, levels = tree_mod.pad_dataset(
             x, y.astype(np.float32), self.leaf_size,
             min_levels=self._min_levels())
-        mesh = self.mesh
-        if mesh is not None and (2 ** levels) % mesh_ndev(mesh) != 0:
-            mesh = None         # un-shardable leaf count: run the local path
-        self._mesh = mesh
+        mesh = self._mesh = self.mesh
         t = tree_mod.build_tree(x_pad, self.leaf_size, levels)
         xp_host = x_pad[t.perm]
         yp = y_pad[t.perm]

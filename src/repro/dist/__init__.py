@@ -4,7 +4,7 @@ pipeline parallelism, and fault handling.
 Modules:
   api       — ``use_mesh`` context, logical-axis resolution (``resolve_spec``),
               the ``constrain`` activation-sharding hint used throughout
-              repro.models, and a version-compatible ``shard_map``.
+              repro.models, and the repo's ``shard_map`` wrapper.
   sharding  — pytree -> NamedSharding rules for params / optimizer state /
               batches / decode caches (consumed by launch.specs and
               launch.dryrun).

@@ -163,21 +163,11 @@ def constrain_nodes(x: jax.Array) -> jax.Array:
         x, NamedSharding(mesh, node_partition_spec(mesh, x.ndim, x.shape[0])))
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-compatible shard_map.
+def shard_map(f, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-axes check.
 
-    jax renamed the replication-check kwarg (check_rep -> check_vma) and
-    moved shard_map out of jax.experimental across releases; callers in
-    repro.models go through this shim so both API generations work.
+    The per-device bodies here (psum'd partial scores, per-level node
+    blocks) are written against explicit specs, not inferred varying axes.
     """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -13,7 +13,7 @@ from repro.kernels.admm_update.ref import fused_zmu_update_ref
 @functools.partial(jax.jit, static_argnames=("beta", "interpret", "use_pallas"))
 def fused_zmu_update(
     x: jax.Array, mu: jax.Array, c_vec: jax.Array, beta: float,
-    interpret: bool = True, use_pallas: bool = True,
+    interpret: bool = False, use_pallas: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     if not use_pallas:
         return fused_zmu_update_ref(x, mu, c_vec, beta)

@@ -2,7 +2,7 @@
 
 One grid step = one tree node.  The kernel evaluates the node's sampled
 block Aᵀ = K(x_proxy, x_candidate) tile-resident in VMEM — gaussian via the
-MXU matmul expansion, laplacian via a feature-chunked L1 scan — and then
+MXU matmul expansion, laplacian via a per-feature L1 loop — and then
 runs the greedy column-pivoted-QR deflation loop of ``idqr.cpqr_select``
 directly on that block while it is still on-chip.  Only the pivot indices
 (k,) and the projected factor R = QᵀAᵀ (k, m) are written back to HBM: the
@@ -10,15 +10,18 @@ directly on that block while it is still on-chip.  Only the pivot indices
 Per node that is O(k·m) HBM traffic instead of O(n_proxy·m) plus the
 O(k·n_proxy·m) of an unfused deflation loop's intermediate round-trips.
 
-The CPQR loop mirrors ``idqr.cpqr_select`` operation for operation
-(same norm, re-orthogonalization, deflation, and exact-zeroing steps) so the
-selected pivots are identical to the XLA path on non-degenerate blocks; all
-contractions and the deflation state are f32 regardless of input dtype
-(bf16 inputs are upcast on load — the precision-accumulate convention).
+The CPQR loop mirrors ``idqr.cpqr_select`` step for step (same norm,
+argmax tie rule, re-orthogonalization, deflation, and exact-zeroing steps)
+so the selected pivots are identical to the XLA path on non-degenerate
+blocks; all contractions and the deflation state are f32 regardless of
+input dtype (bf16 inputs are upcast on load — the precision-accumulate
+convention).  The loop's vector products are VPU multiply + reduce over the
+(s, m) tile rather than N = 1 matmuls.
 
 Pivot bookkeeping is fully vectorized (one-hot accumulation against a lane
-iota) — no dynamic scalar stores, so the same kernel body runs on TPU and
-under ``interpret=True`` on CPU.
+iota, an f32 liveness row) — no dynamic scalar stores and no boolean loop
+carry, so the same kernel body compiles with Mosaic for TPU and runs under
+``interpret=True`` on CPU.
 
 VMEM budget per grid step at the largest committed shapes (accurate preset
 leaf stage: m = 256 candidates, s = 192 proxies, k = 64, f padded to 128):
@@ -34,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_F_CHUNK = 8   # laplacian L1 scan: feature sublane chunk
+from repro.kernels.compress.laplacian import l1_dist
 
 
 def _assemble_gaussian(xp: jax.Array, xc: jax.Array, h: float) -> jax.Array:
@@ -50,25 +53,9 @@ def _assemble_gaussian(xp: jax.Array, xc: jax.Array, h: float) -> jax.Array:
 
 def _assemble_laplacian(xp: jax.Array, xc: jax.Array, h: float,
                         f_real: int) -> jax.Array:
-    """exp(-||xp_i - xc_j||₁ / h) via the feature-chunked L1 scan.
-
-    The L1 distance has no matmul expansion; scanning ``_F_CHUNK``-wide
-    feature slices keeps the broadcast intermediate at
-    (s, m, _F_CHUNK) — the same trick as ``kernelfn.laplacian_block_xla``.
-    Only ceil(f_real / _F_CHUNK) chunks are visited: the zero-padded feature
-    tail contributes |0 - 0| = 0 and is skipped entirely.
-    """
-    n_chunks = -(-f_real // _F_CHUNK)
-
-    def body(c, acc):
-        a = jax.lax.dynamic_slice_in_dim(xp, c * _F_CHUNK, _F_CHUNK, 1)
-        b = jax.lax.dynamic_slice_in_dim(xc, c * _F_CHUNK, _F_CHUNK, 1)
-        return acc + jnp.sum(jnp.abs(a[:, None, :] - b[None, :, :]), axis=-1)
-
-    d1 = jax.lax.fori_loop(
-        0, n_chunks, body,
-        jnp.zeros((xp.shape[0], xc.shape[0]), jnp.float32))
-    return jnp.exp(-d1 / h)
+    """exp(-||xp_i - xc_j||₁ / h) by the block kernel's feature loop
+    (``laplacian.l1_dist``); the zero-padded feature tail is skipped."""
+    return jnp.exp(-l1_dist(xp, xc, f_real) / h)
 
 
 def _fused_tile(xc_ref, xp_ref, cmask_ref, piv_ref, rfull_ref, *,
@@ -88,42 +75,43 @@ def _fused_tile(xc_ref, xp_ref, cmask_ref, piv_ref, rfull_ref, *,
     # (exp of a finite distance, not 0) — mask them to exact zeros, and fold
     # in the caller's candidate-liveness mask (dead child skeletons of the
     # adaptive build; all-ones otherwise).
-    row_ok = jax.lax.broadcasted_iota(jnp.int32, (s_pad, 1), 0) < s_real
-    col_ok = jax.lax.broadcasted_iota(jnp.int32, (1, m_pad), 1) < m_real
-    cmask = cmask_ref[0].astype(jnp.float32)[None, :]          # (1, m_pad)
-    a_t = a_t * row_ok.astype(jnp.float32) * col_ok.astype(jnp.float32)
-    a_t = a_t * cmask
-
+    row_ok = (jax.lax.broadcasted_iota(jnp.int32, (s_pad, 1), 0)
+              < s_real).astype(jnp.float32)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (1, m_pad), 1)
+    col_ok = (iota_m < m_real).astype(jnp.float32)             # (1, m_pad)
+    cmask = cmask_ref[0].astype(jnp.float32)                   # (1, m_pad)
+    a_t = a_t * row_ok * col_ok * cmask
+
     iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
     def body(i, carry):
+        # ``avail`` is an f32 0/1 liveness row: Mosaic cannot carry a
+        # boolean vector through ``scf.for``.
         resid, qs, piv, avail = carry
-        norms = jnp.where(avail, jnp.sum(resid * resid, axis=0)[None, :],
-                          -1.0)
-        p = jnp.argmax(norms).astype(jnp.int32)
+        norms = jnp.where(avail > 0.5,
+                          jnp.sum(resid * resid, axis=0, keepdims=True), -1.0)
+        # argmax as max + first matching lane (same tie rule as jnp.argmax),
+        # kept (1, 1) so every reduction stays a vector op.
+        top = jnp.max(norms, axis=1, keepdims=True)
+        p = jnp.min(jnp.where(norms == top, iota_m, m_pad), axis=1,
+                    keepdims=True)                             # (1, 1) int32
         onehot = (iota_m == p).astype(jnp.float32)             # (1, m_pad)
-        col = jnp.sum(resid * onehot, axis=1)[:, None]         # (s_pad, 1)
-        nrm = jnp.sqrt(jnp.maximum(jnp.sum(norms * onehot), 1e-30))
+        col = jnp.sum(resid * onehot, axis=1, keepdims=True)   # (s_pad, 1)
+        nrm = jnp.sqrt(jnp.maximum(top, 1e-30))                # (1, 1)
         q = col / nrm
         # "Twice is enough": re-orthogonalize against prior directions.
-        proj = jax.lax.dot_general(
-            qs, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (k, 1)
-        q = q - jax.lax.dot_general(
-            qs, proj, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        q = q / jnp.sqrt(jnp.maximum(jnp.sum(q * q), 1e-30))
+        proj = jnp.sum(qs * q, axis=0, keepdims=True)          # (1, k)
+        q = q - jnp.sum(qs * proj, axis=1, keepdims=True)
+        q = q / jnp.sqrt(jnp.maximum(
+            jnp.sum(q * q, axis=0, keepdims=True), 1e-30))
         # Deflate every remaining column; zero the chosen one exactly.
-        qr = jax.lax.dot_general(
-            q, resid, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (1, m_pad)
+        qr = jnp.sum(q * resid, axis=0, keepdims=True)         # (1, m_pad)
         resid = (resid - q * qr) * (1.0 - onehot)
         # One-hot accumulation instead of dynamic stores (TPU-friendly).
-        sel = (iota_k == i).astype(jnp.float32)                # (1, k)
-        piv = piv + p * (iota_k == i).astype(jnp.int32)
-        qs = qs + q * sel
-        avail = jnp.logical_and(avail, onehot < 0.5)
+        sel = iota_k == i                                      # (1, k)
+        piv = piv + jnp.where(sel, p, 0)
+        qs = qs + q * sel.astype(jnp.float32)
+        avail = avail * (1.0 - onehot)
         return resid, qs, piv, avail
 
     qs0 = jnp.zeros((s_pad, k), jnp.float32)
@@ -133,7 +121,7 @@ def _fused_tile(xc_ref, xp_ref, cmask_ref, piv_ref, rfull_ref, *,
     rfull = jax.lax.dot_general(
         qs, a_t, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                    # (k, m_pad)
-    piv_ref[0] = piv[0]
+    piv_ref[0] = piv
     rfull_ref[0] = rfull
 
 
@@ -168,14 +156,14 @@ def fused_assemble_id_pallas(
         in_specs=[
             pl.BlockSpec((1, m_pad, f_pad), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, s_pad, f_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m_pad), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, m_pad), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, k), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, k, m_pad), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
             jax.ShapeDtypeStruct((b, k, m_pad), jnp.float32),
         ],
         interpret=interpret,

@@ -2,16 +2,15 @@
 
 Computes K = exp(-||xa_i - xb_j||₁ / h) one (bm, bn) output tile at a time.
 The L1 distance has no MXU matmul expansion, so each tile accumulates the
-distance over feature chunks on the VPU — the broadcast intermediate is
-(bm, bn, _F_CHUNK), never (ma, mb, f) — and the exp epilogue fuses into the
-tile while it is VMEM-resident.  This is the Pallas twin of the
-feature-chunked ``kernelfn.laplacian_block_xla`` scan, closing the gap where
-``KernelSpec(name="laplacian", impl="pallas")`` used to warn-and-fall-back.
+distance one feature at a time on the VPU — a (bm, 1) column of xa against a
+(1, bn) row of the transposed xb tile, so every intermediate is a plain
+(bm, bn) tile — and the exp epilogue fuses into the tile while it is
+VMEM-resident.  This is the Pallas twin of ``kernelfn.laplacian_block_xla``.
 
 Padding rows are zero vectors: their pairwise L1 distance to other zero rows
 is 0 (kernel value 1), which lands only in cropped-away tiles; zero-padded
-FEATURES contribute |0 - 0| = 0 to every distance, so the chunked loop can
-simply skip the padded feature tail.
+FEATURES contribute |0 - 0| = 0 to every distance, so the feature loop
+simply stops at the real feature count.
 """
 from __future__ import annotations
 
@@ -21,25 +20,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_F_CHUNK = 8
+def l1_dist(xa: jax.Array, xb: jax.Array, f_real: int) -> jax.Array:
+    """(ma, mb) L1 distances between the rows of f32 tiles xa (ma, f_pad)
+    and xb (mb, f_pad), over their first ``f_real`` features.
+
+    The feature loop is unrolled in Python, so every lane slice has a static
+    offset (Mosaic has no lowering for a lane ``dynamic_slice``), and each
+    step is one (ma, mb) VPU update; a (ma, mb, chunk) broadcast would put
+    the short chunk axis on the 128 lanes.
+    """
+    xbt = xb.T                                 # (f_pad, mb)
+    d1 = jnp.zeros((xa.shape[0], xb.shape[0]), jnp.float32)
+    for j in range(f_real):
+        d1 = d1 + jnp.abs(xa[:, j:j + 1] - xbt[j:j + 1, :])
+    return d1
 
 
 def _laplacian_tile(xa_ref, xb_ref, out_ref, *, inv_h: float, f_real: int):
-    # f_real is the pre-padding feature count: chunks past it are all-zero
-    # padding and contribute |0 - 0| = 0, so the loop skips them.
     xa = xa_ref[...].astype(jnp.float32)       # (bm, f_pad) in VMEM
     xb = xb_ref[...].astype(jnp.float32)       # (bn, f_pad)
-    n_chunks = -(-f_real // _F_CHUNK)
-
-    def body(c, acc):
-        a = jax.lax.dynamic_slice_in_dim(xa, c * _F_CHUNK, _F_CHUNK, 1)
-        b = jax.lax.dynamic_slice_in_dim(xb, c * _F_CHUNK, _F_CHUNK, 1)
-        return acc + jnp.sum(jnp.abs(a[:, None, :] - b[None, :, :]), axis=-1)
-
-    d1 = jax.lax.fori_loop(
-        0, n_chunks, body,
-        jnp.zeros((xa.shape[0], xb.shape[0]), jnp.float32))
-    out_ref[...] = jnp.exp(-d1 * inv_h).astype(out_ref.dtype)
+    out_ref[...] = jnp.exp(-l1_dist(xa, xb, f_real) * inv_h).astype(
+        out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -92,8 +93,8 @@ def laplacian_block(
     bn_eff = min(bn, max(((mb + 127) // 128) * 128, 128))
     ma_p = ((ma + bm_eff - 1) // bm_eff) * bm_eff
     mb_p = ((mb + bn_eff - 1) // bn_eff) * bn_eff
-    # Feature padding to the lane width; the in-kernel chunk loop only
-    # visits ceil(f / _F_CHUNK) chunks, so the zero tail costs nothing.
+    # Feature padding to the lane width; the in-kernel feature loop stops
+    # at f, so the zero tail costs nothing.
     f_p = max(((f + 127) // 128) * 128, 128)
     out = laplacian_block_pallas(
         _pad_to(xa, ma_p, f_p), _pad_to(xb, mb_p, f_p),

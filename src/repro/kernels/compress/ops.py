@@ -50,9 +50,10 @@ def _batched_assemble_id(
     f_p = max(-(-f // 128) * 128, 128)
     piv, r_full = fused_assemble_id_pallas(
         _pad3(xc, m_p, f_p), _pad3(xp, s_p, f_p),
-        jnp.pad(cmask.astype(jnp.float32), ((0, 0), (0, m_p - m))),
+        jnp.pad(cmask.astype(jnp.float32), ((0, 0), (0, m_p - m)))[:, None],
         kernel_name=kernel_name, h=h, k=k,
         m_real=m, s_real=s, f_real=f, interpret=interpret)
+    piv = piv[:, 0]
     r_full = r_full[:, :, :m]
     t_full, ranks = jax.vmap(
         lambda p, r: idqr.finish_interp(

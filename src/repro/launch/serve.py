@@ -118,7 +118,9 @@ def serve_svm(args) -> None:
 
     mesh = None
     if args.svm_mesh and jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        from repro.launch.mesh import make_data_mesh
+
+        mesh = make_data_mesh()
         print(f"mesh-parallel build over {jax.device_count()} devices")
 
     t0 = time.time()
@@ -158,34 +160,14 @@ def serve_svm(args) -> None:
           f"{rep.factorization_s:.2f}s / batched ADMM {rep.admm_s:.2f}s), "
           f"{quality}")
 
-    # Request loop through the serving tier: ONE scoring entry point
-    # (ServingEngine.score) covers all four task decodes — no per-task
-    # closures here.  --registry round-trips the model through the
-    # persistent registry first (optionally SV-pruned on load).
-    from repro.serve import BatchPolicy, ModelRegistry, ServingEngine
-
     registry = None
     if args.registry:
+        from repro.serve import ModelRegistry
+
         registry = ModelRegistry(args.registry)
-        version = registry.save(task, model)
-        print(f"registered model {task!r} v{version} under {args.registry}")
-    serve = ServingEngine(
-        policy=BatchPolicy(compute_dtype=args.serve_dtype), registry=registry)
-    if registry is not None:
-        mid = serve.load(task, prune_tol=args.prune_tol)
-    else:
-        mid = serve.add_model(model)
-
-    rng = np.random.default_rng(1)
-    serve.score(mid, xte[: args.batch])               # compile outside timing
-
-    t_serve = time.time()
-    for _ in range(args.requests):
-        idx = rng.integers(0, xte.shape[0], size=args.batch)
-        _scores, pred = serve.score(mid, xte[idx])
-    t_serve = time.time() - t_serve
-    lat_ms = np.sort(np.array(serve.drain_latencies())[-args.requests:]) * 1e3
-    qps = args.requests * args.batch / max(t_serve, 1e-9)
+    _, lat_ms, qps = serve_requests(
+        model, xte, args.requests, args.batch, registry=registry,
+        prune_tol=args.prune_tol, serve_dtype=args.serve_dtype)
     per_pass = (f"{args.svm_classes} classes" if task == "svm"
                 else {"svr": "regression values",
                       "krr": "regression values",
@@ -195,6 +177,47 @@ def serve_svm(args) -> None:
           f"{qps:.0f} points/s, latency p50 {lat_ms[len(lat_ms)//2]:.2f}ms "
           f"p95 {lat_ms[int(len(lat_ms)*0.95)-1]:.2f}ms "
           f"({per_pass} per pass)")
+
+
+def serve_requests(model, xq: np.ndarray, n_requests: int, batch: int, *,
+                   registry=None, prune_tol: float | None = None,
+                   serve_dtype: str = "float32"):
+    """The request loop through the serving tier.
+
+    ONE scoring entry point (``ServingEngine.score``) covers all task
+    decodes.  With a ``ModelRegistry`` the model is first round-tripped
+    through it (optionally SV-pruned on load).  One warm-up request at the
+    request batch shape compiles the scorer outside the timed loop; then
+    ``n_requests`` requests of ``batch`` rows drawn from ``xq``.
+
+    Returns ([(row indices, predictions)] per request, sorted latencies in
+    ms, points/s over the loop).
+    """
+    from repro.serve import BatchPolicy, ServingEngine
+
+    serve = ServingEngine(
+        policy=BatchPolicy(compute_dtype=serve_dtype), registry=registry)
+    if registry is not None:
+        version = registry.save(model.task, model)
+        print(f"registered model {model.task!r} v{version} under "
+              f"{registry.root}")
+        mid = serve.load(model.task, prune_tol=prune_tol)
+    else:
+        mid = serve.add_model(model)
+
+    rng = np.random.default_rng(1)
+    serve.score(mid, xq[:batch])                      # compile outside timing
+
+    served = []
+    t_serve = time.time()
+    for _ in range(n_requests):
+        idx = rng.integers(0, xq.shape[0], size=batch)
+        _scores, pred = serve.score(mid, xq[idx])
+        served.append((idx, pred))
+    t_serve = time.time() - t_serve
+    lat_ms = np.sort(np.array(serve.drain_latencies())[-n_requests:]) * 1e3
+    qps = n_requests * batch / max(t_serve, 1e-9)
+    return served, lat_ms, qps
 
 
 def main() -> None:
@@ -233,6 +256,9 @@ def main() -> None:
                     help="serving-tier kernel block compute dtype")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     if args.task in ("svm", "svr", "oneclass", "krr", "gp"):
         serve_svm(args)
     else:

@@ -32,11 +32,47 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def train_svm(args) -> None:
+def build_svm_engine(task: str, h: float, rank: int, leaf: int, mesh=None):
+    """The launch driver's engine: ``HSSSVMEngine`` with the driver's
+    compression (rank cap, 48 NEAR + 64 FAR proxies) and 10 ADMM
+    iterations per knob value."""
     from repro.core.compression import CompressionParams
     from repro.core.engine import HSSSVMEngine
     from repro.core.kernelfn import KernelSpec
+
+    return HSSSVMEngine(
+        spec=KernelSpec(h=h),
+        comp=CompressionParams(rank=rank, n_near=48, n_far=64),
+        leaf_size=leaf, max_it=10, mesh=mesh, task=task)
+
+
+def fit_svm_grid(engine, xtr, ytr, xte, yte, c_grid, log=print) -> list:
+    """prepare once, then the warm-started knob sweep; returns
+    [(knob, model, holdout metric)] — accuracy for svm, RMSE for krr/gp."""
+    task = engine.task
+    rep = engine.prepare(xtr, ytr)
+    log(f"prepare: compress {rep.compression_s:.1f}s, factorize "
+        f"{rep.factorization_s:.2f}s, HSS {rep.memory_mb:.1f} MB, "
+        f"beta {rep.beta:g}")
+    yte_j = jnp.asarray(yte)
+    knob_name = "λ" if task in ("krr", "gp") else "C"
+    out = []
+    for c, model in zip(c_grid, engine.train_grid(c_grid)):
+        pred = model.predict(jnp.asarray(xte))
+        if task in ("krr", "gp"):
+            metric = float(jnp.sqrt(jnp.mean((pred - yte_j) ** 2)))
+            log(f"{knob_name}={c:g}: holdout rmse {metric:.4f} "
+                f"(admm iters {engine.report.iters_run})")
+        else:
+            metric = float(jnp.mean(pred == yte_j))
+            log(f"{knob_name}={c:g}: holdout acc {metric:.4f}")
+        out.append((c, model, metric))
+    return out
+
+
+def train_svm(args) -> None:
     from repro.data import synthetic
+    from repro.launch.mesh import make_data_mesh
 
     task = args.task
     dataset = args.svm_dataset
@@ -46,30 +82,15 @@ def train_svm(args) -> None:
         dataset, args.svm_train, args.svm_test, seed=0)
     mesh = None
     if jax.device_count() > 1 and not args.svm_local:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_data_mesh()
         print(f"mesh-parallel build over {jax.device_count()} devices")
-    engine = HSSSVMEngine(
-        spec=KernelSpec(h=args.svm_h),
-        comp=CompressionParams(rank=args.svm_rank, n_near=48, n_far=64),
-        leaf_size=args.svm_leaf, max_it=10, mesh=mesh, task=task)
+    engine = build_svm_engine(task, args.svm_h, args.svm_rank, args.svm_leaf,
+                              mesh=mesh)
     t0 = time.time()
-    rep = engine.prepare(xtr, ytr)
-    print(f"prepare: compress {rep.compression_s:.1f}s, factorize "
-          f"{rep.factorization_s:.2f}s, HSS {rep.memory_mb:.1f} MB, "
-          f"beta {rep.beta:g}")
     c_grid = [float(c) for c in args.svm_c_grid.split(",")]
-    yte_j = jnp.asarray(yte)
-    knob_name = "λ" if task in ("krr", "gp") else "C"
-    for c, model in zip(c_grid, engine.train_grid(c_grid)):
-        pred = model.predict(jnp.asarray(xte))
-        if task in ("krr", "gp"):
-            rmse = float(jnp.sqrt(jnp.mean((pred - yte_j) ** 2)))
-            print(f"{knob_name}={c:g}: holdout rmse {rmse:.4f} "
-                  f"(admm iters {engine.report.iters_run})")
-        else:
-            acc = float(jnp.mean(pred == yte_j))
-            print(f"{knob_name}={c:g}: holdout acc {acc:.4f}")
+    fit_svm_grid(engine, xtr, ytr, xte, yte, c_grid)
     stage = "solve" if task in ("krr", "gp") else "ADMM"
+    knob_name = "λ" if task in ("krr", "gp") else "C"
     print(f"done in {time.time() - t0:.1f}s "
           f"({stage} total {engine.report.admm_s:.2f}s across the "
           f"{knob_name} grid)")
@@ -102,6 +123,9 @@ def main() -> None:
                     help="force the single-device engine path")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     if args.task in ("svm", "krr", "gp"):
         train_svm(args)
         return

@@ -241,6 +241,7 @@ def test_adaptive_sharded_build_8_devices():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import compression, factorization, tree as tree_mod
         from repro.core.hss import shrink_to_fit
         from repro.core.kernelfn import KernelSpec
@@ -255,7 +256,7 @@ def test_adaptive_sharded_build_8_devices():
         rtol = 1e-4
         params = compression.CompressionParams(
             rank=24, n_near=32, n_far=48, rtol=rtol)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
 
         hss_ref = compression.compress(jnp.asarray(xp), t, spec, params)
         hss = compression.compress_sharded(xp, t, spec, params, mesh)
@@ -308,6 +309,7 @@ def test_engine_adaptive_8_devices_matches_local():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.compression import CompressionParams
         from repro.core.engine import HSSSVMEngine
         from repro.core.kernelfn import KernelSpec
@@ -323,7 +325,7 @@ def test_engine_adaptive_8_devices_matches_local():
         eng0 = HSSSVMEngine(**kw)
         m0 = eng0.fit(xtr, ytr, c_value=1.0)
         acc0 = float(jnp.mean(m0.predict(jnp.asarray(xte)) == yte))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         eng8 = HSSSVMEngine(mesh=mesh, **kw)
         m8 = eng8.fit(xtr, ytr, c_value=1.0)
         acc8 = float(jnp.mean(m8.predict(jnp.asarray(xte)) == yte))
@@ -352,6 +354,7 @@ def test_shrink_to_fit_sharding_matches_partition_spec_8_devices():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding
         from repro.core import compression, tree as tree_mod
         from repro.core.hss import shrink_to_fit
@@ -364,7 +367,7 @@ def test_shrink_to_fit_sharding_matches_partition_spec_8_devices():
         t = tree_mod.build_tree(x, leaf_size=leaf)
         params = compression.CompressionParams(
             rank=24, n_near=32, n_far=48, rtol=1e-4)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         hss = compression.compress_sharded(
             x[t.perm], t, KernelSpec(h=1.5), params, mesh)
         shr = shrink_to_fit(hss, mesh=mesh)

@@ -130,17 +130,18 @@ def test_elastic_reshard_subprocess():
         import sys, tempfile
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.checkpoint import save_checkpoint, load_checkpoint
 
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh8 = make_mesh((4, 2), ("data", "model"))
         arr = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         sharded = jax.device_put(arr, NamedSharding(mesh8, P("data", "model")))
         tree = {"w": sharded}
         d = tempfile.mkdtemp()
         save_checkpoint(d, tree, step=1)
 
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh4 = make_mesh((2, 2), ("data", "model"))
         sh4 = {"w": NamedSharding(mesh4, P("model", "data"))}
         out, step = load_checkpoint(d, tree, shardings=sh4)
         np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(arr))

@@ -16,11 +16,12 @@ import pytest
 
 from repro.dist import api as dist_api
 from repro.dist import sharding as shd
+from repro.launch.mesh import make_mesh
 
 
 def test_resolve_spec_divisibility_fallback():
     import jax.numpy as jnp
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with dist_api.use_mesh(mesh):
         spec = dist_api.resolve_spec(("model", None), (7, 3))
         # 7 % 1 == 0 -> keeps axis
@@ -33,7 +34,7 @@ def test_param_shardings_cover_all_leaves():
 
     cfg = get_config("arctic-480b").reduced()
     shapes = jax.eval_shape(Model(cfg).init, jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = shd.param_shardings(shapes, mesh)
     n = len(jax.tree.leaves(sh, is_leaf=lambda x: hasattr(x, "spec")))
     assert n == len(jax.tree.leaves(shapes))
@@ -47,6 +48,7 @@ def test_distributed_train_step_matches_single_device():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models.transformer import Model
         from repro.train import optim
@@ -65,7 +67,7 @@ def test_distributed_train_step_matches_single_device():
         # single device reference
         loss_ref, _ = jax.jit(model.loss_fn)(params, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with dist_api.use_mesh(mesh), mesh:
             psh = shd.param_shardings(
                 jax.eval_shape(lambda: params), mesh, fsdp=True)
@@ -95,6 +97,7 @@ def test_sharded_attention_matches_single_device():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models.transformer import Model
         from repro.data.tokens import batch_for_config
@@ -109,7 +112,7 @@ def test_sharded_attention_matches_single_device():
         batch = jax.tree.map(jnp.asarray, batch_for_config(cfg, 4, 64, 0))
         loss_ref, _ = jax.jit(model.loss_fn)(params, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with dist_api.use_mesh(mesh), mesh:
             psh = shd.param_shardings(jax.eval_shape(lambda: params), mesh)
             fn = jax.jit(model.loss_fn)
@@ -133,6 +136,7 @@ def test_distributed_svm_solve_matches_local():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import compression, factorization, tree as tree_mod
         from repro.core.kernelfn import KernelSpec
         from repro.core.distributed import fac_shardings, vec_sharding
@@ -149,7 +153,7 @@ def test_distributed_svm_solve_matches_local():
         b = jnp.asarray(rng.normal(size=n), jnp.float32)
         ref = np.asarray(fac.solve(b))
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         fac_sh = fac_shardings(jax.eval_shape(lambda: fac), mesh)
         fac_d = jax.device_put(fac, fac_sh)
         b_d = jax.device_put(b, vec_sharding(mesh))
@@ -174,6 +178,7 @@ def test_distributed_admm_c_grid_matches_single_device():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import compression, factorization, tree as tree_mod
         from repro.core.distributed import admm_train_distributed
         from repro.core.kernelfn import KernelSpec
@@ -191,9 +196,9 @@ def test_distributed_admm_c_grid_matches_single_device():
 
         c_grid = [0.5, 1.0, 2.0]
         res1 = admm_train_distributed(
-            fac, yp, c_grid, jax.make_mesh((1,), ("data",)), max_it=10)
+            fac, yp, c_grid, make_mesh((1,), ("data",)), max_it=10)
         res8 = admm_train_distributed(
-            fac, yp, c_grid, jax.make_mesh((8,), ("data",)), max_it=10)
+            fac, yp, c_grid, make_mesh((8,), ("data",)), max_it=10)
         for i in range(len(c_grid)):
             np.testing.assert_allclose(
                 np.asarray(res8[i][0]), np.asarray(res1[i][0]),

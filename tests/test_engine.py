@@ -26,6 +26,7 @@ from repro.core.kernelfn import KernelSpec
 from repro.core.multiclass import MulticlassHSSSVMTrainer
 from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
+from repro.launch.mesh import make_mesh
 
 COMP = CompressionParams(rank=24, n_near=32, n_far=48)
 
@@ -142,6 +143,7 @@ def test_sharded_build_matches_local_build():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import compression, factorization, tree as tree_mod
         from repro.core.distributed import fac_shardings
         from repro.core.kernelfn import KernelSpec
@@ -154,7 +156,7 @@ def test_sharded_build_matches_local_build():
         xp = x[t.perm]
         spec = KernelSpec(h=1.0)
         params = compression.CompressionParams(rank=24, n_near=32, n_far=48)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
 
         hss_ref = compression.compress(jnp.asarray(xp), t, spec, params)
         fac_ref = factorization.factorize(hss_ref, 10.0)
@@ -222,6 +224,19 @@ def test_sharded_build_matches_local_build():
     assert "BUILD_PARITY_OK" in r.stdout, r.stdout + r.stderr
 
 
+def test_engine_refuses_a_mesh_it_cannot_shard_over():
+    """A 3-device mesh cannot divide the perfect tree's 2^L leaves: prepare
+    raises instead of quietly running everything on one device."""
+    import types
+
+    fake3 = types.SimpleNamespace(axis_names=("data",), shape={"data": 3})
+    xtr, ytr, _, _ = synthetic.train_test("blobs", 256, 16, seed=0)
+    eng = HSSSVMEngine(spec=KernelSpec(h=1.0), comp=COMP, leaf_size=32,
+                       mesh=fake3)
+    with pytest.raises(ValueError, match="power-of-two"):
+        eng.prepare(xtr, ytr)
+
+
 def test_sharded_build_preserves_dtype_bf16():
     """Fast leg of the dtype-preservation fix: under a 1-device mesh the
     sharded build keeps bf16 end-to-end (no silent f32 downcast) and agrees
@@ -238,7 +253,7 @@ def test_sharded_build_preserves_dtype_bf16():
     xp_bf = jnp.asarray(x[t.perm], jnp.bfloat16)
     spec = KernelSpec(h=1.0)
     params = compression.CompressionParams(rank=16, n_near=16, n_far=16)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     hss_lo = compression.compress(xp_bf, t, spec, params)
     hss_sh = compression.compress_sharded(xp_bf, t, spec, params, mesh)
     for name in ("d_leaf", "u_leaf", "x"):
@@ -272,6 +287,7 @@ def test_engine_end_to_end_1_vs_8_devices():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.compression import CompressionParams
         from repro.core.engine import HSSSVMEngine
         from repro.core.kernelfn import KernelSpec
@@ -290,8 +306,8 @@ def test_engine_end_to_end_1_vs_8_devices():
             acc = float(np.mean(np.where(scores >= 0, 1, -1) == yte))
             return eng, model, scores, acc
 
-        eng1, m1, s1, acc1 = fit(jax.make_mesh((1,), ("data",)))
-        eng8, m8, s8, acc8 = fit(jax.make_mesh((8,), ("data",)))
+        eng1, m1, s1, acc1 = fit(make_mesh((1,), ("data",)))
+        eng8, m8, s8, acc8 = fit(make_mesh((8,), ("data",)))
         eng0, m0, s0, acc0 = fit(None)
 
         # 8-device model is genuinely sharded
@@ -323,6 +339,7 @@ def test_engine_multiclass_8_devices():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.compression import CompressionParams
         from repro.core.engine import HSSSVMEngine
         from repro.core.kernelfn import KernelSpec
@@ -337,7 +354,7 @@ def test_engine_multiclass_8_devices():
         ref = MulticlassHSSSVMTrainer(**kw).fit(xtr, ytr, c_value=1.0)
         acc_ref = float(jnp.mean(ref.predict(jnp.asarray(xte))
                                  == jnp.asarray(yte)))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         eng = HSSSVMEngine(mesh=mesh, **kw)
         model = eng.fit(xtr, ytr, c_value=1.0)
         assert model.z_y.shape[1] == 4

@@ -46,9 +46,10 @@ def test_compressed_allreduce_multidevice():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.train import grad_compress as gc
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(8, 4096)), jnp.float32)
         reduce_fn = gc.make_compressed_allreduce(mesh, "data")

@@ -15,6 +15,7 @@ from repro.core import multiclass as mc
 from repro.core.compression import CompressionParams
 from repro.core.kernelfn import KernelSpec
 from repro.data import synthetic
+from repro.launch.mesh import make_mesh
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
 
@@ -156,7 +157,7 @@ def test_multiclass_distributed_matches_local(trained4):
 
     trainer, _, _ = trained4
     fac, ys, pmask = trainer._fac, trainer._ys, trainer._pmask
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     out = admm_train_multiclass_distributed(
         fac, ys, [0.5, 1.0], mesh, max_it=8, pmask=pmask)
     st1, _ = admm_mod.admm_svm_batched(

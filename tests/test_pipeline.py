@@ -15,10 +15,11 @@ def test_pipeline_matches_sequential():
         import sys
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist.pipeline import pipeline_forward
 
         n_stages, n_micro, mb, d = 4, 6, 2, 16
-        mesh = jax.make_mesh((n_stages,), ("stage",))
+        mesh = make_mesh((n_stages,), ("stage",))
         rng = np.random.default_rng(0)
         w = jnp.asarray(rng.normal(size=(n_stages, d, d)) * 0.3, jnp.float32)
         params = {"w": w}

@@ -228,6 +228,7 @@ import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core.compression import CompressionParams, StreamParams
 from repro.core.kernelfn import KernelSpec
@@ -239,7 +240,7 @@ t = tree_mod.build_tree(x, leaf_size=64)
 xp = x[t.perm]
 spec = KernelSpec(h=1.5)
 params = CompressionParams(rank=12, n_near=16, n_far=16, rtol=1e-3)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 ref = compression.compress(xp, t, spec, params)
 hss, stats = compression.compress_streamed(

@@ -351,6 +351,7 @@ _MESH_PARITY_TMPL = """
     import sys
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core.compression import CompressionParams
     from repro.core.engine import HSSSVMEngine
     from repro.core.kernelfn import KernelSpec
@@ -369,8 +370,8 @@ _MESH_PARITY_TMPL = """
         return eng, model, np.asarray(
             model.decision_function(jnp.asarray(xte)))
 
-    eng1, m1, s1 = fit(jax.make_mesh((1,), ("data",)))
-    eng8, m8, s8 = fit(jax.make_mesh((8,), ("data",)))
+    eng1, m1, s1 = fit(make_mesh((1,), ("data",)))
+    eng8, m8, s8 = fit(make_mesh((8,), ("data",)))
     assert not m8.z_y.sharding.is_fully_replicated
     assert not eng8.hss.d_leaf.sharding.is_fully_replicated
     rel = np.linalg.norm(s1 - s8) / max(np.linalg.norm(s1), 1e-30)
