@@ -10,8 +10,9 @@ One process drives the chip.  The phases call the same code as
 
   train    SUSY-shaped data (18 features, ``synthetic.susy_like``), tree ->
            compress -> factorize -> warm-started 2-point C grid -> bias ->
-           holdout predict; per-stage times, compile time apart, ranks,
-           kernel evaluations, holdout accuracy, peak device bytes.
+           holdout predict; the fit's stage spans (seconds, self seconds)
+           and jit counters from ``repro.obs``, ranks, kernel evaluations,
+           holdout accuracy, peak device bytes.
   correct  a 4,096-row slice against the dense exact-kernel ADMM reference
            (``core.baselines``) at the same h, C, beta and iteration count.
   serve    the trained model through ``ServingEngine.score``; served
@@ -41,6 +42,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.core import admm as admm_mod  # noqa: E402
 from repro.core import baselines, compression, tree as tree_mod  # noqa: E402
 from repro.core.kernelfn import KernelSpec, kernel_block  # noqa: E402
@@ -93,30 +95,6 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileClock:
-    """Collects jax's compile events with their wall-clock end times, so a
-    stage's compile seconds can be read off its time window."""
-
-    _PREFIX = "/jax/core/compile/"
-
-    def __init__(self):
-        self.events: list[tuple[float, float]] = []
-
-    def __call__(self, event: str, duration: float, **_kw) -> None:
-        if event.startswith(self._PREFIX):
-            self.events.append((time.perf_counter(), duration))
-
-    def __enter__(self):
-        jax.monitoring.register_event_duration_secs_listener(self)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_duration_listener(self)
-
-    def within(self, t0: float, t1: float) -> float:
-        return sum(d for t, d in self.events if t0 <= t <= t1)
-
-
 def peak_bytes(device) -> str:
     stats = device.memory_stats()
     if not stats or "peak_bytes_in_use" not in stats:
@@ -134,40 +112,19 @@ def make_data(n_train: int, n_test: int, seed: int = SEED):
 # --------------------------------------------------------------------- #
 def phase_train(xtr, ytr, xte, yte, *, h=H, leaf=LEAF, rank=RANK,
                 c_grid=C_GRID, min_acc=0.9, mesh=None) -> dict:
-    """The launch driver's train path, with per-stage times."""
+    """The launch driver's train path, with the stage spans it records."""
     engine = build_svm_engine("svm", h, rank, leaf, mesh=mesh)
-    marks = []
-
-    def mark(msg: str) -> None:
-        marks.append(time.perf_counter())
-        log("  " + msg)
-
-    with CompileClock() as clock:
-        t0 = time.perf_counter()
-        results = fit_svm_grid(engine, xtr, ytr, xte, yte, list(c_grid),
-                               log=mark)
+    results = fit_svm_grid(engine, xtr, ytr, xte, yte, list(c_grid),
+                           log=lambda m: log("  " + m))
+    fit, = obs.recent_roots(1, name="hss.fit")
+    for name, sec in fit.seconds.items():
+        log(f"  span {name}: {sec:.3f} s (self "
+            f"{fit.self_seconds[name]:.3f} s)")
+    log("  counters: " + ", ".join(
+        f"{k} {v}" for k, v in sorted(fit.counters.items())))
     rep = engine.report
-    t_prep = marks[0]
-    prep = t_prep - t0
-    host = prep - rep.compression_s - rep.factorization_s
-    t_comp0 = t0 + host
-    t_fact0 = t_comp0 + rep.compression_s
-    stages = [
-        ("host prep (pad, tree, labels)", t0, t_comp0),
-        ("compress (NEAR KD-tree, proxies, IDs)", t_comp0, t_fact0),
-        ("factorize", t_fact0, t_prep),
-        ("ADMM grid + bias + predict C[0]", t_prep, marks[1]),
-    ]
-    stages += [(f"predict C[{i}]", marks[i], marks[i + 1])
-               for i in range(1, len(marks) - 1)]
-    for name, a, b in stages:
-        log(f"  stage {name}: {b - a:.3f} s (compile {clock.within(a, b):.3f}"
-            f" s)")
-    log(f"  ADMM (inside the grid stage, {len(c_grid)} C values, "
-        f"{engine.max_it} iterations each): {rep.admm_s:.3f} s")
-    compile_s = clock.within(t0, marks[-1])
-    log(f"  total {marks[-1] - t0:.3f} s, compile {compile_s:.3f} s in "
-        f"{len(clock.events)} compile events")
+    log(f"  ADMM ({len(c_grid)} C values, {engine.max_it} iterations each): "
+        f"{rep.admm_s:.3f} s")
     log(f"  levels {rep.hss_levels}, ranks {rep.ranks_post} (sum "
         f"{rep.rank_sum_post}), kernel_evals {rep.kernel_evals}, HSS "
         f"{rep.memory_mb:.1f} MB, beta {rep.beta:g}")

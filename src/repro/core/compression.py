@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import idqr
 from repro.core.hss import HSSMatrix, rank_mask
 from repro.core.kernelfn import KernelSpec, kernel_block
@@ -34,8 +35,7 @@ from repro.core.tree import ClusterTree
 
 Array = jax.Array
 
-# Counting-kernel instrumentation state (see ``counting_kernel_evals``).
-_EVAL_STATE: dict | None = None
+KERNEL_EVALS = "hss.kernel_evals"
 
 
 @contextlib.contextmanager
@@ -44,27 +44,28 @@ def counting_kernel_evals():
 
     Every kernel evaluation inside the build flows through the two seams
     below (``_batched_kernel_block`` / ``_batched_row_id``), which add the
-    logical block sizes to this counter whenever their operands are concrete
-    — i.e. for the eager host-orchestrated ``compress``.  Inside traced
-    contexts (``compress_sharded``'s shard_map bodies) the operands are
-    tracers and nothing is counted: per-device shapes would double-count.
+    logical block sizes to the recorder counter ``hss.kernel_evals``
+    whenever their operands are concrete — i.e. for the eager
+    host-orchestrated ``compress``.  Inside traced contexts
+    (``compress_sharded``'s shard_map bodies) the operands are tracers and
+    nothing is counted: per-device shapes would double-count.
 
-    Yields a dict whose ``"count"`` entry is the running total; the property
-    test pins it against the hand-derived ``kernel_eval_count`` formula.
+    Yields a dict whose ``"count"`` entry holds the evaluations counted in
+    the block once it exits; the property test pins it against the
+    hand-derived ``kernel_eval_count`` formula.
     """
-    global _EVAL_STATE
-    prev = _EVAL_STATE
-    _EVAL_STATE = {"count": 0}
+    out = {"count": 0}
+    start = obs.total(KERNEL_EVALS)
     try:
-        yield _EVAL_STATE
+        yield out
     finally:
-        _EVAL_STATE = prev
+        out["count"] = obs.total(KERNEL_EVALS) - start
 
 
 def _note_evals(xa: Array, xb: Array, count: int) -> None:
-    if _EVAL_STATE is not None and not (
-            isinstance(xa, jax.core.Tracer) or isinstance(xb, jax.core.Tracer)):
-        _EVAL_STATE["count"] += count
+    if not (isinstance(xa, jax.core.Tracer)
+            or isinstance(xb, jax.core.Tracer)):
+        obs.count(KERNEL_EVALS, count)
 
 
 def _batched_kernel_block(spec: KernelSpec, xa: Array, xb: Array) -> Array:
@@ -217,14 +218,15 @@ def _host_proxy_indices(
     rng = np.random.default_rng(params.seed)
     n, m, K = tree.n, tree.leaf_size, tree.levels
     out = []
-    for k in range(K):  # levels 0..K-1 need bases/skeletons
-        n_k = 2 ** (K - k)
-        width = m * 2 ** k
-        rows = [
-            _complement_sample(rng, n, node * width, width, params.n_far)
-            for node in range(n_k)
-        ]
-        out.append(np.stack(rows, axis=0))
+    with obs.span("hss.far_proxies"):
+        for k in range(K):  # levels 0..K-1 need bases/skeletons
+            n_k = 2 ** (K - k)
+            width = m * 2 ** k
+            rows = [
+                _complement_sample(rng, n, node * width, width, params.n_far)
+                for node in range(n_k)
+            ]
+            out.append(np.stack(rows, axis=0))
     return out
 
 
@@ -239,23 +241,39 @@ def _host_leaf_near(
     (scipy) — the exact analogue of STRUMPACK's ANN preprocessing; without
     data we fall back to sampling the sibling leaf (tree-adjacent ≈ near).
     """
-    rng = np.random.default_rng(params.seed + 1)
-    m, K = tree.leaf_size, tree.levels
-    n_leaf = 2 ** K
-    out = np.empty((n_leaf, params.n_near), dtype=np.int32)
-    if x_perm is not None and n_leaf > 1:
-        from scipy.spatial import cKDTree
+    with obs.span("hss.near_search"):
+        rng = np.random.default_rng(params.seed + 1)
+        m, n_leaf = tree.leaf_size, 2 ** tree.levels
+        if x_perm is not None and n_leaf > 1:
+            return _kdtree_near(tree, params, x_perm, rng)
+        out = np.empty((n_leaf, params.n_near), dtype=np.int32)
+        for i in range(n_leaf):
+            sib = i ^ 1
+            out[i] = rng.choice(m, size=params.n_near,
+                                replace=params.n_near > m) + sib * m
+        return out
 
-        # f32 is plenty for neighbour RANKING and keeps scipy happy with
-        # dtypes it cannot handle (bf16); the kernel evaluations themselves
-        # stay in the caller's dtype.
-        x_f32 = np.asarray(x_perm, np.float32)
+
+def _kdtree_near(tree: ClusterTree, params: CompressionParams,
+                 x_perm: np.ndarray, rng: np.random.Generator) -> np.ndarray:
+    """``_host_leaf_near``'s KD-tree search over the data."""
+    from scipy.spatial import cKDTree
+
+    m, n_leaf = tree.leaf_size, 2 ** tree.levels
+    out = np.empty((n_leaf, params.n_near), dtype=np.int32)
+    # f32 is plenty for neighbour RANKING and keeps scipy happy with dtypes
+    # it cannot handle (bf16); the kernel evaluations themselves stay in the
+    # caller's dtype.
+    x_f32 = np.asarray(x_perm, np.float32)
+    with obs.span("hss.near_search.kdtree"):
         kdt = cKDTree(x_f32)
-        k_query = min(max(2 * params.n_near // m + 4, 4), tree.n)
+    k_query = min(max(2 * params.n_near // m + 4, 4), tree.n)
+    with obs.span("hss.near_search.query"):
         # workers=-1: one query thread per host core.  In 18 dimensions the
         # KD-tree prunes little and the query grows ~N^1.8; it is the
         # host stage that bounds the build's size.
         _, nbr = kdt.query(x_f32, k=k_query, workers=-1)  # (n, k) incl. self
+    with obs.span("hss.near_search.select"):
         leaf_of = np.arange(tree.n) // m
         # Vectorized over ALL leaves at once (the per-leaf Python loop was
         # the host-preprocessing serial bottleneck at large n_leaf): each
@@ -296,10 +314,6 @@ def _host_leaf_near(
                 extra = rng.choice(m, size=short - len(pool)) + sib * m
                 fill = np.concatenate([pool, extra])
             out[i, c:] = fill
-        return out
-    for i in range(n_leaf):
-        sib = i ^ 1
-        out[i] = rng.choice(m, size=params.n_near, replace=params.n_near > m) + sib * m
     return out
 
 
@@ -335,57 +349,60 @@ def compress(
         x_host = np.asarray(jax.device_get(x_perm))
     leaf_near = jnp.asarray(_host_leaf_near(tree, params, x_host))
 
-    x_leaves = x_perm.reshape(n_leaf, m, -1)
-
     # ---------------- leaves ---------------- #
-    d_leaf = _batched_kernel_block(spec, x_leaves, x_leaves)
+    with obs.span("hss.compress.leaves"):
+        x_leaves = x_perm.reshape(n_leaf, m, -1)
+        d_leaf = _batched_kernel_block(spec, x_leaves, x_leaves)
 
-    prox0 = jnp.concatenate([leaf_near, far_idx[0]], axis=1)
-    x_prox0 = jnp.take(x_perm, prox0, axis=0)      # (n_leaf, n_proxy, f)
-    piv0, u_leaf, leaf_ranks = _batched_row_id(
-        spec, x_leaves, x_prox0, r0, rtol, adaptive)
-    leaf_starts = jnp.arange(n_leaf, dtype=jnp.int32) * m
-    skel_leaf = leaf_starts[:, None] + piv0
+        prox0 = jnp.concatenate([leaf_near, far_idx[0]], axis=1)
+        x_prox0 = jnp.take(x_perm, prox0, axis=0)      # (n_leaf, n_proxy, f)
+        piv0, u_leaf, leaf_ranks = _batched_row_id(
+            spec, x_leaves, x_prox0, r0, rtol, adaptive)
+        leaf_starts = jnp.arange(n_leaf, dtype=jnp.int32) * m
+        skel_leaf = leaf_starts[:, None] + piv0
 
     # ---------------- internal levels ---------------- #
-    transfers: list[Array] = []
-    skels: list[Array] = []
-    b_mats: list[Array] = []
-    level_ranks: list[Array] = []
-    skel_prev = skel_leaf                     # (n_{k-1}, r_{k-1})
-    rank_prev = leaf_ranks                    # (n_{k-1},) numerical ranks
-    r_prev = r0
-    for k in range(1, K + 1):
-        n_k = 2 ** (K - k)
-        cand = skel_prev.reshape(n_k, 2 * r_prev)      # children skeleton ids
-        # Liveness of each candidate slot under the children's detected ranks
-        # (all-ones in fixed-rank mode).
-        cmask = _cand_mask(rank_prev, r_prev, x_perm.dtype)
-        # B couplings: K(skel_c1, skel_c2) — pure kernel evals.  Dead
-        # skeleton rows/columns are masked to exact zeros so the truncation
-        # is structural (factorization decouples them; shrink slices them).
-        xa = jnp.take(x_perm, cand[:, :r_prev], axis=0)
-        xb = jnp.take(x_perm, cand[:, r_prev:], axis=0)
-        b_k = _batched_kernel_block(spec, xa, xb)
-        if adaptive:
-            b_k = _mask_b(b_k, cmask, r_prev)
-        b_mats.append(b_k)
-        if k == K:
-            break
-        r_k = min(params.rank, 2 * r_prev)
-        # NEAR proxies: the sibling node's candidate skeletons (dynamic).
-        sib = cand.reshape(n_k // 2, 2, 2 * r_prev)[:, ::-1, :].reshape(n_k, 2 * r_prev)
-        prox = jnp.concatenate([sib, far_idx[k]], axis=1)
-        xc = jnp.take(x_perm, cand, axis=0)            # (n_k, 2 r_prev, f)
-        xp = jnp.take(x_perm, prox, axis=0)
-        piv_k, t_k, rank_k = _batched_row_id(
-            spec, xc, xp, r_k, rtol, adaptive,
-            cmask=cmask if adaptive else None)
-        skel_k = jnp.take_along_axis(cand, piv_k, axis=1)
-        transfers.append(t_k)
-        skels.append(skel_k)
-        level_ranks.append(rank_k)
-        skel_prev, rank_prev, r_prev = skel_k, rank_k, r_k
+    with obs.span("hss.compress.levels"):
+        transfers: list[Array] = []
+        skels: list[Array] = []
+        b_mats: list[Array] = []
+        level_ranks: list[Array] = []
+        skel_prev = skel_leaf                 # (n_{k-1}, r_{k-1})
+        rank_prev = leaf_ranks                # (n_{k-1},) numerical ranks
+        r_prev = r0
+        for k in range(1, K + 1):
+            n_k = 2 ** (K - k)
+            cand = skel_prev.reshape(n_k, 2 * r_prev)  # children skeleton ids
+            # Liveness of each candidate slot under the children's detected
+            # ranks (all-ones in fixed-rank mode).
+            cmask = _cand_mask(rank_prev, r_prev, x_perm.dtype)
+            # B couplings: K(skel_c1, skel_c2) — pure kernel evals.  Dead
+            # skeleton rows/columns are masked to exact zeros so the
+            # truncation is structural (factorization decouples them; shrink
+            # slices them).
+            xa = jnp.take(x_perm, cand[:, :r_prev], axis=0)
+            xb = jnp.take(x_perm, cand[:, r_prev:], axis=0)
+            b_k = _batched_kernel_block(spec, xa, xb)
+            if adaptive:
+                b_k = _mask_b(b_k, cmask, r_prev)
+            b_mats.append(b_k)
+            if k == K:
+                break
+            r_k = min(params.rank, 2 * r_prev)
+            # NEAR proxies: the sibling node's candidate skeletons (dynamic).
+            sib = cand.reshape(n_k // 2, 2, 2 * r_prev)[:, ::-1, :].reshape(
+                n_k, 2 * r_prev)
+            prox = jnp.concatenate([sib, far_idx[k]], axis=1)
+            xc = jnp.take(x_perm, cand, axis=0)            # (n_k, 2 r_prev, f)
+            xp = jnp.take(x_perm, prox, axis=0)
+            piv_k, t_k, rank_k = _batched_row_id(
+                spec, xc, xp, r_k, rtol, adaptive,
+                cmask=cmask if adaptive else None)
+            skel_k = jnp.take_along_axis(cand, piv_k, axis=1)
+            transfers.append(t_k)
+            skels.append(skel_k)
+            level_ranks.append(rank_k)
+            skel_prev, rank_prev, r_prev = skel_k, rank_k, r_k
 
     return HSSMatrix(
         x=x_perm,
@@ -474,123 +491,129 @@ def compress_sharded(
     leaf_near = _host_leaf_near(tree, params, x_host)
     prox0 = np.concatenate([leaf_near, far_idx[0]], axis=1)
 
-    x_leaves = jax.device_put(x_host.reshape(n_leaf, m, -1), sh_nodes)
-    x_prox0 = jax.device_put(x_host[prox0], sh_nodes)   # (n_leaf, n_proxy, f)
-    leaf_starts = jax.device_put(
-        np.arange(n_leaf, dtype=np.int32) * m, sh_nodes)
-
     # ---------------- leaves (shard_map over the node axis) ------------- #
-    def _leaf_stage(xl, xp, starts):
-        d = _batched_kernel_block(spec, xl, xl)
-        piv, u, rks = _batched_row_id(spec, xl, xp, r0, rtol, adaptive)
-        skel = starts[:, None] + piv
-        spts = jax.vmap(lambda xa, p: jnp.take(xa, p, axis=0))(xl, piv)
-        return d, u, skel, spts, rks
+    with obs.span("hss.compress.leaves"):
+        x_leaves = jax.device_put(x_host.reshape(n_leaf, m, -1),
+                                  sh_nodes)
+        # (n_leaf, n_proxy, f)
+        x_prox0 = jax.device_put(x_host[prox0], sh_nodes)
+        leaf_starts = jax.device_put(
+            np.arange(n_leaf, dtype=np.int32) * m, sh_nodes)
 
-    leaf_fn = jax.jit(shard_map(
-        _leaf_stage, mesh,
-        in_specs=(p_nodes, p_nodes, p_nodes),
-        out_specs=(p_nodes,) * 5))
-    d_leaf, u_leaf, skel_leaf, spts, leaf_ranks = leaf_fn(
-        x_leaves, x_prox0, leaf_starts)
-    sids, sranks = skel_leaf, leaf_ranks
+        def _leaf_stage(xl, xp, starts):
+            d = _batched_kernel_block(spec, xl, xl)
+            piv, u, rks = _batched_row_id(spec, xl, xp, r0, rtol, adaptive)
+            skel = starts[:, None] + piv
+            spts = jax.vmap(lambda xa, p: jnp.take(xa, p, axis=0))(xl, piv)
+            return d, u, skel, spts, rks
+
+        leaf_fn = jax.jit(shard_map(
+            _leaf_stage, mesh,
+            in_specs=(p_nodes, p_nodes, p_nodes),
+            out_specs=(p_nodes,) * 5))
+        d_leaf, u_leaf, skel_leaf, spts, leaf_ranks = leaf_fn(
+            x_leaves, x_prox0, leaf_starts)
+        sids, sranks = skel_leaf, leaf_ranks
 
     # ---------------- internal levels ---------------- #
-    transfers: list[Array] = []
-    skels: list[Array] = []
-    b_mats: list[Array] = []
-    level_ranks: list[Array] = []
-    r_prev = r0
-    sharded = True
-    for k in range(1, K + 1):
-        n_k = 2 ** (K - k)
-        # Pair-shardable: parents divide the devices AND each device holds
-        # an even number of parents so the sibling-NEAR exchange is local.
-        want = (sharded and n_k % ndev == 0
-                and (k == K or (n_k // ndev) % 2 == 0))
-        if sharded and not want:
-            # Degradation point: one all-gather of the skeleton points/ids/
-            # ranks (O(r * n_k) — the only cross-device traffic of the
-            # upper tree).
-            spts = jax.device_put(spts, sh_repl)
-            sids = jax.device_put(sids, sh_repl)
-            sranks = jax.device_put(sranks, sh_repl)
-            sharded = False
-        r_k = min(params.rank, 2 * r_prev)
+    with obs.span("hss.compress.levels"):
+        transfers: list[Array] = []
+        skels: list[Array] = []
+        b_mats: list[Array] = []
+        level_ranks: list[Array] = []
+        r_prev = r0
+        sharded = True
+        for k in range(1, K + 1):
+            n_k = 2 ** (K - k)
+            # Pair-shardable: parents divide the devices AND each device holds
+            # an even number of parents so the sibling-NEAR exchange is local.
+            want = (sharded and n_k % ndev == 0
+                    and (k == K or (n_k // ndev) % 2 == 0))
+            if sharded and not want:
+                # Degradation point: one all-gather of the skeleton points/ids/
+                # ranks (O(r * n_k) — the only cross-device traffic of the
+                # upper tree).
+                spts = jax.device_put(spts, sh_repl)
+                sids = jax.device_put(sids, sh_repl)
+                sranks = jax.device_put(sranks, sh_repl)
+                sharded = False
+            r_k = min(params.rank, 2 * r_prev)
 
-        if sharded:
-            loc = n_k // ndev
-            rp, rk = r_prev, r_k
-            if k == K:
-                def _b_only(sp, sr):
-                    cp = sp.reshape(loc, 2 * rp, sp.shape[-1])
+            if sharded:
+                loc = n_k // ndev
+                rp, rk = r_prev, r_k
+                if k == K:
+                    def _b_only(sp, sr):
+                        cp = sp.reshape(loc, 2 * rp, sp.shape[-1])
+                        b = _batched_kernel_block(spec, cp[:, :rp], cp[:, rp:])
+                        if adaptive:
+                            b = _mask_b(b, _cand_mask(sr, rp, b.dtype), rp)
+                        return b
+
+                    b_fn = jax.jit(shard_map(
+                        _b_only, mesh, in_specs=(p_nodes, p_nodes),
+                        out_specs=p_nodes))
+                    b_mats.append(b_fn(spts, sranks))
+                    break
+
+                far_pts = jax.device_put(x_host[far_idx[k]], sh_nodes)
+
+                def _level(sp, si, sr, fp):
+                    f = sp.shape[-1]
+                    cp = sp.reshape(loc, 2 * rp, f)
+                    ci = si.reshape(loc, 2 * rp)
+                    cm = _cand_mask(sr, rp, sp.dtype)
                     b = _batched_kernel_block(spec, cp[:, :rp], cp[:, rp:])
                     if adaptive:
-                        b = _mask_b(b, _cand_mask(sr, rp, b.dtype), rp)
-                    return b
+                        b = _mask_b(b, cm, rp)
+                    sib = cp.reshape(loc // 2, 2, 2 * rp, f)[:, ::-1]
+                    sib = sib.reshape(loc, 2 * rp, f)
+                    xp_ = jnp.concatenate([sib, fp], axis=1)
+                    piv, t, rks = _batched_row_id(
+                        spec, cp, xp_, rk, rtol, adaptive,
+                        cmask=cm if adaptive else None)
+                    ids = jnp.take_along_axis(ci, piv, axis=1)
+                    pts = jax.vmap(
+                        lambda c, p: jnp.take(c, p, axis=0))(cp, piv)
+                    return b, t, ids, pts, rks
 
-                b_fn = jax.jit(shard_map(
-                    _b_only, mesh, in_specs=(p_nodes, p_nodes),
-                    out_specs=p_nodes))
-                b_mats.append(b_fn(spts, sranks))
-                break
-
-            far_pts = jax.device_put(x_host[far_idx[k]], sh_nodes)
-
-            def _level(sp, si, sr, fp):
-                f = sp.shape[-1]
-                cp = sp.reshape(loc, 2 * rp, f)
-                ci = si.reshape(loc, 2 * rp)
-                cm = _cand_mask(sr, rp, sp.dtype)
-                b = _batched_kernel_block(spec, cp[:, :rp], cp[:, rp:])
+                lvl_fn = jax.jit(shard_map(
+                    _level, mesh,
+                    in_specs=(p_nodes,) * 4,
+                    out_specs=(p_nodes,) * 5))
+                b_k, t_k, sids, spts, sranks = lvl_fn(
+                    spts, sids, sranks, far_pts)
+                b_mats.append(b_k)
+                transfers.append(t_k)
+                skels.append(sids)
+                level_ranks.append(sranks)
+            else:
+                # Replicated upper tree: same math, every device computes it.
+                f = spts.shape[-1]
+                cand_pts = spts.reshape(n_k, 2 * r_prev, f)
+                cand_ids = sids.reshape(n_k, 2 * r_prev)
+                cmask = _cand_mask(sranks, r_prev, spts.dtype)
+                b_k = _batched_kernel_block(
+                    spec, cand_pts[:, :r_prev], cand_pts[:, r_prev:])
                 if adaptive:
-                    b = _mask_b(b, cm, rp)
-                sib = cp.reshape(loc // 2, 2, 2 * rp, f)[:, ::-1]
-                sib = sib.reshape(loc, 2 * rp, f)
-                xp_ = jnp.concatenate([sib, fp], axis=1)
-                piv, t, rks = _batched_row_id(
-                    spec, cp, xp_, rk, rtol, adaptive,
-                    cmask=cm if adaptive else None)
-                ids = jnp.take_along_axis(ci, piv, axis=1)
-                pts = jax.vmap(lambda c, p: jnp.take(c, p, axis=0))(cp, piv)
-                return b, t, ids, pts, rks
-
-            lvl_fn = jax.jit(shard_map(
-                _level, mesh,
-                in_specs=(p_nodes,) * 4,
-                out_specs=(p_nodes,) * 5))
-            b_k, t_k, sids, spts, sranks = lvl_fn(spts, sids, sranks, far_pts)
-            b_mats.append(b_k)
-            transfers.append(t_k)
-            skels.append(sids)
-            level_ranks.append(sranks)
-        else:
-            # Replicated upper tree: same math, every device computes it.
-            f = spts.shape[-1]
-            cand_pts = spts.reshape(n_k, 2 * r_prev, f)
-            cand_ids = sids.reshape(n_k, 2 * r_prev)
-            cmask = _cand_mask(sranks, r_prev, spts.dtype)
-            b_k = _batched_kernel_block(
-                spec, cand_pts[:, :r_prev], cand_pts[:, r_prev:])
-            if adaptive:
-                b_k = _mask_b(b_k, cmask, r_prev)
-            b_mats.append(b_k)
-            if k == K:
-                break
-            sib = cand_pts.reshape(n_k // 2, 2, 2 * r_prev, f)[:, ::-1]
-            sib = sib.reshape(n_k, 2 * r_prev, f)
-            far_pts = jax.device_put(x_host[far_idx[k]], sh_repl)
-            xp_ = jnp.concatenate([sib, far_pts], axis=1)
-            piv_k, t_k, sranks = _batched_row_id(
-                spec, cand_pts, xp_, r_k, rtol, adaptive,
-                cmask=cmask if adaptive else None)
-            sids = jnp.take_along_axis(cand_ids, piv_k, axis=1)
-            spts = jax.vmap(lambda c, p: jnp.take(c, p, axis=0))(
-                cand_pts, piv_k)
-            transfers.append(t_k)
-            skels.append(sids)
-            level_ranks.append(sranks)
-        r_prev = r_k
+                    b_k = _mask_b(b_k, cmask, r_prev)
+                b_mats.append(b_k)
+                if k == K:
+                    break
+                sib = cand_pts.reshape(n_k // 2, 2, 2 * r_prev, f)[:, ::-1]
+                sib = sib.reshape(n_k, 2 * r_prev, f)
+                far_pts = jax.device_put(x_host[far_idx[k]], sh_repl)
+                xp_ = jnp.concatenate([sib, far_pts], axis=1)
+                piv_k, t_k, sranks = _batched_row_id(
+                    spec, cand_pts, xp_, r_k, rtol, adaptive,
+                    cmask=cmask if adaptive else None)
+                sids = jnp.take_along_axis(cand_ids, piv_k, axis=1)
+                spts = jax.vmap(lambda c, p: jnp.take(c, p, axis=0))(
+                    cand_pts, piv_k)
+                transfers.append(t_k)
+                skels.append(sids)
+                level_ranks.append(sranks)
+            r_prev = r_k
 
     return HSSMatrix(
         x=jax.device_put(x_host, sh_nodes),
@@ -850,7 +873,11 @@ def compress_streamed(
     def _step(state: dict, i: int) -> dict:
         if on_level is not None:
             on_level(i)
-        return _run_leaves(state) if i == 0 else _run_level(state, i)
+        if i == 0:
+            with obs.span("hss.compress.leaves"):
+                return _run_leaves(state)
+        with obs.span("hss.compress.levels"):
+            return _run_level(state, i)
 
     def _save(state: dict, completed: int) -> None:
         if stream.ckpt_dir is None:

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Sequence
 
 import jax
@@ -33,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from repro import obs
 from repro.core import admm as admm_mod
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core import tasks as tasks_mod
@@ -245,6 +245,10 @@ class HSSSVMEngine:
     # ------------------------------------------------------------------ #
     def prepare(self, x: np.ndarray, y: np.ndarray | None = None) -> FitReport:
         """Pad + tree + compress ONCE + factorize ONCE (Alg. 3 lines 1–6)."""
+        with obs.span("hss.prepare"):
+            return self._prepare(x, y)
+
+    def _prepare(self, x: np.ndarray, y: np.ndarray | None) -> FitReport:
         if self.strategy not in ("ovr", "ovo"):
             raise ValueError(f"unknown strategy {self.strategy!r}")
         if self.task not in ("svm", "svr", "oneclass", "krr", "gp"):
@@ -272,66 +276,75 @@ class HSSSVMEngine:
             classes = np.array([-1.0, 1.0], np.float32)
             self._binary = False
         d_real = x.shape[0]
-        x_pad, y_pad, mask, levels = tree_mod.pad_dataset(
-            x, y.astype(np.float32), self.leaf_size,
-            min_levels=self._min_levels())
+        with obs.span("hss.pad"):
+            x_pad, y_pad, mask, levels = tree_mod.pad_dataset(
+                x, y.astype(np.float32), self.leaf_size,
+                min_levels=self._min_levels())
         mesh = self._mesh = self.mesh
-        t = tree_mod.build_tree(x_pad, self.leaf_size, levels)
-        xp_host = x_pad[t.perm]
-        yp = y_pad[t.perm]
-        maskp = mask[t.perm]
+        with obs.span("hss.tree"):
+            t = tree_mod.build_tree(x_pad, self.leaf_size, levels)
+            xp_host = x_pad[t.perm]
+            yp = y_pad[t.perm]
+            maskp = mask[t.perm]
 
-        if self.task != "svm":
-            # one problem column: SVR's ys row holds the (mask-zeroed)
-            # regression targets, one-class ignores it — the participation
-            # mask is what pins pads to the inert [0, 0] box in both.
-            ys = (yp * maskp)[None, :].astype(np.float32)
-            pmasks = maskp[None, :].astype(np.float32)
-            pairs = None
-        elif self._binary:
-            ys = np.where(yp > 0, 1.0, -1.0)[None, :].astype(np.float32)
-            pmasks = maskp[None, :].astype(np.float32)
-            pairs = None
-        else:
-            build = ovr_problems if self.strategy == "ovr" else ovo_problems
-            ys, pmasks, pairs = build(yp, classes.astype(np.float32), maskp)
+        with obs.span("hss.labels"):
+            if self.task != "svm":
+                # one problem column: SVR's ys row holds the (mask-zeroed)
+                # regression targets, one-class ignores it — the
+                # participation mask is what pins pads to the inert [0, 0]
+                # box in both.
+                ys = (yp * maskp)[None, :].astype(np.float32)
+                pmasks = maskp[None, :].astype(np.float32)
+                pairs = None
+            elif self._binary:
+                ys = np.where(yp > 0, 1.0, -1.0)[None, :].astype(np.float32)
+                pmasks = maskp[None, :].astype(np.float32)
+                pairs = None
+            else:
+                build = ovr_problems if self.strategy == "ovr" else \
+                    ovo_problems
+                ys, pmasks, pairs = build(
+                    yp, classes.astype(np.float32), maskp)
 
-        t0 = time.perf_counter()
-        sstats = None
-        if self.stream is not None:
-            hss, sstats = compression.compress_streamed(
-                xp_host, t, self.spec, self.comp, stream=self.stream,
-                mesh=mesh)
-        elif mesh is not None:
-            hss = compression.compress_sharded(
-                xp_host, t, self.spec, self.comp, mesh)
-        else:
-            hss = compression.compress(xp_host, t, self.spec, self.comp)
-        # Adaptive builds (comp.rtol set): slice every level down to its
-        # observed max rank before factorizing — the factorization and every
-        # downstream solve/matmat then run at the detected ranks, mesh
-        # placement preserved via the shared node_partition_spec rule.
-        hss, rank_info = shrink_report(hss, mesh=mesh)
-        jax.block_until_ready(hss.d_leaf)
-        t1 = time.perf_counter()
+        with obs.span("hss.compress") as compress_span:
+            sstats = None
+            if self.stream is not None:
+                hss, sstats = compression.compress_streamed(
+                    xp_host, t, self.spec, self.comp, stream=self.stream,
+                    mesh=mesh)
+            elif mesh is not None:
+                hss = compression.compress_sharded(
+                    xp_host, t, self.spec, self.comp, mesh)
+            else:
+                hss = compression.compress(xp_host, t, self.spec, self.comp)
+            # Adaptive builds (comp.rtol set): slice every level down to its
+            # observed max rank before factorizing — the factorization and
+            # every downstream solve/matmat then run at the detected ranks,
+            # mesh placement preserved via the shared node_partition_spec
+            # rule.
+            with obs.span("hss.shrink"):
+                hss, rank_info = shrink_report(hss, mesh=mesh)
+            with obs.span("hss.compress.wait"):
+                jax.block_until_ready(hss.d_leaf)
         beta = self.beta if self.beta is not None else admm_mod.paper_beta(
             d_real)
-        if mesh is not None:
-            fac = factorization.factorize_sharded(
-                hss, beta, mesh, store_dtype=self.store_dtype)
-        else:
-            fac = factorization.factorize(
-                hss, beta, store_dtype=self.store_dtype)
-        jax.block_until_ready(fac.root_lu)
-        t2 = time.perf_counter()
+        with obs.span("hss.factorize") as factorize_span:
+            if mesh is not None:
+                fac = factorization.factorize_sharded(
+                    hss, beta, mesh, store_dtype=self.store_dtype)
+            else:
+                fac = factorization.factorize(
+                    hss, beta, store_dtype=self.store_dtype)
+            jax.block_until_ready(fac.root_lu)
 
-        if mesh is not None:
-            row_sh = NamedSharding(
-                mesh, PartitionSpec(None, tuple(mesh.axis_names)))
-            ys_d = jax.device_put(jnp.asarray(ys), row_sh)
-            pm_d = jax.device_put(jnp.asarray(pmasks), row_sh)
-        else:
-            ys_d, pm_d = jnp.asarray(ys), jnp.asarray(pmasks)
+        with obs.span("hss.upload"):
+            if mesh is not None:
+                row_sh = NamedSharding(
+                    mesh, PartitionSpec(None, tuple(mesh.axis_names)))
+                ys_d = jax.device_put(jnp.asarray(ys), row_sh)
+                pm_d = jax.device_put(jnp.asarray(pmasks), row_sh)
+            else:
+                ys_d, pm_d = jnp.asarray(ys), jnp.asarray(pmasks)
 
         self._hss, self._fac = hss, fac
         self._ys, self._pmask = ys_d, pm_d
@@ -344,8 +357,8 @@ class HSSSVMEngine:
         self._fac_cache = {float(beta): fac}
         self._chunk_fns = {}
         self._report = FitReport(
-            compression_s=t1 - t0,
-            factorization_s=t2 - t1,
+            compression_s=compress_span.seconds,
+            factorization_s=factorize_span.seconds,
             admm_s=0.0,
             memory_mb=hss.memory_bytes() / 1e6,
             hss_levels=t.levels,
@@ -404,8 +417,13 @@ class HSSSVMEngine:
         exactly once.
         """
         assert self._fac is not None, "call prepare() first"
-        if self.task in ("krr", "gp"):
-            return self._train_krr(c_value)
+        with obs.span("hss.train", knob=float(c_value)):
+            if self.task in ("krr", "gp"):
+                return self._train_krr(c_value)
+            return self._train_box(c_value, warm)
+
+    def _train_box(self, c_value: float, warm: tuple[Array, Array] | None
+                   ) -> tuple[EngineModel, tuple[Array, Array]]:
         if self.task == "oneclass" and not 0.0 < c_value <= 1.0:
             # nu > 1 makes e'alpha = 1 infeasible (box mass < 1), nu <= 0
             # divides by zero — either silently yields a garbage model.
@@ -455,25 +473,26 @@ class HSSSVMEngine:
 
         rho_info = None
         with self._active():
-            t0 = time.perf_counter()
-            if adapt:
-                z, mu, z_y, hi_mat, iters_run, rho_info = \
-                    self._train_adaptive(ap, knob, z0, mu0, n_prob)
-            else:
-                z, mu, z_y, hi_mat, iters_run = self._jit_admm(
-                    fac, ys, pmask, knob, z0, mu0)
-            jax.block_until_ready(z)
-            t1 = time.perf_counter()
-            if self.task == "svr":
-                biases = self._jit_bias(
-                    self._hss, ys.T, z, self.svr_c * pmask.T, pmask.T, knob)
-            elif self.task == "oneclass":
-                biases = -self._jit_bias(self._hss, z, hi_mat, pmask.T)
-            else:
-                biases = self._jit_bias(
-                    self._hss, ys.T, z, c_value * pmask.T, pmask.T)
+            with obs.span("hss.admm") as admm_span:
+                if adapt:
+                    z, mu, z_y, hi_mat, iters_run, rho_info = \
+                        self._train_adaptive(ap, knob, z0, mu0, n_prob)
+                else:
+                    z, mu, z_y, hi_mat, iters_run = self._jit_admm(
+                        fac, ys, pmask, knob, z0, mu0)
+                jax.block_until_ready(z)
+            with obs.span("hss.bias"):
+                if self.task == "svr":
+                    biases = self._jit_bias(self._hss, ys.T, z,
+                                            self.svr_c * pmask.T, pmask.T,
+                                            knob)
+                elif self.task == "oneclass":
+                    biases = -self._jit_bias(self._hss, z, hi_mat, pmask.T)
+                else:
+                    biases = self._jit_bias(
+                        self._hss, ys.T, z, c_value * pmask.T, pmask.T)
         if self._report is not None:
-            self._report.admm_s += t1 - t0
+            self._report.admm_s += admm_span.seconds
             self._report.iters_run = tuple(
                 int(i) for i in np.asarray(iters_run))
             if rho_info is not None:
@@ -510,18 +529,18 @@ class HSSSVMEngine:
         if self._jit_admm is None:
             self._jit_admm = jax.jit(krr_mod.krr_solve)
         with self._active():
-            t0 = time.perf_counter()
-            fac = self._fac_for(float(lam))
-            jax.block_until_ready(fac.root_lu)
-            t1 = time.perf_counter()
-            # pads decouple exactly ((1+λ)I block, zero targets); the mask
-            # only clips factorization float noise off the pad coefficients
-            alpha = self._jit_admm(fac, ys.T) * pmask.T
-            jax.block_until_ready(alpha)
-            t2 = time.perf_counter()
+            with obs.span("hss.factorize") as factorize_span:
+                fac = self._fac_for(float(lam))
+                jax.block_until_ready(fac.root_lu)
+            with obs.span("hss.admm") as solve_span:
+                # pads decouple exactly ((1+λ)I block, zero targets); the
+                # mask only clips factorization float noise off the pad
+                # coefficients
+                alpha = self._jit_admm(fac, ys.T) * pmask.T
+                jax.block_until_ready(alpha)
         if self._report is not None:
-            self._report.factorization_s += t1 - t0
-            self._report.admm_s += t2 - t1
+            self._report.factorization_s += factorize_span.seconds
+            self._report.admm_s += solve_span.seconds
             self._report.iters_run = (0,) * n_prob
         model = EngineModel(
             x_perm=self._hss.x, z_y=alpha,

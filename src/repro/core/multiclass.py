@@ -23,13 +23,13 @@ fixed point restricted to participating points solves the pair subproblem.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import admm as admm_mod
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core.hss import HSSMatrix, shrink_report
@@ -168,25 +168,25 @@ class MulticlassHSSSVMTrainer:
         build = ovr_problems if self.strategy == "ovr" else ovo_problems
         ys, pmasks, pairs = build(yp, classes.astype(np.float32), maskp)
 
-        t0 = time.perf_counter()
-        hss = compression.compress(xp, t, self.spec, self.comp)
-        # Adaptive builds shrink to the observed ranks before factorizing:
-        # ALL k class subproblems then share the smaller factors.
-        hss, rank_info = shrink_report(hss)
-        jax.block_until_ready(hss.d_leaf)
-        t1 = time.perf_counter()
+        with obs.span("hss.compress") as compress_span:
+            hss = compression.compress(xp, t, self.spec, self.comp)
+            # Adaptive builds shrink to the observed ranks before
+            # factorizing: ALL k class subproblems then share the smaller
+            # factors.
+            hss, rank_info = shrink_report(hss)
+            jax.block_until_ready(hss.d_leaf)
         beta = self.beta if self.beta is not None else admm_mod.paper_beta(d_real)
-        fac = factorization.factorize(hss, beta)
-        jax.block_until_ready(fac.root_lu)
-        t2 = time.perf_counter()
+        with obs.span("hss.factorize") as factorize_span:
+            fac = factorization.factorize(hss, beta)
+            jax.block_until_ready(fac.root_lu)
 
         self._hss, self._fac = hss, fac
         self._ys, self._pmask = jnp.asarray(ys), jnp.asarray(pmasks)
         self._classes, self._pairs = classes, pairs
         self._jit_admm = None
         self._report = FitReport(
-            compression_s=t1 - t0,
-            factorization_s=t2 - t1,
+            compression_s=compress_span.seconds,
+            factorization_s=factorize_span.seconds,
             admm_s=0.0,
             memory_mb=hss.memory_bytes() / 1e6,
             hss_levels=t.levels,
@@ -220,16 +220,15 @@ class MulticlassHSSSVMTrainer:
             self._jit_admm = jax.jit(_run)
 
         zeros = jnp.zeros((ys.shape[1], ys.shape[0]), ys.dtype)
-        t0 = time.perf_counter()
-        state, _trace = self._jit_admm(
-            fac, ys, c_upper,
-            zeros if warm is None else warm[0],
-            zeros if warm is None else warm[1],
-        )
-        z = jax.block_until_ready(state.z)            # (d, P)
-        t1 = time.perf_counter()
+        with obs.span("hss.admm") as admm_span:
+            state, _trace = self._jit_admm(
+                fac, ys, c_upper,
+                zeros if warm is None else warm[0],
+                zeros if warm is None else warm[1],
+            )
+            z = jax.block_until_ready(state.z)        # (d, P)
         if self._report is not None:
-            self._report.admm_s += t1 - t0
+            self._report.admm_s += admm_span.seconds
 
         y_cols = ys.T                                 # (d, P)
         biases = compute_bias_batched(

@@ -17,13 +17,13 @@ leaving the real block's solves untouched.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import admm as admm_mod
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core.hss import HSSMatrix, shrink_report
@@ -128,23 +128,23 @@ class HSSSVMTrainer:
         yp = jnp.asarray(y_pad[t.perm])
         maskp = jnp.asarray(mask[t.perm].astype(np.float32))
 
-        t0 = time.perf_counter()
-        hss = compression.compress(xp, t, self.spec, self.comp)
-        # Adaptive builds: slice every level to its observed max rank before
-        # the factorization, so factor + every per-iteration solve run at the
-        # detected ranks instead of the cap (shrink time bills to compression).
-        hss, rank_info = shrink_report(hss)
-        jax.block_until_ready(hss.d_leaf)
-        t1 = time.perf_counter()
+        with obs.span("hss.compress") as compress_span:
+            hss = compression.compress(xp, t, self.spec, self.comp)
+            # Adaptive builds: slice every level to its observed max rank
+            # before the factorization, so factor + every per-iteration solve
+            # run at the detected ranks instead of the cap (shrink time bills
+            # to compression).
+            hss, rank_info = shrink_report(hss)
+            jax.block_until_ready(hss.d_leaf)
         beta = self.beta if self.beta is not None else admm_mod.paper_beta(d_real)
-        fac = factorization.factorize(hss, beta)
-        jax.block_until_ready(fac.root_lu)
-        t2 = time.perf_counter()
+        with obs.span("hss.factorize") as factorize_span:
+            fac = factorization.factorize(hss, beta)
+            jax.block_until_ready(fac.root_lu)
 
         self._hss, self._fac, self._y, self._cmask = hss, fac, yp, maskp
         self._report = FitReport(
-            compression_s=t1 - t0,
-            factorization_s=t2 - t1,
+            compression_s=compress_span.seconds,
+            factorization_s=factorize_span.seconds,
             admm_s=0.0,
             memory_mb=hss.memory_bytes() / 1e6,
             hss_levels=t.levels,
@@ -172,16 +172,15 @@ class HSSSVMTrainer:
             self._jit_admm = jax.jit(_run)
 
         zeros = jnp.zeros_like(y)
-        t0 = time.perf_counter()
-        state, trace = self._jit_admm(
-            fac, y, c_vec,
-            zeros if warm is None else warm[0],
-            zeros if warm is None else warm[1],
-        )
-        z = jax.block_until_ready(state.z)
-        t1 = time.perf_counter()
+        with obs.span("hss.admm") as admm_span:
+            state, trace = self._jit_admm(
+                fac, y, c_vec,
+                zeros if warm is None else warm[0],
+                zeros if warm is None else warm[1],
+            )
+            z = jax.block_until_ready(state.z)
         if self._report is not None:
-            self._report.admm_s += t1 - t0
+            self._report.admm_s += admm_span.seconds
             self._report.iters_run = (int(trace.iters_run),)
 
         bias = compute_bias(self._hss, y, z, c_value, mask)

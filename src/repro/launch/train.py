@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 def build_svm_engine(task: str, h: float, rank: int, leaf: int, mesh=None):
     """The launch driver's engine: ``HSSSVMEngine`` with the driver's
@@ -48,25 +50,31 @@ def build_svm_engine(task: str, h: float, rank: int, leaf: int, mesh=None):
 
 def fit_svm_grid(engine, xtr, ytr, xte, yte, c_grid, log=print) -> list:
     """prepare once, then the warm-started knob sweep; returns
-    [(knob, model, holdout metric)] — accuracy for svm, RMSE for krr/gp."""
+    [(knob, model, holdout metric)] — accuracy for svm, RMSE for krr/gp.
+
+    The whole fit is one ``hss.fit`` span (``repro.obs``); each knob's
+    holdout scoring, up to the metric's host read, is an ``hss.predict``."""
     task = engine.task
-    rep = engine.prepare(xtr, ytr)
-    log(f"prepare: compress {rep.compression_s:.1f}s, factorize "
-        f"{rep.factorization_s:.2f}s, HSS {rep.memory_mb:.1f} MB, "
-        f"beta {rep.beta:g}")
-    yte_j = jnp.asarray(yte)
-    knob_name = "λ" if task in ("krr", "gp") else "C"
-    out = []
-    for c, model in zip(c_grid, engine.train_grid(c_grid)):
-        pred = model.predict(jnp.asarray(xte))
-        if task in ("krr", "gp"):
-            metric = float(jnp.sqrt(jnp.mean((pred - yte_j) ** 2)))
-            log(f"{knob_name}={c:g}: holdout rmse {metric:.4f} "
-                f"(admm iters {engine.report.iters_run})")
-        else:
-            metric = float(jnp.mean(pred == yte_j))
-            log(f"{knob_name}={c:g}: holdout acc {metric:.4f}")
-        out.append((c, model, metric))
+    with obs.span("hss.fit", rows=int(xtr.shape[0]),
+                  features=int(xtr.shape[1]), knobs=len(c_grid)):
+        rep = engine.prepare(xtr, ytr)
+        log(f"prepare: compress {rep.compression_s:.1f}s, factorize "
+            f"{rep.factorization_s:.2f}s, HSS {rep.memory_mb:.1f} MB, "
+            f"beta {rep.beta:g}")
+        yte_j = jnp.asarray(yte)
+        knob_name = "λ" if task in ("krr", "gp") else "C"
+        out = []
+        for c, model in zip(c_grid, engine.train_grid(c_grid)):
+            with obs.span("hss.predict", knob=float(c)):
+                pred = model.predict(jnp.asarray(xte))
+                if task in ("krr", "gp"):
+                    metric = float(jnp.sqrt(jnp.mean((pred - yte_j) ** 2)))
+                    log(f"{knob_name}={c:g}: holdout rmse {metric:.4f} "
+                        f"(admm iters {engine.report.iters_run})")
+                else:
+                    metric = float(jnp.mean(pred == yte_j))
+                    log(f"{knob_name}={c:g}: holdout acc {metric:.4f}")
+            out.append((c, model, metric))
     return out
 
 
