@@ -50,9 +50,9 @@ from repro.data import synthetic  # noqa: E402
 from repro.launch.serve import serve_requests  # noqa: E402
 from repro.launch.train import build_svm_engine, fit_svm_grid  # noqa: E402
 
-# The bring-up size.  2^20 rows was the target; the host NEAR-proxy
-# KD-tree query (18 dimensions, ~N^1.8) takes longer than the run's time
-# limit there, so the run uses 2^18 (see CHANGES.md).
+# The bring-up size.  2^20 rows was the target; the NEAR-proxy search
+# was a host KD-tree query (18 dimensions, ~N^1.8) then, too slow for the
+# run's time limit there, so the run uses 2^18 (see CHANGES.md).
 N_TRAIN = 2 ** 18
 N_TEST = 65_536
 LEAF, RANK = 256, 32          # the launch driver's defaults

@@ -107,12 +107,14 @@ def test_compression_error_probe():
     assert err < 8e-2
 
 
-def test_leaf_near_deficit_topup_has_no_duplicates():
-    """Regression: on tiny problems the KD-tree candidate pool runs short and
-    the deficit top-up used to sample the sibling leaf WITH possible repeats
-    of already-placed candidates — duplicate NEAR proxies waste ID sample
-    budget.  Each row must now be duplicate-free whenever the leaf's
-    complement has at least n_near points, and never contain in-leaf points."""
+@pytest.mark.parametrize("on_device", [False, True], ids=["host", "device"])
+def test_leaf_near_deficit_topup_has_no_duplicates(on_device):
+    """Regression: on tiny problems the neighbour candidate pool runs short
+    and the deficit top-up used to sample the sibling leaf WITH possible
+    repeats of already-placed candidates — duplicate NEAR proxies waste ID
+    sample budget.  Each row must now be duplicate-free whenever the leaf's
+    complement has at least n_near points, and never contain in-leaf points,
+    whichever search (host KD-tree or device k-NN) fed the pool."""
     for seed in range(5):
         rng = np.random.default_rng(seed)
         m, levels = 8, 2                       # n = 32, n_near = 8
@@ -121,7 +123,9 @@ def test_leaf_near_deficit_topup_has_no_duplicates():
         t = tree_mod.build_tree(x, leaf_size=m)
         params = compression.CompressionParams(rank=4, n_near=8, n_far=4,
                                                seed=seed)
-        near = compression._host_leaf_near(t, params, x[t.perm])
+        xp = x[t.perm]
+        near = compression._host_leaf_near(
+            t, params, xp, x_device=jnp.asarray(xp) if on_device else None)
         assert near.shape == (2 ** levels, params.n_near)
         leaf_of = np.arange(n) // m
         for i in range(near.shape[0]):
@@ -141,3 +145,77 @@ def test_leaf_near_data_free_fallback_shapes():
     for i in range(near.shape[0]):
         sib = i ^ 1
         assert np.all((near[i] >= sib * m) & (near[i] < (sib + 1) * m))
+
+
+def _knn_rows(case):
+    """(x, leaf size, n_near) of the neighbour-search exactness cases."""
+    if case == "susy-2^14":
+        from bench.data import susy
+        x, _ = susy.generate(2 ** 14, (3141592653, 0))
+        return x, 256, 48
+    if case == "blobs-512":
+        return make_blobs(512, n_features=4, seed=0)[0], 64, 64
+    if case == "tiny-32":
+        return np.random.default_rng(3).normal(
+            size=(32, 2)).astype(np.float32), 8, 8
+    x = make_blobs(1024, n_features=18, seed=1)[0]    # bf16-1024
+    return np.asarray(x.astype(jnp.bfloat16)), 128, 32
+
+
+KNN_CASES = ["susy-2^14", "blobs-512", "tiny-32", "bf16-1024"]
+
+
+@pytest.mark.parametrize("case", KNN_CASES)
+def test_device_knn_matches_the_kdtree_row_for_row(case):
+    """The device k-NN finds the KD-tree's exact neighbour set on every
+    row (the KD-tree ranks in f64; the device in f32-exact products)."""
+    from scipy.spatial import cKDTree
+
+    x, m, n_near = _knn_rows(case)
+    x32 = np.asarray(x, np.float32)
+    k = min(max(2 * n_near // m + 4, 4), len(x))
+    _, want = cKDTree(x32).query(x32, k=k)
+    got = np.asarray(compression._device_knn(
+        jnp.asarray(x), k=k, block=compression._knn_block(len(x))))
+    assert got.shape == (len(x), k) and got.dtype == np.int32
+    np.testing.assert_array_equal(np.sort(got, axis=1),
+                                  np.sort(want, axis=1))
+
+
+@pytest.mark.parametrize("case", KNN_CASES)
+def test_device_leaf_near_equals_the_kdtree_leaf_near(case):
+    x, m, n_near = _knn_rows(case)
+    t = tree_mod.build_tree(np.asarray(x, np.float32), leaf_size=m)
+    xp = x[t.perm]
+    params = compression.CompressionParams(rank=8, n_near=n_near, n_far=8)
+    host = compression._host_leaf_near(t, params, xp)
+    device = compression._host_leaf_near(t, params, xp,
+                                         x_device=jnp.asarray(xp))
+    np.testing.assert_array_equal(device, host)
+
+
+def test_compress_skeletons_equal_a_kdtree_fed_build(monkeypatch):
+    """``compress`` searches on the device; fed the KD-tree's neighbours
+    instead it evaluates the same kernel entries and picks the same
+    skeletons."""
+    x, _ = make_blobs(512, n_features=6, seed=2)
+    t = tree_mod.build_tree(x, leaf_size=32)
+    xp = x[t.perm]
+    spec = KernelSpec(h=1.0)
+    params = compression.CompressionParams(rank=16, n_near=32, n_far=32)
+
+    def build():
+        with compression.counting_kernel_evals() as evals:
+            hss = compression.compress(xp, t, spec, params)
+        return hss, evals["count"]
+
+    dev, dev_evals = build()
+    monkeypatch.setattr(
+        compression, "_device_knn",
+        lambda xd, *, k, block: compression._kdtree_query(
+            np.asarray(xd, np.float32), k))
+    kdt, kdt_evals = build()
+    assert dev_evals == kdt_evals > 0
+    np.testing.assert_array_equal(dev.skel_leaf, kdt.skel_leaf)
+    for a, b in zip(dev.skels, kdt.skels, strict=True):
+        np.testing.assert_array_equal(a, b)

@@ -19,8 +19,7 @@ TREE = {
     "hss.compress": "hss.prepare",
     "hss.far_proxies": "hss.compress",
     "hss.near_search": "hss.compress",
-    "hss.near_search.kdtree": "hss.near_search",
-    "hss.near_search.query": "hss.near_search",
+    "hss.near_search.knn": "hss.near_search",
     "hss.near_search.select": "hss.near_search",
     "hss.compress.leaves": "hss.compress",
     "hss.compress.levels": "hss.compress",
@@ -87,6 +86,37 @@ def test_a_fresh_engine_compiles_or_reads_the_cache(fitted, data):
     c = fit.counters
     assert c.get("jit.compiles", 0) + c.get("jit.cache_reads", 0) >= 1
     assert c.get("jit.traces", 0) >= 1
+
+
+def test_the_local_build_searches_neighbours_on_the_device(fitted, data):
+    _, fit = fitted
+    assert fit.counters["hss.near_search.device"] == 1
+    assert "hss.near_search.host" not in fit.counters
+    # a second fresh engine reuses the module-level k-NN program
+    _, again = _fit(data)
+    knn, = [s for s in again.root.walk() if s.name == "hss.near_search.knn"]
+    assert "jit.traces" not in knn.counters
+    assert again.counters["hss.near_search.device"] == 1
+
+
+def test_the_streamed_build_searches_neighbours_on_the_host(data):
+    import numpy as np
+
+    from repro.core import compression, tree as tree_mod
+    from repro.core.kernelfn import KernelSpec
+
+    x = np.asarray(data[0][:512])
+    t = tree_mod.build_tree(x, leaf_size=64)
+    with obs.span("test.streamed"):
+        compression.compress_streamed(
+            x[t.perm], t, KernelSpec(h=3.0),
+            compression.CompressionParams(rank=8, n_near=16, n_far=16),
+            stream=compression.StreamParams(batch_leaves=4))
+    root, = obs.recent_roots(1, name="test.streamed")
+    assert root.counters["hss.near_search.host"] == 1
+    assert "hss.near_search.device" not in root.counters
+    assert {"hss.near_search.kdtree", "hss.near_search.query",
+            "hss.near_search.select"} <= set(root.seconds)
 
 
 def test_a_thread_starts_its_own_root():

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import admm as admm_mod
+from repro.core import compression
 from repro.core import factorization
 from repro.core.hss import HSSMatrix
 from repro.kernels.compress.kernel import fused_assemble_id_pallas
@@ -130,4 +131,20 @@ def test_admm_run_at_2_20_rows_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes)
     # the leaf factors alone: G (n, m) and E (n, r) in f32
     assert mem.argument_size_in_bytes >= n * (m + r) * 4
+    assert total < HBM_BYTES, total
+
+
+@pytest.mark.parametrize("n", [2 ** 16, 2 ** 20])
+def test_device_knn_fits_one_chip(one_chip, n):
+    """The NEAR search's exact k-NN program for 18-feature rows: at the
+    ``susy.train`` size and at 2^20 rows, with the block it chooses."""
+    k, block = 4, compression._knn_block(n)
+    assert block * n * 4 <= compression._KNN_TILE_BYTES
+    compiled, _ = _compile(
+        lambda x: compression._device_knn(x, k=k, block=block),
+        _sds(one_chip, (n, 18)))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert mem.output_size_in_bytes >= n * k * 4
     assert total < HBM_BYTES, total
