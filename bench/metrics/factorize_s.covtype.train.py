@@ -1,0 +1,7 @@
+"""Factorization of a trained model: the ``hss.factorize`` span, mean over
+the window's models."""
+from bench.metrics._spans import per_model
+
+
+def read(rec: dict) -> float | None:
+    return per_model(rec, lambda t: t.seconds.get("hss.factorize"))

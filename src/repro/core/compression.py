@@ -10,7 +10,8 @@ data geometry to pick the kernel entries that matter.  TPU adaptation
     on the host;
   * skeleton selection per node = interpolative decomposition via pivoted QR
     on the sampled block (repro.core.idqr), vmapped over all nodes of a
-    level;
+    level; a large internal node's transfer matrix is the kernel's own
+    interpolation from its skeleton instead (``_kernel_interp``);
   * total kernel evaluations O(N * n_proxy) — never the full matrix.
 
 Construction cost O(r^2 N) and storage O(r N), matching the paper's claims
@@ -123,6 +124,76 @@ def _batched_row_id(
     return jax.vmap(one)(xc, xp, cmask)
 
 
+def _batched_level_id(
+    spec: KernelSpec,
+    xc: Array,
+    xp: Array,
+    k: int,
+    rtol: float | None,
+    adaptive: bool,
+    cmask: Array | None = None,
+    *,
+    node_rows: int,
+) -> tuple[Array, Array, Array]:
+    """``_batched_row_id`` for an internal level whose nodes hold
+    ``node_rows`` rows each: the same skeletons and ranks, and, from
+    ``KERNEL_INTERP_ROWS`` rows up, ``_kernel_interp``'s transfer matrices
+    in place of the ID's."""
+    piv, t, ranks = _batched_row_id(spec, xc, xp, k, rtol, adaptive, cmask)
+    if node_rows < KERNEL_INTERP_ROWS:
+        return piv, t, ranks
+    return piv, _kernel_interp(spec, xc, piv, ranks, cmask), ranks
+
+
+# Internal nodes of at least this many rows take ``_kernel_interp``'s
+# transfer matrices.  On covtype rows (10 continuous + 44 one-hot columns)
+# at 2^16 rows the ID's transfers of the 8,192-row level and above left
+# K~ with eigenvalues down to -66 against beta = 100 and one seed in three
+# off the exact SVM; the kernel's interpolation at every internal level
+# holds K~ PSD there but is less accurate on SUSY rows, whose accuracy
+# stays within its limit with it from 8,192 rows up (PERF.md, PR 15).
+KERNEL_INTERP_ROWS = 8192
+
+
+# Diagonal shift of each skeleton block K(S, S) before its Cholesky: keeps
+# the f32 factor finite on near-coincident skeletons, and damps (never
+# amplifies) their directions, so the residuals below stay PSD.
+_INTERP_SHIFT = 1e-5
+
+
+def _kernel_interp(spec: KernelSpec, xc: Array, piv: Array, ranks: Array,
+                   cmask: Array | None) -> Array:
+    """(B, m, k) kernel interpolation of every candidate row from its node's
+    live skeleton rows: T = K(xc, xs) (K(xs, xs) + shift I)^-1.
+
+    A large node's candidates are its children's skeletons, and its
+    couplings to the rest of the data are weak and spread over more
+    directions than the rank where the data falls into many groups (one-hot
+    categories): an ID fit to the node's few proxy columns then overshoots
+    off them, and K~ + beta I comes near singular.  With this basis from a
+    level up, the levels above it add, in that level's basis,
+    block-diagonal residuals K(C, C) - T K(S, S) T^T, each at least the
+    Schur complement of K(S, S) in K(C, C), so positive semidefinite,
+    over the root's exact K(C, C).  Dead slots (adaptive ranks) and dead
+    candidate rows get exact zeros.
+    """
+    dtype = xc.dtype
+    xs = jnp.take_along_axis(xc, piv[:, :, None], axis=1)        # (B, k, f)
+    kcs = _batched_kernel_block(spec, xc, xs).astype(jnp.float32)
+    live = rank_mask(ranks, piv.shape[1], jnp.float32)          # (B, k)
+    kss = jnp.take_along_axis(kcs, piv[:, :, None], axis=1)      # (B, k, k)
+    eye = jnp.eye(piv.shape[1], dtype=jnp.float32)
+    kss = (kss * live[:, :, None] * live[:, None, :]
+           + eye * (1.0 - live)[:, :, None] + _INTERP_SHIFT * eye)
+    chol = jnp.linalg.cholesky(kss)
+    rhs = jnp.swapaxes(kcs * live[:, None, :], 1, 2)              # (B, k, m)
+    t = jnp.swapaxes(jax.vmap(lambda c, b: jax.scipy.linalg.cho_solve(
+        (c, True), b))(chol, rhs), 1, 2) * live[:, None, :]
+    if cmask is not None:
+        t = t * cmask[:, :, None]
+    return t.astype(dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class CompressionParams:
     """Accuracy knobs, analogous to the paper's STRUMPACK parameters.
@@ -148,6 +219,17 @@ class CompressionParams:
     seed: int = 0
     rtol: float | None = None
 
+    def __post_init__(self):
+        # a leaf's row ID picks ``rank`` rows of its (leaf × n_proxy)
+        # sampled block, whose rank is at most n_proxy: the rows past it
+        # interpolate nothing, and the model trained on that basis is
+        # wrong while nothing raises
+        if self.rank > self.n_proxy:
+            raise ValueError(
+                f"rank {self.rank} exceeds the {self.n_proxy} proxy columns "
+                f"(n_near {self.n_near} + n_far {self.n_far}) it is chosen "
+                f"from")
+
     @property
     def n_proxy(self) -> int:
         return self.n_near + self.n_far
@@ -168,7 +250,8 @@ def kernel_eval_count(tree: ClusterTree, params: CompressionParams) -> int:
 
     The partially matrix-free build touches O(N · n_proxy) entries instead of
     N² — this counts them exactly (leaf diagonal blocks + leaf sampled
-    blocks + per-level candidate×proxy blocks + B couplings), for the bench's
+    blocks + per-level candidate×proxy and candidate×skeleton blocks + B
+    couplings), for the bench's
     perf trajectory.  Static per (tree, params): the adaptive build masks
     entries but the sampled block SHAPES are the rank cap, so adaptivity
     shows up in stored ranks and factor/solve cost, not here.
@@ -183,8 +266,11 @@ def kernel_eval_count(tree: ClusterTree, params: CompressionParams) -> int:
         total += n_k * r_prev * r_prev                  # sibling couplings B
         if k == K:
             break
+        r_k = min(params.rank, 2 * r_prev)
         total += n_k * (2 * r_prev) * (2 * r_prev + params.n_far)
-        r_prev = min(params.rank, 2 * r_prev)
+        if m * 2 ** k >= KERNEL_INTERP_ROWS:         # candidate×skeleton
+            total += n_k * (2 * r_prev) * r_k
+        r_prev = r_k
     return total
 
 
@@ -482,9 +568,9 @@ def compress(
             prox = jnp.concatenate([sib, far_idx[k]], axis=1)
             xc = jnp.take(x_perm, cand, axis=0)            # (n_k, 2 r_prev, f)
             xp = jnp.take(x_perm, prox, axis=0)
-            piv_k, t_k, rank_k = _batched_row_id(
+            piv_k, t_k, rank_k = _batched_level_id(
                 spec, xc, xp, r_k, rtol, adaptive,
-                cmask=cmask if adaptive else None)
+                cmask=cmask if adaptive else None, node_rows=m * 2 ** k)
             skel_k = jnp.take_along_axis(cand, piv_k, axis=1)
             transfers.append(t_k)
             skels.append(skel_k)
@@ -658,9 +744,9 @@ def compress_sharded(
                     sib = cp.reshape(loc // 2, 2, 2 * rp, f)[:, ::-1]
                     sib = sib.reshape(loc, 2 * rp, f)
                     xp_ = jnp.concatenate([sib, fp], axis=1)
-                    piv, t, rks = _batched_row_id(
+                    piv, t, rks = _batched_level_id(
                         spec, cp, xp_, rk, rtol, adaptive,
-                        cmask=cm if adaptive else None)
+                        cmask=cm if adaptive else None, node_rows=m * 2 ** k)
                     ids = jnp.take_along_axis(ci, piv, axis=1)
                     pts = jax.vmap(
                         lambda c, p: jnp.take(c, p, axis=0))(cp, piv)
@@ -693,9 +779,9 @@ def compress_sharded(
                 sib = sib.reshape(n_k, 2 * r_prev, f)
                 far_pts = jax.device_put(x_host[far_idx[k]], sh_repl)
                 xp_ = jnp.concatenate([sib, far_pts], axis=1)
-                piv_k, t_k, sranks = _batched_row_id(
+                piv_k, t_k, sranks = _batched_level_id(
                     spec, cand_pts, xp_, r_k, rtol, adaptive,
-                    cmask=cmask if adaptive else None)
+                    cmask=cmask if adaptive else None, node_rows=m * 2 ** k)
                 sids = jnp.take_along_axis(cand_ids, piv_k, axis=1)
                 spts = jax.vmap(lambda c, p: jnp.take(c, p, axis=0))(
                     cand_pts, piv_k)
@@ -774,7 +860,7 @@ def _stream_leaf_batch(spec, xl, xp, r0, rtol, adaptive):
     return d, u, piv, rks
 
 
-def _stream_level_batch(spec, cp, xp, cm, rk, rtol, adaptive):
+def _stream_level_batch(spec, cp, xp, cm, rk, rtol, adaptive, node_rows):
     """One node batch of a streamed internal level: sibling couplings B +
     the candidate->proxy row ID.  ``cp`` (b, 2·r_prev, f) candidate points,
     ``xp`` (b, 2·r_prev + n_far, f) proxy points, ``cm`` candidate liveness
@@ -783,8 +869,9 @@ def _stream_level_batch(spec, cp, xp, cm, rk, rtol, adaptive):
     b = _batched_kernel_block(spec, cp[:, :rp], cp[:, rp:])
     if adaptive:
         b = _mask_b(b, cm, rp)
-    piv, t, rks = _batched_row_id(
-        spec, cp, xp, rk, rtol, adaptive, cmask=cm if adaptive else None)
+    piv, t, rks = _batched_level_id(
+        spec, cp, xp, rk, rtol, adaptive, cmask=cm if adaptive else None,
+        node_rows=node_rows)
     return b, piv, t, rks
 
 
@@ -945,7 +1032,7 @@ def compress_streamed(
                 [x_host[sib], x_host[far_idx[k][s:e]]], axis=1))
             cm = jnp.asarray(cm_all[s:e]) if adaptive else None
             b, piv, t, rks = _stream_level_batch(spec, cp, xp, cm, r_k,
-                                                 rtol, adaptive)
+                                                 rtol, adaptive, m * 2 ** k)
             stats.peak_stream_bytes = max(
                 stats.peak_stream_bytes,
                 _device_bytes(cp, xp, b, piv, t, rks))

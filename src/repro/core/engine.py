@@ -281,8 +281,10 @@ class HSSSVMEngine:
                 x, y.astype(np.float32), self.leaf_size,
                 min_levels=self._min_levels())
         mesh = self._mesh = self.mesh
-        with obs.span("hss.tree"):
+        with obs.span("hss.tree") as sp:
             t = tree_mod.build_tree(x_pad, self.leaf_size, levels)
+            sp.attrs.update(split_onehot=t.splits[0],
+                            split_continuous=t.splits[1])
             xp_host = x_pad[t.perm]
             yp = y_pad[t.perm]
             maskp = mask[t.perm]
@@ -418,6 +420,7 @@ class HSSSVMEngine:
         """
         assert self._fac is not None, "call prepare() first"
         with obs.span("hss.train", knob=float(c_value)):
+            obs.count("hss.dual_columns", self.n_problems)
             if self.task in ("krr", "gp"):
                 return self._train_krr(c_value)
             return self._train_box(c_value, warm)

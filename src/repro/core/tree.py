@@ -2,10 +2,21 @@
 
 The paper relies on STRUMPACK's geometry-aware preprocessing (recursive
 clustering + approximate-nearest-neighbour sampling).  TPU adaptation
-(DESIGN.md §3.2): a *perfect* binary tree built by recursive
-widest-dimension median bisection so that every leaf holds exactly
-``leaf_size`` points — all downstream HSS arrays then have static shapes and
+(DESIGN.md §3.2): a *perfect* binary tree whose leaves are consecutive runs
+of exactly ``leaf_size`` points of one cluster order, so every node is a
+contiguous run too — all downstream HSS arrays then have static shapes and
 every per-level operation is a batched (vmapped) dense op.
+
+The order comes from recursive bisection.  A group is split first on a
+two-valued column (a one-hot or other 0/1 feature) into the rows of each
+value, each kept whole, the column whose rarer value is rarest first; a
+group with no such column left is split at the median of its widest
+coordinate into equal halves.  On continuous data this is widest-dimension
+median bisection.  On one-hot data every category's rows are contiguous, so
+the strong same-category kernel entries stay inside nodes; and the
+attribute with the most (and rarest) categories is the outer one of the
+order, so the weaker entries between nodes join rows that share one of the
+few categories of the inner attribute, which a fixed rank can hold.
 
 The tree is built once per dataset on the host (numpy); everything after is
 JAX.  Datasets whose size is not ``leaf_size * 2**levels`` are padded with
@@ -28,11 +39,13 @@ class ClusterTree:
     perm[i]   — original index of the i-th point in tree (leaf-major) order.
     levels    — number of binary splits; n_leaves == 2**levels.
     leaf_size — points per leaf; n == leaf_size * n_leaves.
+    splits    — (on a 0/1 column, at a median) splits of the cluster order.
     """
 
     perm: np.ndarray
     levels: int
     leaf_size: int
+    splits: tuple[int, int] = (0, 0)
 
     @property
     def n(self) -> int:
@@ -48,18 +61,55 @@ class ClusterTree:
         return inv
 
 
-def _split_once(x: np.ndarray, idx: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Split ``idx`` into two equal halves along the widest coordinate."""
-    pts = x[idx]
-    widths = pts.max(axis=0) - pts.min(axis=0)
-    dim = int(np.argmax(widths))
-    order = np.argsort(pts[:, dim], kind="stable")
-    half = idx.shape[0] // 2
-    return idx[order[:half]], idx[order[half:]]
+def _cluster_order(x: np.ndarray, leaf_size: int
+                   ) -> tuple[np.ndarray, tuple[int, int]]:
+    """Row indices of ``x`` in cluster order (module docstring), and the
+    splits made (on a 0/1 column, at a median); a group of at most
+    ``leaf_size`` rows is split on its 0/1 columns only."""
+    lo, hi = x.min(axis=0), x.max(axis=0)
+    two = np.nonzero((hi > lo) & np.all((x == lo) | (x == hi), axis=0))[0]
+    ones = x[:, two] == hi[two]                 # (n, columns) bool
+
+    def count(idx):
+        return np.count_nonzero(ones[idx], axis=0)
+
+    # Each group carries its per-column counts of ``hi`` (None once no
+    # 0/1 column varies in it, which then holds for all its parts); a 0/1
+    # split gathers only its smaller part and takes the other's counts by
+    # subtraction.
+    out, splits = [], [0, 0]
+    stack = [(np.arange(x.shape[0]), count(slice(None)) if two.size else None)]
+    while stack:
+        idx, cnt = stack.pop()
+        m = idx.shape[0]
+        rarer = None if cnt is None else np.minimum(cnt, m - cnt)
+        if rarer is not None and rarer.any():
+            j = int(np.argmin(np.where(rarer > 0, rarer, m)))
+            sel = ones[idx, j]
+            parts = (idx[~sel], idx[sel])
+            small = int(parts[1].shape[0] <= parts[0].shape[0])
+            c_small = count(parts[small])
+            cnts = [cnt - c_small, cnt - c_small]
+            cnts[small] = c_small
+            splits[0] += 1
+        elif m <= leaf_size:
+            out.append(idx)
+            continue
+        else:
+            pts = x[idx]
+            dim = int(np.argmax(pts.max(axis=0) - pts.min(axis=0)))
+            order = np.argsort(pts[:, dim], kind="stable")
+            parts = (idx[order[m // 2:]], idx[order[:m // 2]])
+            cnts = [None, None]
+            splits[1] += 1
+        # pushed second, popped first: the 1s of a 0/1 column, the lower
+        # half of a median split
+        stack.extend(zip(parts, cnts))
+    return np.concatenate(out), tuple(splits)
 
 
 def build_tree(x: np.ndarray, leaf_size: int = 256, levels: int | None = None) -> ClusterTree:
-    """Recursive median-bisection tree. ``len(x)`` must be leaf_size * 2**levels."""
+    """Perfect tree over the cluster order. ``len(x)`` must be leaf_size * 2**levels."""
     n = x.shape[0]
     if levels is None:
         levels = max(int(round(math.log2(n / leaf_size))), 0)
@@ -68,15 +118,9 @@ def build_tree(x: np.ndarray, leaf_size: int = 256, levels: int | None = None) -
             f"n={n} != leaf_size*2**levels={leaf_size * 2 ** levels}; pad first "
             "(see pad_dataset)"
         )
-    groups = [np.arange(n)]
-    for _ in range(levels):
-        nxt = []
-        for g in groups:
-            a, b = _split_once(x, g)
-            nxt.extend((a, b))
-        groups = nxt
-    perm = np.concatenate(groups) if groups else np.arange(n)
-    return ClusterTree(perm=perm, levels=levels, leaf_size=leaf_size)
+    perm, splits = _cluster_order(x, leaf_size)
+    return ClusterTree(perm=perm, levels=levels, leaf_size=leaf_size,
+                       splits=splits)
 
 
 def padded_size(n: int, leaf_size: int) -> tuple[int, int]:

@@ -219,3 +219,75 @@ def test_compress_skeletons_equal_a_kdtree_fed_build(monkeypatch):
     np.testing.assert_array_equal(dev.skel_leaf, kdt.skel_leaf)
     for a, b in zip(dev.skels, kdt.skels, strict=True):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("rank,n_near,n_far", [(113, 48, 64), (128, 48, 64),
+                                               (33, 16, 16)])
+def test_a_rank_above_the_proxy_columns_is_refused(rank, n_near, n_far):
+    # the leaf ID chooses its rank rows from n_near + n_far sampled columns
+    with pytest.raises(ValueError, match="proxy columns"):
+        compression.CompressionParams(rank=rank, n_near=n_near, n_far=n_far)
+
+
+def test_the_launch_driver_refuses_a_rank_above_its_proxies():
+    from repro.launch.train import build_svm_engine
+
+    assert build_svm_engine("svm", 0.5, 112, 256).comp.rank == 112
+    with pytest.raises(ValueError, match="rank 128 exceeds the 112 proxy"):
+        build_svm_engine("svm", 0.5, 128, 256)
+
+
+@pytest.mark.parametrize("ranks", [(12, 12, 12), (12, 5, 9)],
+                         ids=["full", "adaptive"])
+def test_kernel_interp_leaves_a_psd_residual_and_zero_dead_slots(ranks):
+    """T = K(C, S) (K(S, S) + shift)^-1 from each node's live skeletons S:
+    K(C, C) - T K(S, S) T^T is positive semidefinite (what keeps the
+    levels it is used at PSD), skeleton rows interpolate themselves, and
+    dead slots get exact zeros."""
+    from repro.core import compression as comp
+    from repro.core.kernelfn import KernelSpec, kernel_block
+
+    spec = KernelSpec(h=1.0)
+    r = np.random.default_rng(4)
+    xc = jnp.asarray(r.normal(size=(3, 24, 5)), jnp.float32)
+    xp = jnp.asarray(r.normal(size=(3, 40, 5)), jnp.float32)
+    piv, _, _ = comp._batched_row_id(spec, xc, xp, 12, None, False)
+    rk = jnp.asarray(ranks, jnp.int32)
+    t = np.asarray(comp._kernel_interp(spec, xc, piv, rk, None))
+    for i, live in enumerate(ranks):
+        kcc = np.asarray(kernel_block(spec, xc[i], xc[i]), np.float64)
+        s = np.asarray(piv[i][:live])
+        res = kcc - t[i][:, :live] @ kcc[np.ix_(s, s)] @ t[i][:, :live].T
+        assert np.linalg.eigvalsh(res).min() > -1e-4
+        np.testing.assert_allclose(t[i][s, :live], np.eye(live), atol=1e-3)
+        assert not t[i][:, live:].any()
+
+
+def test_large_nodes_take_the_kernel_interp_transfers(monkeypatch):
+    """From ``KERNEL_INTERP_ROWS`` rows up an internal node's transfer is
+    ``_kernel_interp``'s; below it, the ID's."""
+    from repro.core import compression as comp
+    from repro.core.kernelfn import KernelSpec
+
+    r = np.random.default_rng(5)
+    x = r.normal(size=(512, 3)).astype(np.float32)
+    t = tree_mod.build_tree(x, leaf_size=32)              # 4 internal levels
+    xp = jnp.asarray(x[t.perm])
+    spec, params = KernelSpec(h=1.0), comp.CompressionParams(
+        rank=8, n_near=8, n_far=8)
+    ids = comp.compress(xp, t, spec, params)
+    monkeypatch.setattr(comp, "KERNEL_INTERP_ROWS", 128)  # levels 2 and 3
+    mixed = comp.compress(xp, t, spec, params)
+    np.testing.assert_array_equal(np.asarray(ids.transfers[0]),
+                                  np.asarray(mixed.transfers[0]))
+    for k in (1, 2):
+        # the same skeletons, other transfers
+        np.testing.assert_array_equal(np.asarray(ids.skels[k]),
+                                      np.asarray(mixed.skels[k]))
+        assert not np.allclose(np.asarray(ids.transfers[k]),
+                               np.asarray(mixed.transfers[k]), atol=1e-3)
+    v = jnp.asarray(r.normal(size=(512, 2)), jnp.float32)
+    exact = np.asarray(gaussian_block_xla(xp, xp, 1.0) @ v)
+    err = [np.linalg.norm(np.asarray(h.matmat(v)) - exact)
+           for h in (ids, mixed)]
+    assert err[1] < 1.5 * err[0]

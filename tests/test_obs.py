@@ -81,6 +81,32 @@ def test_fit_report_timers_are_the_spans(fitted):
     assert rep.kernel_evals == fit.counters["hss.kernel_evals"]
 
 
+def test_a_binary_fit_trains_one_dual_column(fitted):
+    _, fit = fitted
+    assert fit.counters["hss.dual_columns"] == 1
+
+
+def test_a_seven_class_fit_trains_seven_dual_columns():
+    from bench.data import covtype
+
+    x, y = covtype.generate(2048 + 256, (3, 1))
+    engine = build_svm_engine("svm", 0.5, 16, 256)
+    fit_svm_grid(engine, x[:2048], y[:2048], x[2048:], y[2048:], [1.0, 2.0],
+                 log=lambda _m: None)
+    fit, = obs.recent_roots(1, name="hss.fit")
+    assert fit.counters["hss.dual_columns"] == 14      # 7 columns, 2 knobs
+    tree, = [s for s in fit.root.walk() if s.name == "hss.tree"]
+    # the (wilderness, soil) pairs are split apart before any median split
+    assert tree.attrs["split_onehot"] >= 20
+
+
+def test_the_tree_span_counts_its_splits_by_column_kind(fitted):
+    _, fit = fitted
+    tree, = [s for s in fit.root.walk() if s.name == "hss.tree"]
+    # 2,048 continuous rows in leaves of 64: 31 median splits, no 0/1 one
+    assert tree.attrs == dict(split_onehot=0, split_continuous=31)
+
+
 def test_a_fresh_engine_compiles_or_reads_the_cache(fitted, data):
     _, fit = _fit(data)
     c = fit.counters

@@ -383,3 +383,28 @@ def test_property_pallas_path_kernel_eval_count_unchanged():
         pred = compression.kernel_eval_count(t, params)
         assert counts["xla"] == counts["pallas_interpret"] == pred, (
             case, counts, pred)
+
+
+def test_property_kernel_eval_count_counts_the_kernel_interp_blocks(
+        monkeypatch):
+    """With the transfers of every internal level from ``_kernel_interp``
+    (its threshold lowered to the leaf size), ``kernel_eval_count`` still
+    equals the instrumented count, resident and streamed."""
+    monkeypatch.setattr(compression, "KERNEL_INTERP_ROWS", 16)
+    for levels, rtol in ((3, None), (2, 1e-2)):
+        rng = np.random.default_rng(levels)
+        x = rng.normal(size=(16 * 2 ** levels, 3)).astype(np.float32)
+        t = tree_mod.build_tree(x, leaf_size=16)
+        xp = jnp.asarray(x[t.perm])
+        params = compression.CompressionParams(rank=6, n_near=6, n_far=8,
+                                               rtol=rtol)
+        spec = KernelSpec(h=1.0)
+        pred = compression.kernel_eval_count(t, params)
+        with compression.counting_kernel_evals() as ctr:
+            compression.compress(xp, t, spec, params)
+        assert ctr["count"] == pred
+        with compression.counting_kernel_evals() as ctr:
+            compression.compress_streamed(
+                np.asarray(xp), t, spec, params,
+                stream=compression.StreamParams(batch_leaves=2))
+        assert ctr["count"] == pred

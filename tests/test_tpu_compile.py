@@ -109,10 +109,11 @@ def _hss_struct(sharding, n, m, r, f):
         levels=levels, leaf_size=m)
 
 
-def test_admm_run_at_2_20_rows_fits_one_chip(one_chip):
+def _admm_fits_one_chip(one_chip, n, f, columns):
     """The engine's ADMM program (10 iterations of the HSS solve on the
-    warm-started C grid's state) at 2^20 rows, leaf 256, rank 32."""
-    n, m, r, f, beta, max_it = 2 ** 20, 256, 32, 18, 100.0, 10
+    warm-started C grid's state) for ``columns`` dual columns, leaf 256,
+    rank 32."""
+    m, r, beta, max_it = 256, 32, 100.0, 10
     hss = _hss_struct(one_chip, n, m, r, f)
     fac = jax.eval_shape(lambda h: factorization.factorize(h, beta), hss)
     fac = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), fac)
@@ -124,14 +125,24 @@ def test_admm_run_at_2_20_rows_fits_one_chip(one_chip):
         return state.z, state.mu, trace.iters_run
 
     compiled, _ = _compile(
-        run, fac, _sds(one_chip, (1, n)), _sds(one_chip, (1, n)),
-        _sds(one_chip, ()), _sds(one_chip, (n, 1)), _sds(one_chip, (n, 1)))
+        run, fac, _sds(one_chip, (columns, n)), _sds(one_chip, (columns, n)),
+        _sds(one_chip, ()), _sds(one_chip, (n, columns)),
+        _sds(one_chip, (n, columns)))
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     # the leaf factors alone: G (n, m) and E (n, r) in f32
     assert mem.argument_size_in_bytes >= n * (m + r) * 4
     assert total < HBM_BYTES, total
+
+
+def test_admm_run_at_2_20_rows_fits_one_chip(one_chip):
+    _admm_fits_one_chip(one_chip, 2 ** 20, 18, 1)
+
+
+def test_admm_run_with_seven_ovr_columns_fits_one_chip(one_chip):
+    """The ``covtype.train`` size: 2^16 rows of 54 features, 7 columns."""
+    _admm_fits_one_chip(one_chip, 2 ** 16, 54, 7)
 
 
 @pytest.mark.parametrize("n", [2 ** 16, 2 ** 20])
@@ -147,4 +158,17 @@ def test_device_knn_fits_one_chip(one_chip, n):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert mem.output_size_in_bytes >= n * k * 4
+    assert total < HBM_BYTES, total
+
+
+def test_device_knn_fits_one_chip_on_covtype_rows(one_chip):
+    """The k-NN program for the ``covtype.train`` rows: 2^16 x 54."""
+    n, k = 2 ** 16, 4
+    compiled, _ = _compile(
+        lambda x: compression._device_knn(
+            x, k=k, block=compression._knn_block(n)),
+        _sds(one_chip, (n, 54)))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total

@@ -53,3 +53,92 @@ def test_build_tree_rejects_bad_n():
     x = np.zeros((100, 2), np.float32)
     with pytest.raises(ValueError):
         tree_mod.build_tree(x, leaf_size=32, levels=2)
+
+
+def test_susy_rows_give_a_valid_permutation_and_only_continuous_splits():
+    from bench.data import susy
+
+    x, y = susy.generate(3000, (7, 1))
+    xp, _, mask, levels = tree_mod.pad_dataset(x, y, leaf_size=256)
+    t = tree_mod.build_tree(xp, leaf_size=256, levels=levels)
+    assert t.n == 4096 and sorted(t.perm.tolist()) == list(range(4096))
+    # no column holds only 0s and 1s, so every split is along a continuous one
+    assert not np.all((xp == 0) | (xp == 1), axis=0).any()
+    # the inert pads, far out along the first axis, fill the last leaves
+    assert not mask[t.perm][-1024:].any()
+
+
+def _widest_median_bisection(x, leaf_size, levels):
+    groups = [np.arange(x.shape[0])]
+    for _ in range(levels):
+        nxt = []
+        for g in groups:
+            pts = x[g]
+            dim = int(np.argmax(pts.max(axis=0) - pts.min(axis=0)))
+            order = np.argsort(pts[:, dim], kind="stable")
+            half = g.shape[0] // 2
+            nxt.extend((g[order[:half]], g[order[half:]]))
+        groups = nxt
+    return np.concatenate(groups)
+
+
+@pytest.mark.parametrize("rows,features", [(4096, 18), (2048, 3)])
+def test_continuous_rows_give_widest_median_bisection(rows, features):
+    r = np.random.default_rng(rows)
+    x = r.normal(size=(rows, features)).astype(np.float32)
+    t = tree_mod.build_tree(x, leaf_size=64)
+    np.testing.assert_array_equal(
+        t.perm, _widest_median_bisection(x, 64, t.levels))
+
+
+def _combos(x):
+    """One id per (wilderness, soil) pair of covtype-shaped rows."""
+    return np.argmax(x[:, 10:14], axis=1) * 40 + np.argmax(x[:, 14:], axis=1)
+
+
+def test_covtype_rows_keep_each_category_contiguous():
+    from bench.data import covtype
+
+    x, _ = covtype.generate(16384, (5, 1))
+    t = tree_mod.build_tree(x, leaf_size=256)
+    assert t.levels == 6 and sorted(t.perm.tolist()) == list(range(16384))
+    ids = _combos(x)[t.perm]
+    # every (wilderness, soil) pair is one run of the order, so each node
+    # of the perfect tree (a run of it) cuts at most two pairs
+    runs = 1 + np.count_nonzero(ids[1:] != ids[:-1])
+    assert runs == np.unique(ids).shape[0]
+    def per_leaf(perm):
+        return np.mean([np.unique(leaf).shape[0]
+                        for leaf in _combos(x)[perm].reshape(64, 256)])
+
+    # widest-coordinate bisection splits on continuous columns only and
+    # leaves ~80 pairs in a leaf here
+    widest = per_leaf(_widest_median_bisection(x, 256, 6))
+    assert widest > 60 and per_leaf(t.perm) < widest / 10
+    # soil, the attribute of rarer categories, is mostly the outer one of
+    # the order: its 40 values change far less often than the 4 areas do
+    def changes(col):
+        return np.count_nonzero(col[1:] != col[:-1])
+
+    soil = changes(np.argmax(x[t.perm, 14:], axis=1))
+    area = changes(np.argmax(x[t.perm, 10:14], axis=1))
+    assert soil < 80 and 2 * soil < area, (soil, area)
+
+
+def test_one_hot_leaves_are_tight_in_the_continuous_columns():
+    from bench.data import covtype
+
+    x, _ = covtype.generate(8192, (6, 1))
+    t = tree_mod.build_tree(x, leaf_size=128)
+    xp = x[t.perm]
+    ids = _combos(x)[t.perm]
+    # inside a pair's run the continuous columns are bisected: a leaf that
+    # holds one pair spans less than the pair's whole range
+    for leaf in range(t.n_leaves):
+        s = slice(leaf * 128, (leaf + 1) * 128)
+        pair = ids[s]
+        if np.all(pair == pair[0]) and np.sum(ids == pair[0]) >= 512:
+            whole = xp[ids == pair[0], :10]
+            part = xp[s, :10]
+            assert np.any((part.max(0) - part.min(0))
+                          < 0.75 * (whole.max(0) - whole.min(0)))
