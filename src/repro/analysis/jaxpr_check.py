@@ -308,36 +308,42 @@ def check_streamed_stage() -> list[Finding]:
     return findings
 
 
-def check_recompile_engine(c_grid=(0.5, 1.0, 2.0, 4.0)) -> list[Finding]:
-    """A warm-started C-sweep on the engine must compile the ADMM run
-    exactly once (PR 5's traced-scalar knob convention, end to end)."""
+def check_recompile_engine(c_grid=(0.5, 1.0, 2.0, 4.0), engines: int = 1,
+                           rows: int = 256) -> list[Finding]:
+    """``engines`` fresh engines of one shape, each running a warm-started
+    C-sweep one after the other, must trace the shared ADMM program at most
+    once in all (the traced-scalar knob convention, end to end).
+
+    Reads the ``hss.admm.traces`` counter, which the program bumps each
+    time jax traces it.  One trace serves the whole sweep and every later
+    engine of the same shapes; none at all if an earlier engine of this
+    process traced them.  More than one means a knob reaches jit as a fresh
+    Python value instead of a traced ``jnp.asarray`` scalar, or something
+    differs from engine to engine."""
+    from repro import obs
     from repro.core import compression
     from repro.core.engine import HSSSVMEngine
     from repro.core.kernelfn import KernelSpec
 
-    x, y = _blobs(256)
-    engine = HSSSVMEngine(
-        spec=KernelSpec(h=1.0),
-        comp=compression.CompressionParams(rank=16, n_near=16, n_far=24),
-        leaf_size=32, max_it=4)
-    engine.prepare(x, y)
-    engine.train_grid(list(c_grid))
-    findings = []
-    cache_size = getattr(engine._jit_admm, "_cache_size", lambda: None)()
-    if cache_size is None:
-        findings.append(_finding(
-            "engine.train_grid",
-            "cannot read the jit cache size on this jax version — "
-            "recompile guard inconclusive"))
-    elif cache_size != 1:
-        sigs = abstract_signature(jnp.asarray(c_grid[0], jnp.float32))
-        findings.append(_finding(
-            "engine.train_grid",
-            f"{len(c_grid)}-point C-sweep compiled {cache_size}x "
-            f"(expected 1): a knob is reaching jit as a fresh Python "
-            f"value instead of a traced jnp.asarray scalar "
-            f"(expected signature per call: {sigs})"))
-    return findings
+    x, y = _blobs(rows)
+    before = obs.total("hss.admm.traces")
+    for _ in range(engines):
+        engine = HSSSVMEngine(
+            spec=KernelSpec(h=1.0),
+            comp=compression.CompressionParams(rank=16, n_near=16, n_far=24),
+            leaf_size=32, max_it=4)
+        engine.prepare(x, y)
+        engine.train_grid(list(c_grid))
+    traces = obs.total("hss.admm.traces") - before
+    if traces <= 1:
+        return []
+    sigs = abstract_signature(jnp.asarray(c_grid[0], jnp.float32))
+    return [_finding(
+        "engine.train_grid",
+        f"{engines} engine(s) x {len(c_grid)}-point C-sweep traced the ADMM "
+        f"program {traces}x (expected at most 1): a knob is reaching jit as "
+        f"a fresh Python value instead of a traced jnp.asarray scalar "
+        f"(expected signature per call: {sigs}), or the engines differ")]
 
 
 def check_serve_path() -> list[Finding]:

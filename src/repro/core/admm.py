@@ -311,9 +311,8 @@ def adaptive_rho_outer(
 
     ``run_chunk(beta, n_it, z0, mu0, done0) -> (ADMMState, ADMMTrace)`` runs
     ``n_it`` iterations at penalty β — the caller owns the factorization of
-    K̃ + βI a rescale implies (the engine passes a jitted chunk that takes
-    the factorization as a pytree argument, so chunks never recompile across
-    β values).  Between chunks the last live residuals are balanced:
+    K̃ + βI a rescale implies (the engine passes its process-wide jitted
+    ADMM program, which takes the factorization as a pytree argument).  Between chunks the last live residuals are balanced:
     primal > ρ_μ·dual ⟹ β ← τβ, dual > ρ_μ·primal ⟹ β ← β/τ, at most
     ``rho_max_updates`` times.  The UNSCALED multiplier μ is carried across
     a rescale — it is the β-invariant quantity (Boyd eq. 3.14 rescales the

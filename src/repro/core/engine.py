@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
@@ -137,6 +138,46 @@ class EngineModel:
         return jnp.asarray(self.classes)[idx]
 
 
+def _build_task(task_name: str, svr_c: float, ys, pmask, knob):
+    """The engine's knob → BoxQPTask rule."""
+    if task_name == "svr":
+        return tasks_mod.svr_task(ys, svr_c * pmask, knob)
+    if task_name == "oneclass":
+        return tasks_mod.one_class_task(pmask, knob)
+    return admm_mod.svm_task(ys, knob * pmask)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("boxqp", "task_name", "svr_c", "max_it", "tol"))
+def _admm_run(fac, ys, pmask, knob, z0, mu0, done0=None, *, boxqp,
+              task_name: str, svr_c: float, max_it: int, tol: float | None):
+    """The batched box-QP ADMM run of every engine, jitted once per process.
+
+    The factorization is a pytree argument and the knob a traced scalar, so
+    engines that agree on shapes, dtypes, shardings and the static task
+    values share one trace and one loaded executable.  ``boxqp`` is
+    ``admm.admm_boxqp`` as bound at the call: static, so the process-wide
+    cache never serves a program traced from another binding of it (a
+    test that replaces the solver gets a trace of its own).  Without
+    ``done0`` (a fixed-length run) it returns ``(z, mu, sign * z, hi,
+    iters_run)``; with it (one chunk of the adaptive-ρ loop) ``(state,
+    trace, sign * z, hi)``.  ``hi`` is the (d, P) box bound for one-class,
+    else ``()``.
+    """
+    obs.count("hss.admm.traces")      # runs only while jax traces
+    task = _build_task(task_name, svr_c, ys, pmask, knob)
+    state, trace = boxqp(
+        fac.solve_mat, task, fac.beta, max_it, tol=tol, z0=z0, mu0=mu0,
+        done0=done0)
+    # only the oneclass rho extraction needs the box bounds — skip
+    # materializing the (d, P) hi block everywhere else
+    hi = task.hi if task_name == "oneclass" else ()
+    if done0 is None:
+        return state.z, state.mu, task.sign * state.z, hi, trace.iters_run
+    return state, trace, task.sign * state.z, hi
+
+
 @dataclasses.dataclass
 class HSSSVMEngine:
     """partition → compress → factorize → ADMM → bias/predict, local or mesh.
@@ -211,7 +252,6 @@ class HSSSVMEngine:
     _xp_host: np.ndarray | None = None     # padded+permuted points (host)
     _maskp_host: np.ndarray | None = None  # (d,) real-point mask (host)
     _fac_cache: dict | None = None         # beta -> factorization
-    _chunk_fns: dict | None = None         # chunk length -> jitted runner
 
     # ------------------------------------------------------------------ #
     @contextlib.contextmanager
@@ -357,7 +397,6 @@ class HSSSVMEngine:
         self._xp_host = xp_host
         self._maskp_host = maskp.astype(np.float32)
         self._fac_cache = {float(beta): fac}
-        self._chunk_fns = {}
         self._report = FitReport(
             compression_s=compress_span.seconds,
             factorization_s=factorize_span.seconds,
@@ -447,22 +486,6 @@ class HSSSVMEngine:
                 self._jit_bias = jax.jit(tasks_mod.compute_rho_oneclass_batched)
             else:
                 self._jit_bias = jax.jit(compute_bias_batched)
-        if not adapt and self._jit_admm is None:
-            max_it, tol = eff_max_it, eff_tol
-            task_name, svr_c = self.task, self.svr_c
-
-            def _run(fac_, ys_, pmask_, knob, z0, mu0):
-                task = self._build_task(task_name, svr_c, ys_, pmask_, knob)
-                state, trace = admm_mod.admm_boxqp(
-                    fac_.solve_mat, task, fac_.beta, max_it, tol=tol,
-                    z0=z0, mu0=mu0)
-                # only the oneclass rho extraction needs the box bounds —
-                # skip materializing the (d, P) hi block everywhere else
-                hi = task.hi if task_name == "oneclass" else ()
-                return (state.z, state.mu, task.sign * state.z, hi,
-                        trace.iters_run)
-
-            self._jit_admm = jax.jit(_run)
 
         if self._mesh is None:
             zeros = jnp.zeros((d, n_prob), jnp.float32)
@@ -481,8 +504,10 @@ class HSSSVMEngine:
                     z, mu, z_y, hi_mat, iters_run, rho_info = \
                         self._train_adaptive(ap, knob, z0, mu0, n_prob)
                 else:
-                    z, mu, z_y, hi_mat, iters_run = self._jit_admm(
-                        fac, ys, pmask, knob, z0, mu0)
+                    z, mu, z_y, hi_mat, iters_run = _admm_run(
+                        fac, ys, pmask, knob, z0, mu0,
+                        boxqp=admm_mod.admm_boxqp, task_name=self.task,
+                        svr_c=self.svr_c, max_it=eff_max_it, tol=eff_tol)
                 jax.block_until_ready(z)
             with obs.span("hss.bias"):
                 if self.task == "svr":
@@ -600,15 +625,6 @@ class HSSSVMEngine:
         return out
 
     # ------------------------------------------------------------------ #
-    @staticmethod
-    def _build_task(task_name: str, svr_c: float, ys_, pmask_, knob):
-        """The engine's knob → BoxQPTask rule (shared by both ADMM paths)."""
-        if task_name == "svr":
-            return tasks_mod.svr_task(ys_, svr_c * pmask_, knob)
-        if task_name == "oneclass":
-            return tasks_mod.one_class_task(pmask_, knob)
-        return admm_mod.svm_task(ys_, knob * pmask_)
-
     def _fac_for(self, beta: float) -> factorization.HSSFactorization:
         """Factorization of K̃ + βI, cached per visited β.
 
@@ -631,32 +647,21 @@ class HSSSVMEngine:
                         n_prob: int):
         """Residual-balancing adaptive-ρ run (Boyd §3.4.1).
 
-        The chunk runner is jitted ONCE per chunk length with the
-        factorization as a pytree argument, so β rescales never recompile —
-        they only swap which cached factorization is passed in.
+        Each chunk is :func:`_admm_run` with its length static and the
+        factorization as a pytree argument: a chunk length traces once, and
+        a β rescale only swaps which cached factorization is passed in (β is
+        the factorization's static field, so each visited β traces once).
         """
         ys, pmask = self._ys, self._pmask
-        task_name, svr_c = self.task, self.svr_c
-
-        def make_chunk(n_it: int):
-            def _chunk(fac_, ys_, pmask_, knob_, z0_, mu0_, done0_):
-                task = self._build_task(task_name, svr_c, ys_, pmask_, knob_)
-                state, trace = admm_mod.admm_boxqp(
-                    fac_.solve_mat, task, fac_.beta, n_it, tol=ap.tol,
-                    z0=z0_, mu0=mu0_, done0=done0_)
-                hi = task.hi if task_name == "oneclass" else ()
-                return state, trace, task.sign * state.z, hi
-            return jax.jit(_chunk)
-
         last = {}
 
         def run_chunk(beta, n_it, z, mu, done):
             fac_b = self._fac_for(beta)
-            fn = self._chunk_fns.get(n_it)
-            if fn is None:
-                fn = self._chunk_fns[n_it] = make_chunk(n_it)
             done = jnp.zeros((n_prob,), bool) if done is None else done
-            state, trace, z_y, hi = fn(fac_b, ys, pmask, knob, z, mu, done)
+            state, trace, z_y, hi = _admm_run(
+                fac_b, ys, pmask, knob, z, mu, done,
+                boxqp=admm_mod.admm_boxqp, task_name=self.task,
+                svr_c=self.svr_c, max_it=n_it, tol=ap.tol)
             last["z_y"], last["hi"] = z_y, hi
             return state, trace
 

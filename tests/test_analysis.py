@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.analysis import baseline as baseline_mod
 from repro.analysis import jaxpr_check
 from repro.analysis.__main__ import main as cli_main
@@ -197,6 +198,15 @@ def test_engine_c_sweep_compiles_once():
     """The recompile-count guard: 4 grid points, ONE compile (PR 5)."""
     findings = jaxpr_check.check_recompile_engine()
     assert findings == [], [f.render() for f in findings]
+
+
+def test_two_same_shape_engines_c_sweeps_trace_once():
+    """Two fresh engines of one shape, one sweep each: one trace of the
+    shared ADMM program in all, the first engine's."""
+    before = obs.total("hss.admm.traces")
+    findings = jaxpr_check.check_recompile_engine(engines=2, rows=512)
+    assert findings == [], [f.render() for f in findings]
+    assert obs.total("hss.admm.traces") - before == 1
 
 
 # --------------------------------------------------------------------- #

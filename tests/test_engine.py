@@ -20,11 +20,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import admm as admm_mod
+from repro.core import engine as engine_mod
+from repro.core import tasks as tasks_mod
 from repro.core.compression import CompressionParams
 from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
 from repro.core.multiclass import MulticlassHSSSVMTrainer
-from repro.core.svm import HSSSVMTrainer
+from repro.core.svm import HSSSVMTrainer, compute_bias_batched
 from repro.data import synthetic
 from repro.launch.mesh import make_mesh
 
@@ -128,6 +131,135 @@ def test_engine_ovo_strategy():
     assert model.pairs is not None
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == jnp.asarray(yte)))
     assert acc > 0.9, acc
+
+
+# --------------------------------------------------------------------- #
+# the shared ADMM program against the per-engine closure it replaced    #
+# --------------------------------------------------------------------- #
+def _closure_build_task(task_name, svr_c, ys_, pmask_, knob):
+    if task_name == "svr":
+        return tasks_mod.svr_task(ys_, svr_c * pmask_, knob)
+    if task_name == "oneclass":
+        return tasks_mod.one_class_task(pmask_, knob)
+    return admm_mod.svm_task(ys_, knob * pmask_)
+
+
+def _closure_body(engine):
+    """The fixed-length ADMM run as every engine used to build it: a fresh
+    closure over the engine's task values (verbatim)."""
+    max_it, tol = engine.max_it, engine.tol
+    task_name, svr_c = engine.task, engine.svr_c
+
+    def _run(fac_, ys_, pmask_, knob, z0, mu0):
+        task = _closure_build_task(task_name, svr_c, ys_, pmask_, knob)
+        state, trace = admm_mod.admm_boxqp(
+            fac_.solve_mat, task, fac_.beta, max_it, tol=tol,
+            z0=z0, mu0=mu0)
+        hi = task.hi if task_name == "oneclass" else ()
+        return (state.z, state.mu, task.sign * state.z, hi,
+                trace.iters_run)
+
+    return _run
+
+
+def _closure_chunk(engine, n_it, tol):
+    """One chunk of the adaptive-ρ run as every engine used to build it
+    (verbatim)."""
+    task_name, svr_c = engine.task, engine.svr_c
+
+    def _chunk(fac_, ys_, pmask_, knob_, z0_, mu0_, done0_):
+        task = _closure_build_task(task_name, svr_c, ys_, pmask_, knob_)
+        state, trace = admm_mod.admm_boxqp(
+            fac_.solve_mat, task, fac_.beta, n_it, tol=tol,
+            z0=z0_, mu0=mu0_, done0=done0_)
+        hi = task.hi if task_name == "oneclass" else ()
+        return state, trace, task.sign * state.z, hi
+    return jax.jit(_chunk)
+
+
+def _problem(case):
+    if case == "covtype7":
+        from bench.data import covtype
+
+        x, y = covtype.generate(3072, (5, 1))
+        return x, y, KernelSpec(h=0.5), 128, None
+    x, y, _, _ = synthetic.train_test("blobs", 1024, 8, seed=3, sep=1.6)
+    return x, y, KernelSpec(h=1.0), 64, (1e-3 if case == "binary_tol"
+                                         else None)
+
+
+@pytest.mark.parametrize("case", ["binary", "binary_tol", "covtype7"])
+def test_shared_admm_program_matches_the_per_engine_closure(case):
+    """Same jaxpr as the closure, and bit-identical z, mu, z_y, biases and
+    iteration counts, on a cold knob and a warm-started second one."""
+    x, y, spec, leaf, tol = _problem(case)
+    engine = HSSSVMEngine(spec=spec, comp=COMP, leaf_size=leaf, max_it=10,
+                          tol=tol)
+    engine.prepare(x, y)
+    if case == "covtype7":
+        assert engine.n_problems == 7
+    fac, ys, pmask = engine.fac, engine.problem_labels, engine.problem_masks
+    body = _closure_body(engine)
+    zeros = jnp.zeros((ys.shape[1], ys.shape[0]), jnp.float32)
+    args = (fac, ys, pmask, jnp.asarray(1.0, jnp.float32), zeros, zeros)
+    old = jax.make_jaxpr(jax.jit(body))(*args).eqns[0].params["jaxpr"]
+    new = jax.make_jaxpr(lambda *a: engine_mod._admm_run(
+        *a, boxqp=admm_mod.admm_boxqp, task_name=engine.task,
+        svr_c=engine.svr_c, max_it=engine.max_it,
+        tol=engine.tol))(*args).eqns[0].params["jaxpr"]
+    assert str(new) == str(old)
+
+    run, bias = jax.jit(body), jax.jit(compute_bias_batched)
+    warm, ref_warm = None, (zeros, zeros)
+    for c in (1.0, 2.0):
+        model, warm = engine.train(c, warm=warm)
+        z, mu, z_y, _hi, iters = run(fac, ys, pmask,
+                                     jnp.asarray(c, jnp.float32), *ref_warm)
+        ref_warm = (z, mu)
+        b = bias(engine.hss, ys.T, z, c * pmask.T, pmask.T)
+        np.testing.assert_array_equal(np.asarray(warm[0]), np.asarray(z))
+        np.testing.assert_array_equal(np.asarray(warm[1]), np.asarray(mu))
+        np.testing.assert_array_equal(np.asarray(model.z_y), np.asarray(z_y))
+        np.testing.assert_array_equal(np.asarray(model.biases),
+                                      np.asarray(b))
+        assert engine.report.iters_run == tuple(int(i) for i in iters)
+
+
+def test_adaptive_rho_engine_matches_the_per_engine_chunks():
+    """The adaptive-ρ path on the shared program: the same β rescales and
+    bit-identical iterates as the per-engine chunk closures."""
+    x, y, _, _ = synthetic.train_test("blobs", 512, 8, seed=4, sep=1.6)
+    ap = admm_mod.ADMMParams(max_it=60, tol=1e-3, adapt_rho=True,
+                             rho_every=5, rho_max_updates=4)
+    engine = HSSSVMEngine(spec=KernelSpec(h=1.0), comp=COMP, leaf_size=64,
+                          beta=1e4, admm=ap)
+    engine.prepare(x, y)
+    model, (z, mu) = engine.train(1.0)
+    assert engine.report.rho_rescales > 0
+
+    ys, pmask = engine.problem_labels, engine.problem_masks
+    knob = jnp.asarray(1.0, jnp.float32)
+    chunks = {}
+
+    def run_chunk(beta, n_it, z_, mu_, done):
+        fn = chunks.setdefault(n_it, _closure_chunk(engine, n_it, ap.tol))
+        done = jnp.zeros((ys.shape[0],), bool) if done is None else done
+        state, trace, z_y, _hi = fn(engine._fac_for(beta), ys, pmask, knob,
+                                    z_, mu_, done)
+        chunks["z_y"] = z_y
+        return state, trace
+
+    zeros = jnp.zeros((ys.shape[1], ys.shape[0]), jnp.float32)
+    state, trace, info = admm_mod.adaptive_rho_outer(
+        run_chunk, 1e4, ap, z0=zeros, mu0=zeros)
+    assert engine.report.rho_rescales == info["rescales"]
+    assert engine.report.rho_final == info["beta"]
+    assert engine.report.iters_run == tuple(
+        int(i) for i in np.asarray(trace.iters_run))
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(state.z))
+    np.testing.assert_array_equal(np.asarray(mu), np.asarray(state.mu))
+    np.testing.assert_array_equal(np.asarray(model.z_y),
+                                  np.asarray(chunks["z_y"]))
 
 
 # --------------------------------------------------------------------- #

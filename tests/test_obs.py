@@ -114,6 +114,22 @@ def test_a_fresh_engine_compiles_or_reads_the_cache(fitted, data):
     assert c.get("jit.traces", 0) >= 1
 
 
+def test_the_admm_program_is_traced_once_per_shape(fitted, data):
+    """A second engine of the same shapes reuses the module-level ADMM
+    program; an engine of another row count traces it once more."""
+    _, again = _fit(data)
+    assert again.counters.get("hss.admm.traces", 0) == 0
+    admm, = [s for s in again.root.walk() if s.name == "hss.admm"]
+    assert "jit.traces" not in admm.counters
+    xtr, ytr, xte, yte = data
+    engine = build_svm_engine("svm", 3.0, 16, 64)
+    fit_svm_grid(engine, xtr[:1024], ytr[:1024], xte, yte, [1.0],
+                 log=lambda _m: None)
+    fit, = obs.recent_roots(1, name="hss.fit")
+    assert fit.root.attrs["rows"] == 1024
+    assert fit.counters["hss.admm.traces"] == 1
+
+
 def test_the_local_build_searches_neighbours_on_the_device(fitted, data):
     _, fit = fitted
     assert fit.counters["hss.near_search.device"] == 1
