@@ -20,8 +20,8 @@ import numpy as np
 
 from repro.core import baselines
 from repro.core.compression import CompressionParams
+from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
-from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
 
 
@@ -68,17 +68,17 @@ def run(csv_rows: list) -> None:
                          f"acc={acc:.4f};runtime_s={t_nys:.3f}"))
 
         # ---- HSS ADMM (ours) ----
-        trainer = HSSSVMTrainer(
+        engine = HSSSVMEngine(
             spec=spec, comp=CompressionParams(rank=32, n_near=48, n_far=64),
             leaf_size=128, max_it=10)
         t0 = time.perf_counter()
-        model = trainer.fit(xtr, ytr, c_value=c_val)
+        model = engine.fit(xtr, ytr, c_value=c_val)
         t_hss = time.perf_counter() - t0
         acc = float(jnp.mean(model.predict(xtj) == yte))
         csv_rows.append((
             f"svm_table23/hss_admm/n{n_train}", t_hss * 1e6,
             f"acc={acc:.4f};runtime_s={t_hss:.3f};"
-            f"admm_only_s={trainer.report.admm_s:.3f}"))
+            f"admm_only_s={engine.report.admm_s:.3f}"))
 
 
 if __name__ == "__main__":

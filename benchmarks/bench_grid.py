@@ -8,12 +8,8 @@ from __future__ import annotations
 
 import time
 
-import jax.numpy as jnp
-import numpy as np
-
 from repro.core.compression import CompressionParams
-from repro.core.kernelfn import KernelSpec
-from repro.core.svm import HSSSVMTrainer, grid_search
+from repro.core.engine import grid_search
 from repro.data import synthetic
 
 HS = (0.3, 1.0, 3.0)
@@ -25,7 +21,7 @@ def run(csv_rows: list) -> None:
         "circles", 8192, 2048, seed=2, n_features=4, gap=0.6, noise=0.25)
     t0 = time.perf_counter()
     model, info = grid_search(
-        xtr, ytr, xte, yte, hs=HS, cs=CS,
+        xtr, ytr, xte, yte, hs=HS, knobs=CS,
         trainer_kwargs=dict(
             comp=CompressionParams(rank=32, n_near=48, n_far=64),
             leaf_size=256, max_it=10))

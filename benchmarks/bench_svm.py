@@ -47,8 +47,6 @@ import numpy as np
 from repro.core.compression import CompressionParams
 from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
-from repro.core.multiclass import MulticlassHSSSVMTrainer
-from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
 from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_data_mesh
@@ -465,9 +463,9 @@ def run_multiclass(csv_rows: list) -> None:
 
         def batched():
             t0 = time.perf_counter()
-            trainer = MulticlassHSSSVMTrainer(
+            engine = HSSSVMEngine(
                 spec=KernelSpec(h=h), comp=comp, leaf_size=256, max_it=10)
-            model = trainer.fit(xtr, ytr, c_value=c_value)
+            model = engine.fit(xtr, ytr, c_value=c_value)
             pred = np.asarray(model.predict(jnp.asarray(xte)))
             return time.perf_counter() - t0, float(np.mean(pred == yte))
 
@@ -476,9 +474,9 @@ def run_multiclass(csv_rows: list) -> None:
             scores = []
             for cls in classes:
                 yb = np.where(ytr == cls, 1.0, -1.0).astype(np.float32)
-                bt = HSSSVMTrainer(spec=KernelSpec(h=h), comp=comp,
-                                   leaf_size=256, max_it=10)
-                bm = bt.fit(xtr, yb, c_value=c_value)
+                be = HSSSVMEngine(spec=KernelSpec(h=h), comp=comp,
+                                  leaf_size=256, max_it=10)
+                bm = be.fit(xtr, yb, c_value=c_value)
                 scores.append(
                     np.asarray(bm.decision_function(jnp.asarray(xte))))
             acc = float(np.mean(

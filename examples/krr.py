@@ -18,9 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compression import CompressionParams
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec
-from repro.core.krr import grid_search_gp, grid_search_krr
 from repro.data import synthetic
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
@@ -50,8 +49,8 @@ def h_lambda_grid():
     xtr, ytr, xte, yte = synthetic.train_test(
         "noisy_sine", n_train=4096, n_test=1024, seed=0, noise=0.1)
     t0 = time.time()
-    model, info = grid_search_krr(
-        xtr, ytr, xte, yte, hs=[0.5, 1.0], lams=[0.3, 1.0, 4.0],
+    model, info = grid_search(
+        xtr, ytr, xte, yte, hs=[0.5, 1.0], knobs=[0.3, 1.0, 4.0], task="krr",
         trainer_kwargs=dict(comp=COMP, leaf_size=128))
     print("KRR (h, λ) grid (scores are negated validation RMSE):")
     print(f"{'h':>6} {'lam':>6} {'rmse':>8}")
@@ -67,9 +66,9 @@ def gp_evidence_grid():
     xtr, ytr, _, _ = synthetic.train_test(
         "noisy_sine", n_train=2048, n_test=256, seed=0, noise=0.1)
     t0 = time.time()
-    model, info = grid_search_gp(
-        xtr, ytr, hs=[0.5, 1.0], lams=[0.01, 0.1, 1.0],
-        trainer_kwargs=dict(comp=COMP, leaf_size=128))
+    model, info = grid_search(
+        xtr, ytr, None, None, hs=[0.5, 1.0], knobs=[0.01, 0.1, 1.0],
+        task="gp", trainer_kwargs=dict(comp=COMP, leaf_size=128))
     print("GP (h, λ) grid scored by log marginal likelihood — no holdout:")
     print(f"{'h':>6} {'lam':>6} {'log p(y)':>12}")
     for (h, lam), rec in sorted(info["results"].items()):

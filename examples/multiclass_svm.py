@@ -17,9 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compression import CompressionParams
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec
-from repro.core.multiclass import MulticlassHSSSVMTrainer, grid_search_multiclass
-from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
@@ -34,21 +33,21 @@ def batched_vs_sequential():
 
     def run_batched():
         t0 = time.time()
-        trainer = MulticlassHSSSVMTrainer(
+        engine = HSSSVMEngine(
             spec=KernelSpec(h=1.5), comp=COMP, leaf_size=256, max_it=10)
-        model = trainer.fit(xtr, ytr, c_value=1.0)
+        model = engine.fit(xtr, ytr, c_value=1.0)
         acc = float(jnp.mean(model.predict(jnp.asarray(xte))
                              == jnp.asarray(yte)))
-        return time.time() - t0, acc, trainer.report
+        return time.time() - t0, acc, engine.report
 
     def run_sequential():
         t0 = time.time()
         preds = []
         for c in classes:
             yb = np.where(ytr == c, 1.0, -1.0).astype(np.float32)
-            bt = HSSSVMTrainer(spec=KernelSpec(h=1.5), comp=COMP,
-                               leaf_size=256, max_it=10)
-            bm = bt.fit(xtr, yb, c_value=1.0)
+            be = HSSSVMEngine(spec=KernelSpec(h=1.5), comp=COMP,
+                              leaf_size=256, max_it=10)
+            bm = be.fit(xtr, yb, c_value=1.0)
             preds.append(np.asarray(bm.decision_function(jnp.asarray(xte))))
         acc = float(np.mean(classes[np.argmax(np.stack(preds, 1), 1)] == yte))
         return time.time() - t0, acc
@@ -73,8 +72,8 @@ def spirals_grid():
     xtr, ytr, xte, yte = synthetic.train_test(
         "spirals", n_train=4096, n_test=1024, seed=0, n_classes=3)
     t0 = time.time()
-    model, info = grid_search_multiclass(
-        xtr, ytr, xte, yte, hs=[0.1, 0.3], cs=[0.5, 2.0, 8.0],
+    model, info = grid_search(
+        xtr, ytr, xte, yte, hs=[0.1, 0.3], knobs=[0.5, 2.0, 8.0],
         trainer_kwargs=dict(comp=COMP, leaf_size=128, max_it=10))
     dt = time.time() - t0
     print("3-class spirals (C x class) grid:")

@@ -18,9 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compression import CompressionParams
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec
-from repro.core.tasks import grid_search_oneclass, oneclass_metrics
+from repro.core.tasks import oneclass_metrics
 from repro.data import synthetic
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
@@ -57,8 +57,9 @@ def h_nu_grid():
     xval, yval = synthetic.blobs_with_outliers(1024, n_features=4,
                                                outlier_frac=0.1, seed=2)
     t0 = time.time()
-    model, info = grid_search_oneclass(
-        xtr, xval, yval, hs=[1.0, 2.0], nus=[0.05, 0.1, 0.2],
+    model, info = grid_search(
+        xtr, None, xval, yval, hs=[1.0, 2.0], knobs=[0.05, 0.1, 0.2],
+        task="oneclass",
         trainer_kwargs=dict(comp=COMP, leaf_size=128, max_it=30))
     print("(h, ν) grid (scores are balanced inlier/outlier accuracy):")
     print(f"{'h':>6} {'nu':>6} {'balanced acc':>13}")

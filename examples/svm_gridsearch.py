@@ -12,7 +12,7 @@ import time
 sys.path.insert(0, "src")
 
 from repro.core.compression import CompressionParams
-from repro.core.svm import grid_search
+from repro.core.engine import grid_search
 from repro.data import synthetic
 
 
@@ -23,7 +23,7 @@ def main():
     t0 = time.time()
     model, info = grid_search(
         xtr, ytr, xte, yte,
-        hs=[1.0, 3.0], cs=[0.1, 1.0, 10.0],
+        hs=[1.0, 3.0], knobs=[0.1, 1.0, 10.0],
         trainer_kwargs=dict(
             comp=CompressionParams(rank=32, n_near=48, n_far=64),
             leaf_size=256, max_it=10),

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compression import CompressionParams
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec
-from repro.core.tasks import grid_search_svr
 from repro.data import synthetic
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
@@ -50,9 +49,9 @@ def h_eps_grid():
     xtr, ytr, xte, yte = synthetic.train_test(
         "noisy_step", n_train=4096, n_test=1024, seed=0, noise=0.05)
     t0 = time.time()
-    model, info = grid_search_svr(
-        xtr, ytr, xte, yte, hs=[0.2, 0.5], epsilons=[0.02, 0.1, 0.3],
-        c_value=2.0, trainer_kwargs=dict(comp=COMP, leaf_size=128, max_it=10))
+    model, info = grid_search(
+        xtr, ytr, xte, yte, hs=[0.2, 0.5], knobs=[0.02, 0.1, 0.3], task="svr",
+        trainer_kwargs=dict(svr_c=2.0, comp=COMP, leaf_size=128, max_it=10))
     print("noisy step (h, ε) grid (scores are negated validation RMSE):")
     print(f"{'h':>6} {'eps':>6} {'rmse':>8}")
     for (h, e), rec in sorted(info["results"].items()):

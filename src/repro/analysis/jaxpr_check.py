@@ -195,7 +195,7 @@ def check_hot_paths(store_dtype: str | None = "bfloat16") -> list[Finding]:
     ``preferred_element_type`` actually bites."""
     from repro.core import admm as admm_mod
     from repro.core import factorization
-    from repro.core.svm import compute_bias_batched
+    from repro.core.tasks import compute_bias_batched
 
     hss, fac, yp = build_probe(store_dtype=store_dtype)
     n = hss.n

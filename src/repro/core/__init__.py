@@ -8,38 +8,30 @@ from repro.core.compression import (
     CompressionParams, compress, compress_sharded, compression_error,
     kernel_eval_count,
 )
-from repro.core.engine import EngineModel, HSSSVMEngine
+from repro.core.engine import EngineModel, HSSSVMEngine, grid_search
 from repro.core.factorization import (
     HSSFactorization, factorize, factorize_sharded, hss_solve, hss_solve_mat,
 )
 from repro.core.hss import HSSMatrix, shrink_to_fit
 from repro.core.kernelfn import KernelSpec, kernel_block
-from repro.core.krr import grid_search_gp, grid_search_krr, krr_solve
+from repro.core.krr import krr_solve
 # NOTE: the raw ``lanczos`` sweep is deliberately NOT re-exported — binding
 # that name here would shadow the ``repro.core.lanczos`` submodule attribute.
 from repro.core.lanczos import spectral_embed, top_eigenpairs
-from repro.core.multiclass import (
-    MulticlassHSSSVMTrainer, MulticlassSVMModel, grid_search_multiclass,
-)
-from repro.core.svm import HSSSVMTrainer, SVMModel, grid_search
-from repro.core.tasks import (
-    grid_search_oneclass, grid_search_svr, one_class_task, svr_task,
-)
+from repro.core.tasks import one_class_task, svr_task
 from repro.core.tree import ClusterTree, build_tree, pad_dataset
 
 __all__ = [
     "ADMMState", "BoxQPTask", "admm_boxqp", "admm_svm", "admm_svm_batched",
     "paper_beta", "svm_task",
-    "grid_search_oneclass", "grid_search_svr", "one_class_task", "svr_task",
+    "one_class_task", "svr_task",
     "CompressionParams", "compress", "compress_sharded", "compression_error",
     "kernel_eval_count",
-    "EngineModel", "HSSSVMEngine",
+    "EngineModel", "HSSSVMEngine", "grid_search",
     "HSSFactorization", "factorize", "factorize_sharded",
     "hss_solve", "hss_solve_mat",
     "HSSMatrix", "shrink_to_fit", "KernelSpec", "kernel_block",
-    "grid_search_gp", "grid_search_krr", "krr_solve",
+    "krr_solve",
     "spectral_embed", "top_eigenpairs",
-    "HSSSVMTrainer", "SVMModel", "grid_search",
-    "MulticlassHSSSVMTrainer", "MulticlassSVMModel", "grid_search_multiclass",
     "ClusterTree", "build_tree", "pad_dataset",
 ]

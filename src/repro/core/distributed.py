@@ -1,4 +1,4 @@
-"""Distributed HSS-ADMM SVM training: shardings, C-grid drivers, mesh cell.
+"""Distributed HSS-ADMM SVM training: shardings and the mesh cell.
 
 Sample dimension d is sharded across ALL mesh devices (node-major): the
 leaf-level factorization arrays (E, G — O(N r) and O(N m)) live device-local;
@@ -14,18 +14,16 @@ cross-device traffic is
 exactly matching the communication pattern of distributed-memory HSS solvers
 (STRUMPACK's design, adapted to SPMD/pjit).
 
-Since the mesh-parallel build landed (``compression.compress_sharded`` /
-``factorization.factorize_sharded`` / ``core.engine.HSSSVMEngine``) the
-factorization arrives here already placed per ``fac_shardings`` — the C-grid
-drivers detect that and skip the legacy build-then-``device_put`` round-trip,
-so no stage of prepare→train ever materializes an unsharded O(N·m) array.
+Training on a mesh is ``core.engine.HSSSVMEngine(mesh=...)``: its sharded
+build (``compression.compress_sharded`` / ``factorization.factorize_sharded``)
+places the factorization per ``fac_shardings``, so no stage of
+prepare→train ever materializes an unsharded O(N·m) array.
 ``build_svm_cell`` exposes the same ADMM step both ways: as a
 ShapeDtypeStruct dry-run cell (launch/dryrun.py) and, given ``data=(x, y)``,
 as a real executable cell over a live sharded factorization.
 """
 from __future__ import annotations
 
-import dataclasses
 import math
 from typing import Any
 
@@ -33,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core.admm import admm_svm, admm_svm_batched
+from repro.core.admm import admm_svm
 from repro.core.factorization import HSSFactorization, hss_solve
 
 
@@ -112,135 +110,6 @@ def make_distributed_admm_step(beta: float, max_it: int = 10,
         return state.z, trace.primal_res
 
     return step
-
-
-def admm_train_distributed(
-    fac: HSSFactorization,
-    y: jax.Array,
-    c_values,
-    mesh: Mesh,
-    max_it: int = 10,
-    warm_start: bool = True,
-) -> list:
-    """Run the ADMM C-grid data-parallel under ``mesh`` (paper Alg. 3 7-14).
-
-    The factorization shards over the node axis (fac_shardings), the vector
-    iterates (x, z, mu) shard over ALL devices (vec_sharding), and under
-    SPMD the per-iteration scalar reductions — w1 = eᵀw, w2 = wᵀ(Yq), the
-    residual norms — lower to cross-device all-reduces while the z/mu box
-    updates stay purely device-local.  Consecutive C values warm-start from
-    the previous (z, mu) exactly as core.svm.grid_search does locally.
-
-    ``c_values`` entries may be scalars or per-coordinate (n,) vectors (the
-    latter pins padded coordinates to zero, cf. tree.pad_dataset).  Returns
-    one (z, primal_res_trace) per C, in grid order, with z left sharded on
-    the mesh.
-    """
-    n = y.shape[0]
-    v_sh = vec_sharding(mesh)
-    y_d = jax.device_put(jnp.asarray(y, jnp.float32), v_sh)
-    beta = fac.beta
-
-    @jax.jit
-    def run(fac_, y_, c, z0, mu0):
-        state, trace = admm_svm(fac_.solve, y_, c, beta, max_it,
-                                z0=z0, mu0=mu0)
-        return state.z, state.mu, trace.primal_res
-
-    def make_c(c):
-        c_arr = jnp.asarray(c, jnp.float32)
-        return jax.device_put(c_arr, v_sh) if c_arr.ndim == 1 else c_arr
-
-    zeros = jax.device_put(jnp.zeros((n,), jnp.float32), v_sh)
-    return _run_c_grid(fac, y_d, c_values, mesh, run, make_c, zeros,
-                       warm_start)
-
-
-def _already_placed(fac, fac_sh) -> bool:
-    """True when every factor array already has its fac_shardings placement
-    (the mesh-parallel build emits it that way — no device_put needed)."""
-    for a, s in zip(jax.tree.leaves(fac), jax.tree.leaves(fac_sh)):
-        sh = getattr(a, "sharding", None)
-        if sh is None:
-            return False
-        try:
-            if not sh.is_equivalent_to(s, a.ndim):
-                return False
-        except (AttributeError, TypeError):
-            return False
-    return True
-
-
-def _run_c_grid(fac, labels_d, c_values, mesh, run, make_c, zeros,
-                warm_start) -> list:
-    """Shared warm-started C-grid driver for the vector and (n, k) block
-    paths: shard the factorization once, then sweep C reusing it."""
-    from repro.dist import api as dist_api
-
-    fac_sh = fac_shardings(jax.eval_shape(lambda: fac), mesh)
-    fac_d = fac if _already_placed(fac, fac_sh) else jax.device_put(fac, fac_sh)
-    z0, mu0 = zeros, zeros
-    out = []
-    with dist_api.use_mesh(mesh), mesh:
-        for c in c_values:
-            z, mu, res = run(fac_d, labels_d, make_c(c), z0, mu0)
-            out.append((z, res))
-            if warm_start:
-                z0, mu0 = z, mu
-    return out
-
-
-def mat_sharding(mesh: Mesh) -> NamedSharding:
-    """(n, k) iterate blocks: samples sharded over all devices, classes local."""
-    return NamedSharding(mesh, PartitionSpec(_node_axis(mesh), None))
-
-
-def admm_train_multiclass_distributed(
-    fac: HSSFactorization,
-    ys: jax.Array,
-    c_values,
-    mesh: Mesh,
-    max_it: int = 10,
-    warm_start: bool = True,
-    pmask: jax.Array | None = None,
-) -> list:
-    """Data-parallel batched multiclass ADMM C-grid under ``mesh``.
-
-    ``ys`` is the (P, n) per-class (or per-pair) label matrix; the iterate
-    blocks are (n, P) with the SAMPLE axis sharded over every device and the
-    class axis kept device-local — per-class batching is orthogonal to the
-    data-parallel layout, so the k-fold RHS widening adds ZERO cross-device
-    traffic: the multi-RHS telescoping solve runs the same collective
-    schedule as the single-RHS solve, just with k-column payloads, and the
-    per-problem scalar reductions (w2, residual norms) psum k values instead
-    of 1.  The C grid reuses the sharded factorization and warm-starts the
-    whole (n, P) block, composing the paper's C-amortization with the
-    class-axis batching.
-
-    ``pmask`` (P, n) optionally pins non-participating coordinates to [0, 0]
-    (one-vs-one pair problems).  Returns one (z (n, P), primal_res (max_it,
-    P)) per C, with z left sharded on the mesh.
-    """
-    n_prob, n = ys.shape
-    y_sh = NamedSharding(mesh, PartitionSpec(None, _node_axis(mesh)))
-    ys_d = jax.device_put(jnp.asarray(ys, jnp.float32), y_sh)
-    mask_d = (jnp.ones_like(ys_d) if pmask is None
-              else jax.device_put(jnp.asarray(pmask, jnp.float32), y_sh))
-    beta = fac.beta
-
-    @jax.jit
-    def run(fac_, ys_, c_upper, z0, mu0):
-        state, trace = admm_svm_batched(fac_.solve_mat, ys_, c_upper, beta,
-                                        max_it, z0=z0, mu0=mu0)
-        return state.z, state.mu, trace.primal_res
-
-    def make_c(c):
-        return jnp.asarray(c, jnp.float32) * mask_d
-
-    zeros = jax.device_put(jnp.zeros((n, n_prob), jnp.float32),
-                           mat_sharding(mesh))
-    return _run_c_grid(fac, ys_d, c_values, mesh, run, make_c, zeros,
-                       warm_start)
 
 
 def build_svm_cell(mesh: Mesh, n: int = 1 << 22, leaf: int = 256,

@@ -17,9 +17,9 @@ single-device case and the mesh-parallel case:
     without ever gathering ``x_perm``.
 
 Binary problems (labels ±1) and k-class problems (arbitrary labels, OVR or
-OVO reduction) share the path: the engine always trains the (d, P)-block
-batched ADMM with P = 1 for binary — the multiclass economy of
-``core.multiclass`` with the distribution of ``core.distributed``.
+OVO reduction, ``core.tasks``) share the path: the engine always trains the
+(d, P)-block batched ADMM with P = 1 for binary.  ``grid_search`` is the one
+(h, knob) sweep over it, for every task.
 """
 from __future__ import annotations
 
@@ -35,14 +35,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.core import admm as admm_mod
-from repro.core import compression, factorization, tree as tree_mod
+from repro.core import compression, factorization, krr as krr_mod
 from repro.core import tasks as tasks_mod
+from repro.core import tree as tree_mod
 from repro.core.hss import HSSMatrix, shrink_report
 from repro.core.kernelfn import (
     DEFAULT_SCORE_BLOCK, KernelSpec, kernel_matvec_streamed,
 )
-from repro.core.multiclass import ovo_problems, ovo_vote, ovr_problems
-from repro.core.svm import FitReport, compute_bias_batched
 from repro.dist import api as dist_api
 from repro.dist.api import mesh_ndev
 
@@ -51,6 +50,44 @@ Array = jax.Array
 
 def _node_spec(mesh: Mesh) -> PartitionSpec:
     return PartitionSpec(tuple(mesh.axis_names))
+
+
+@dataclasses.dataclass
+class FitReport:
+    """Timings mirroring the paper's Tables 4/5 columns.
+
+    The rank fields are populated by adaptive (``CompressionParams.rtol``)
+    builds: per-level stored rank caps before/after the shrink-to-fit pass,
+    the corresponding Σ n_k·r_k storage sums, and the exact number of kernel
+    entries the compression evaluated — the observability hooks the bench
+    records so rank adaptivity shows up in the perf trajectory.
+    """
+
+    compression_s: float
+    factorization_s: float
+    admm_s: float
+    memory_mb: float
+    hss_levels: int
+    beta: float
+    ranks_pre: tuple | None = None
+    ranks_post: tuple | None = None
+    rank_sum_pre: int | None = None
+    rank_sum_post: int | None = None
+    kernel_evals: int | None = None
+    # per-problem ADMM iterations actually run by the last train() — below
+    # max_it when the residual stopping rule (``tol``) froze the iterates
+    iters_run: tuple | None = None
+    # streamed-build observability (compression.StreamStats): peak device
+    # bytes of any one batch round-trip — the build's working set, which a
+    # streamed build bounds by batch size instead of O(N·d) — plus the
+    # batch count and resume/restart record
+    peak_stream_bytes: int | None = None
+    stream_batches: int | None = None
+    stream_resumed_level: int | None = None
+    stream_restarts: int | None = None
+    # adaptive-ρ record of the last train(): final β and rescale count
+    rho_final: float | None = None
+    rho_rescales: int | None = None
 
 
 @dataclasses.dataclass
@@ -134,7 +171,7 @@ class EngineModel:
         if self.strategy == "ovr":
             idx = jnp.argmax(scores, axis=1)
         else:
-            idx = ovo_vote(scores, self.pairs, self.n_classes)
+            idx = tasks_mod.ovo_vote(scores, self.pairs, self.n_classes)
         return jnp.asarray(self.classes)[idx]
 
 
@@ -176,6 +213,14 @@ def _admm_run(fac, ys, pmask, knob, z0, mu0, done0=None, *, boxqp,
     if done0 is None:
         return state.z, state.mu, task.sign * state.z, hi, trace.iters_run
     return state, trace, task.sign * state.z, hi
+
+
+# The tasks' bias rules and the KRR/GP solve, jitted once per process like
+# ``_admm_run``: every engine shares their traces.
+_bias_svm = jax.jit(tasks_mod.compute_bias_batched)
+_bias_svr = jax.jit(tasks_mod.compute_bias_svr_batched)
+_rho_oneclass = jax.jit(tasks_mod.compute_rho_oneclass_batched)
+_krr_solve = jax.jit(krr_mod.krr_solve)
 
 
 @dataclasses.dataclass
@@ -241,8 +286,6 @@ class HSSSVMEngine:
     _pairs: np.ndarray | None = None
     _binary: bool = False
     _report: FitReport | None = None
-    _jit_admm: object = None
-    _jit_bias: object = None
     # The mesh the prepared stages were placed on (self.mesh at prepare).
     _mesh: Mesh | None = None
     # multilevel warm start inputs + adaptive-ρ machinery
@@ -343,8 +386,8 @@ class HSSSVMEngine:
                 pmasks = maskp[None, :].astype(np.float32)
                 pairs = None
             else:
-                build = ovr_problems if self.strategy == "ovr" else \
-                    ovo_problems
+                build = (tasks_mod.ovr_problems if self.strategy == "ovr"
+                         else tasks_mod.ovo_problems)
                 ys, pmasks, pairs = build(
                     yp, classes.astype(np.float32), maskp)
 
@@ -391,7 +434,6 @@ class HSSSVMEngine:
         self._hss, self._fac = hss, fac
         self._ys, self._pmask = ys_d, pm_d
         self._classes, self._pairs = classes, pairs
-        self._jit_admm = self._jit_bias = None
         self._x_raw, self._y_raw = x, (None if y is None else np.asarray(y))
         self._perm_host = t.perm
         self._xp_host = xp_host
@@ -479,14 +521,6 @@ class HSSSVMEngine:
         eff_tol = self.tol if ap is None else ap.tol
         adapt = ap is not None and ap.adapt_rho
 
-        if self._jit_bias is None:
-            if self.task == "svr":
-                self._jit_bias = jax.jit(tasks_mod.compute_bias_svr_batched)
-            elif self.task == "oneclass":
-                self._jit_bias = jax.jit(tasks_mod.compute_rho_oneclass_batched)
-            else:
-                self._jit_bias = jax.jit(compute_bias_batched)
-
         if self._mesh is None:
             zeros = jnp.zeros((d, n_prob), jnp.float32)
         else:
@@ -511,13 +545,12 @@ class HSSSVMEngine:
                 jax.block_until_ready(z)
             with obs.span("hss.bias"):
                 if self.task == "svr":
-                    biases = self._jit_bias(self._hss, ys.T, z,
-                                            self.svr_c * pmask.T, pmask.T,
-                                            knob)
+                    biases = _bias_svr(self._hss, ys.T, z,
+                                       self.svr_c * pmask.T, pmask.T, knob)
                 elif self.task == "oneclass":
-                    biases = -self._jit_bias(self._hss, z, hi_mat, pmask.T)
+                    biases = -_rho_oneclass(self._hss, z, hi_mat, pmask.T)
                 else:
-                    biases = self._jit_bias(
+                    biases = _bias_svm(
                         self._hss, ys.T, z, c_value * pmask.T, pmask.T)
         if self._report is not None:
             self._report.admm_s += admm_span.seconds
@@ -548,14 +581,10 @@ class HSSSVMEngine:
         static field, so each λ traces once — noise next to its O(N r²)
         refactorization.
         """
-        from repro.core import krr as krr_mod
-
         if not lam > 0.0:
             raise ValueError(f"{self.task} needs lambda > 0, got {lam}")
         ys, pmask = self._ys, self._pmask
         n_prob = ys.shape[0]
-        if self._jit_admm is None:
-            self._jit_admm = jax.jit(krr_mod.krr_solve)
         with self._active():
             with obs.span("hss.factorize") as factorize_span:
                 fac = self._fac_for(float(lam))
@@ -564,7 +593,7 @@ class HSSSVMEngine:
                 # pads decouple exactly ((1+λ)I block, zero targets); the
                 # mask only clips factorization float noise off the pad
                 # coefficients
-                alpha = self._jit_admm(fac, ys.T) * pmask.T
+                alpha = _krr_solve(fac, ys.T) * pmask.T
                 jax.block_until_ready(alpha)
         if self._report is not None:
             self._report.factorization_s += factorize_span.seconds
@@ -583,8 +612,6 @@ class HSSSVMEngine:
                      num_iters: int = 20, seed: int = 0) -> float:
         """GP log marginal likelihood estimate at noise λ (see
         ``krr.gp_log_marginal``) — the ``task="gp"`` (h, λ) grid score."""
-        from repro.core import krr as krr_mod
-
         assert self._fac is not None, "call prepare() first"
         if self.task not in ("krr", "gp"):
             raise ValueError(f"log_marginal needs task='krr'/'gp', "
@@ -684,7 +711,7 @@ class HSSSVMEngine:
         Train the same task on a ``coarse_frac`` subsample with a CRUDE
         compression (``CompressionParams.crude`` unless overridden), prolong
         the coarse duals to the full point set by nearest-neighbour
-        interpolation (``svm.prolong_duals`` over the padded/permuted host
+        interpolation (``tasks.prolong_duals`` over the padded/permuted host
         points), and let the warm-started early-stopping ADMM finish —
         ``FitReport.iters_run`` then measures the saved iterations against a
         cold ``train``.  The subsample is stratified per class for
@@ -695,8 +722,6 @@ class HSSSVMEngine:
         records.  Requires ``prepare`` to have run (the fine factorization
         is reused untouched).
         """
-        from repro.core.svm import prolong_duals
-
         assert self._fac is not None, "call prepare() first"
         x, y = self._x_raw, self._y_raw
         n = x.shape[0]
@@ -728,10 +753,12 @@ class HSSSVMEngine:
         scale = tasks_mod.prolong_scale(
             self.task,
             int(coarse._maskp_host.sum()), int(self._maskp_host.sum()))
-        z0 = prolong_duals(coarse._xp_host, np.asarray(jax.device_get(z_c)),
-                           self._xp_host) * scale
-        mu0 = prolong_duals(coarse._xp_host, np.asarray(jax.device_get(mu_c)),
-                            self._xp_host) * scale
+        z0 = tasks_mod.prolong_duals(
+            coarse._xp_host, np.asarray(jax.device_get(z_c)),
+            self._xp_host) * scale
+        mu0 = tasks_mod.prolong_duals(
+            coarse._xp_host, np.asarray(jax.device_get(mu_c)),
+            self._xp_host) * scale
         # Fine pads carry no dual mass regardless of what they mapped to.
         z0 = (z0 * self._maskp_host[:, None]).astype(np.float32)
         mu0 = (mu0 * self._maskp_host[:, None]).astype(np.float32)
@@ -769,3 +796,68 @@ class HSSSVMEngine:
         self.prepare(x, y)
         model, _ = self.train(c_value)
         return model
+
+
+def grid_search(
+    x: np.ndarray,
+    y: np.ndarray | None,
+    x_val: np.ndarray | None,
+    y_val: np.ndarray | None,
+    hs: Sequence[float],
+    knobs: Sequence[float],
+    *,
+    task: str = "svm",
+    trainer_kwargs: dict | None = None,
+    rtol: float | None = None,
+) -> tuple[EngineModel, dict]:
+    """(h, knob) grid search (paper §3.3) for every task.
+
+    Per h: ONE engine, so one compression and one factorization serve the
+    whole knob sweep (C for classification, ε for SVR, ν for one-class, λ
+    for KRR/GP, which refactorizes per λ), warm-started knob to knob.
+    ``trainer_kwargs`` are the engine's fields (``svr_c`` is SVR's box
+    bound); ``y`` is None for one-class.  ``rtol`` mirrors STRUMPACK's
+    rel_tol (crude ≈ 1e-2, accurate ≈ 1e-4, Tables 4–5): it switches each
+    h's build to the adaptive compression and keeps every other ``comp``
+    knob.
+
+    Each model is scored by ``tasks.HOLDOUT_SCORES[task]`` on (x_val,
+    y_val), higher is better; ``task="gp"`` is scored by the training log
+    marginal and needs no validation split.  Returns (best model, info):
+    ``info["results"][(h, knob)]`` holds the score (``accuracy``, or
+    ``log_marginal`` for gp), the cell's own ADMM seconds and the h's
+    compression and factorization seconds; then ``best_h`` with
+    ``best_c``/``best_accuracy``, or ``best_lam``/``best_log_marginal``.
+    """
+    kw = dict(trainer_kwargs or {})
+    if rtol is not None:
+        kw["comp"] = dataclasses.replace(
+            kw.get("comp", compression.CompressionParams()), rtol=rtol)
+    gp = task == "gp"
+    score_key = "log_marginal" if gp else "accuracy"
+    x_v = None if gp else jnp.asarray(x_val)
+    results = {}
+    best_model, best_h, best_knob, best_score = None, None, None, -np.inf
+    for h in hs:
+        engine = HSSSVMEngine(spec=KernelSpec(h=float(h)), task=task, **kw)
+        engine.prepare(x, y)
+        warm, admm_seen = None, 0.0
+        for c in knobs:
+            model, warm = engine.train(float(c), warm=warm)
+            score = (engine.log_marginal(float(c)) if gp
+                     else tasks_mod.HOLDOUT_SCORES[task](model, x_v, y_val))
+            rep = engine.report
+            # report.admm_s accumulates over the sweep; a cell records only
+            # its own run
+            results[(float(h), float(c))] = {
+                score_key: score, "admm_s": rep.admm_s - admm_seen,
+                "compression_s": rep.compression_s,
+                "factorization_s": rep.factorization_s}
+            admm_seen = rep.admm_s
+            if score > best_score:
+                best_model, best_h, best_knob, best_score = (
+                    model, float(h), float(c), score)
+    return best_model, {
+        "results": results, "best_h": best_h,
+        "best_lam" if gp else "best_c": best_knob,
+        f"best_{score_key}": best_score}

@@ -192,7 +192,7 @@ def factorize_sharded(hss: HSSMatrix, beta: float, mesh,
     node count stops dividing the device count lowers to the one all-gather
     of the (tiny, O(r² n_k)) reduced blocks — the same collective schedule as
     ``hss_solve_mat``.  The result needs NO build-then-``device_put``
-    round-trip: ``_run_c_grid`` detects the placement and skips it.
+    round-trip before the engine's ADMM runs on it.
 
     Works on an ``hss`` whose arrays are themselves sharded
     (``compression.compress_sharded``) or local; parity with ``factorize``

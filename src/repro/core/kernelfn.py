@@ -18,9 +18,9 @@ import jax.numpy as jnp
 Array = jax.Array
 
 # The ONE streaming block-size default shared by every predict/score path
-# (SVMModel / MulticlassSVMModel / EngineModel / the serving tier): the row
-# count of each test×support kernel block kept live during scoring.  Serving
-# tunes it in one place (serve.BatchPolicy.block defaults to it).
+# (EngineModel / the serving tier): the row count of each test×support
+# kernel block kept live during scoring.  Serving tunes it in one place
+# (serve.BatchPolicy.block defaults to it).
 DEFAULT_SCORE_BLOCK = 2048
 
 

@@ -24,7 +24,6 @@ coefficients defensively against factorization float noise).
 from __future__ import annotations
 
 import math
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -91,78 +90,3 @@ def gp_log_marginal(hss, fac, y: Array, mask: Array | None = None,
         logdet -= (n - n_real) * math.log1p(lam)
         n_eff = n_real
     return fit - 0.5 * logdet - 0.5 * n_eff * math.log(2.0 * math.pi)
-
-
-# --------------------------------------------------------------------- #
-# validation metric + grid drivers (λ sweeps in place of C)             #
-# --------------------------------------------------------------------- #
-def krr_score(model, x_val: Array, y_val: Array) -> float:
-    """Negated RMSE (higher is better, run_grid_search maximizes)."""
-    pred = model.predict(x_val)
-    return -float(jnp.sqrt(jnp.mean((pred - jnp.asarray(y_val)) ** 2)))
-
-
-def grid_search_krr(
-    x: np.ndarray,
-    y: np.ndarray,
-    x_val: np.ndarray,
-    y_val: np.ndarray,
-    hs: Sequence[float],
-    lams: Sequence[float],
-    trainer_kwargs: dict | None = None,
-    rtol: float | None = None,
-) -> tuple[object, dict]:
-    """(h, λ) grid for KRR — λ sweeps in place of C.
-
-    Per h: ONE compression serves the whole λ sweep; each λ refactorizes the
-    shared representation (cached per visited λ) and solves once.  Scores
-    are negated validation RMSE.
-    """
-    from repro.core.engine import HSSSVMEngine
-    from repro.core.kernelfn import KernelSpec
-    from repro.core.svm import resolve_rtol, run_grid_search
-
-    kw = resolve_rtol(trainer_kwargs, rtol)
-    return run_grid_search(
-        lambda h: HSSSVMEngine(spec=KernelSpec(h=h), task="krr", **kw),
-        x, y, x_val, y_val, hs, lams, score_fn=krr_score)
-
-
-def grid_search_gp(
-    x: np.ndarray,
-    y: np.ndarray,
-    hs: Sequence[float],
-    lams: Sequence[float],
-    trainer_kwargs: dict | None = None,
-    rtol: float | None = None,
-    n_probes: int = 4,
-    num_iters: int = 20,
-    seed: int = 0,
-) -> tuple[object, dict]:
-    """(h, λ) grid for GP regression scored by the TRAINING log marginal.
-
-    No validation split: GP model selection maximizes log p(y | h, λ) on the
-    training data itself (the marginal already charges for complexity).
-    Returns (best posterior-mean model, dict with per-(h, λ) scores and the
-    winning pair) in the same shape as the other grid drivers.
-    """
-    from repro.core.engine import HSSSVMEngine
-    from repro.core.kernelfn import KernelSpec
-    from repro.core.svm import resolve_rtol
-
-    kw = resolve_rtol(trainer_kwargs, rtol)
-    results: dict = {}
-    best_model, best_key, best_score = None, None, -math.inf
-    for h in hs:
-        engine = HSSSVMEngine(spec=KernelSpec(h=float(h)), task="gp", **kw)
-        engine.prepare(x, y)
-        for lam in lams:
-            model, _ = engine.train(float(lam))
-            score = engine.log_marginal(float(lam), n_probes=n_probes,
-                                        num_iters=num_iters, seed=seed)
-            results[(float(h), float(lam))] = dict(log_marginal=score)
-            if score > best_score:
-                best_model, best_key, best_score = model, (h, lam), score
-    return best_model, dict(results=results, best_h=float(best_key[0]),
-                            best_lam=float(best_key[1]),
-                            best_log_marginal=best_score)

@@ -1,4 +1,15 @@
-"""ε-SVR and one-class SVM as thin BoxQPTask specs on the shared K_β⁻¹.
+"""The task layer on the shared K_β⁻¹: problem columns, bias rules, scores.
+
+Classification, ε-SVR and one-class SVM all train on ONE HSS compression and
+factorization; what differs per task lives here: how labels become (d, P)
+problem columns, how each column's bias or offset is recovered, and how
+``engine.grid_search`` scores a trained model.
+
+  k-class SVM: one-vs-rest (k columns) or one-vs-one (k(k−1)/2 pair
+    columns) on the same K̃ + βI, which never sees the labels.  A pair
+    problem keeps the full padded coordinate set and pins every point
+    outside its two classes to the box [0, 0] (the mechanism that makes
+    tree padding inert).  The bias is paper eq. (7), batched over columns.
 
 The shifted kernel K̃ + βI depends only on the data, the bandwidth h, and β —
 never on the task.  Related work (semismooth-Newton and augmented-Lagrangian
@@ -30,8 +41,6 @@ participation mask exactly as in classification, so the restriction of the
 ADMM fixed point to real points solves the original problem.
 """
 from __future__ import annotations
-
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -94,8 +103,84 @@ def one_class_task(mask: Array, nu: Array | float) -> BoxQPTask:
 
 
 # --------------------------------------------------------------------- #
+# multiclass reductions: k classes -> P binary problems on one K̃        #
+# --------------------------------------------------------------------- #
+def ovr_problems(y: np.ndarray, classes: np.ndarray, real_mask: np.ndarray
+                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
+    """One-vs-rest label matrix (k, d) and participation masks (k, d)."""
+    ys = np.where(y[None, :] == classes[:, None], 1.0, -1.0)
+    masks = np.broadcast_to(real_mask[None, :], ys.shape)
+    return ys.astype(np.float32), masks.astype(np.float32), None
+
+
+def ovo_problems(y: np.ndarray, classes: np.ndarray, real_mask: np.ndarray
+                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One-vs-one problems: (P, d) labels/masks + (P, 2) class-index pairs.
+
+    Non-participating points keep label -1 but get box [0, 0] via the mask,
+    so they are inert in the pair's ADMM fixed point.
+    """
+    k = classes.shape[0]
+    pairs = np.array([(a, b) for a in range(k) for b in range(a + 1, k)],
+                     dtype=np.int32).reshape(-1, 2)
+    ys, masks = [], []
+    for a, b in pairs:
+        in_pair = (y == classes[a]) | (y == classes[b])
+        ys.append(np.where(y == classes[a], 1.0, -1.0))
+        masks.append((real_mask & in_pair).astype(np.float32))
+    return (np.stack(ys).astype(np.float32), np.stack(masks).astype(np.float32),
+            pairs)
+
+
+def ovo_vote(scores: Array, pairs: np.ndarray, n_classes: int) -> Array:
+    """One-vs-one decision: (n_test, P) pair scores -> (n_test,) class indices.
+
+    Each pair votes for its winner; vote ties break toward the larger summed
+    functional margin.  Shared by ``core.engine.EngineModel`` and mirrored
+    on the host by the serving tier's decode, so the tie-break can never
+    drift.
+    """
+    pairs = jnp.asarray(pairs)
+    winner = jnp.where(scores >= 0, pairs[:, 0][None, :],
+                       pairs[:, 1][None, :])
+    votes = jax.nn.one_hot(winner, n_classes).sum(axis=1)
+    margin = jnp.zeros_like(votes)
+    margin = margin.at[:, pairs[:, 0]].add(scores)
+    margin = margin.at[:, pairs[:, 1]].add(-scores)
+    return jnp.argmax(votes + 1e-3 * jnp.tanh(margin), axis=1)
+
+
+# --------------------------------------------------------------------- #
 # bias / offset extraction (one HSS matmat each, batched over columns)  #
 # --------------------------------------------------------------------- #
+def compute_bias_batched(hss: HSSMatrix, ys: Array, z: Array, c_mat: Array,
+                         masks: Array, margin_tol: float = 1e-6) -> Array:
+    """Paper eq. (7) for P problems sharing one kernel, with ONE HSS matmat.
+
+    b_p = (z_yᵀ K̃ ē − Σ_{j∈M_p} y_j) / |M_p| where M_p = margin support
+    vectors {j : 0 < z_jp < C_jp} of problem p.  Falls back to the average
+    functional margin over all bounded SVs when M_p is empty.  ``ys``/``z``/
+    ``c_mat``/``masks`` are (d, P) column blocks; returns (P,).
+    """
+    f32 = jnp.float32
+    on_margin = (
+        (z > margin_tol) & (z < c_mat - margin_tol) & (masks > 0)
+    ).astype(z.dtype)
+    n_m = jnp.sum(on_margin, axis=0)                       # (P,)
+    kz = hss.matmat(ys * z)                 # K̃ (Y z) — one O(N r) sweep
+    num = (jnp.einsum("dp,dp->p", on_margin, kz, preferred_element_type=f32)
+           - jnp.einsum("dp,dp->p", on_margin, ys,
+                        preferred_element_type=f32))
+    b_margin = -num / jnp.maximum(n_m, 1.0)
+    # Fallback per problem: average functional margin over all (bounded) SVs.
+    sv = ((z > margin_tol) & (masks > 0)).astype(z.dtype)
+    n_sv = jnp.maximum(jnp.sum(sv, axis=0), 1.0)
+    b_all = -(jnp.einsum("dp,dp->p", sv, kz, preferred_element_type=f32)
+              - jnp.einsum("dp,dp->p", sv, ys,
+                           preferred_element_type=f32)) / n_sv
+    return jnp.where(n_m > 0, b_margin, b_all)
+
+
 def compute_bias_svr_batched(hss: HSSMatrix, targets: Array, alpha: Array,
                              c_mat: Array, masks: Array,
                              epsilon: Array | float,
@@ -170,11 +255,38 @@ def prolong_scale(task: str, n_coarse_real: int, n_fine_real: int) -> float:
     return float(n_coarse_real) / float(max(n_fine_real, 1))
 
 
+def prolong_duals(x_coarse: np.ndarray, z_coarse: np.ndarray,
+                  x_fine: np.ndarray) -> np.ndarray:
+    """Nearest-neighbour prolongation of per-point dual columns.
+
+    The AML-SVM multilevel scheme (arXiv 2011.02592): a dual vector trained
+    on a coarse subsample is lifted to the fine set by giving every fine
+    point its nearest coarse point's dual value — support-vector regions
+    stay support-vector regions, so the fine ADMM starts near its fixed
+    point instead of at zero.  ``x_coarse`` (n_c, f) / ``x_fine`` (n_f, f)
+    are point sets (padded, permuted — any consistent order), ``z_coarse``
+    is (n_c,) or (n_c, P); returns the matching (n_f, ...) array.  Distances
+    are ranked in f32 (bf16 inputs are fine); the dual VALUES are copied
+    untouched.  Task-dependent mass rescaling is ``tasks.prolong_scale``.
+    """
+    from scipy.spatial import cKDTree
+
+    xc = np.asarray(x_coarse, np.float32)
+    xf = np.asarray(x_fine, np.float32)
+    _, nn = cKDTree(xc).query(xf, k=1)
+    return np.asarray(z_coarse)[nn]
+
+
 # --------------------------------------------------------------------- #
-# validation metrics + grid drivers (ε / ν sweeps in place of C)        #
+# holdout scores: grid_search's rule per task (higher is better)        #
 # --------------------------------------------------------------------- #
-def svr_score(model, x_val: Array, y_val: Array) -> float:
-    """Negated RMSE (higher is better, run_grid_search maximizes)."""
+def accuracy_score(model, x_val: Array, y_val: Array) -> float:
+    """Holdout classification accuracy."""
+    return float(jnp.mean(model.predict(x_val) == jnp.asarray(y_val)))
+
+
+def neg_rmse_score(model, x_val: Array, y_val: Array) -> float:
+    """Negated holdout RMSE of a regression model."""
     pred = model.predict(x_val)
     return -float(jnp.sqrt(jnp.mean((pred - jnp.asarray(y_val)) ** 2)))
 
@@ -200,54 +312,11 @@ def oneclass_score(model, x_val: Array, y_val: Array) -> float:
     return oneclass_metrics(model.predict(x_val), y_val)["balanced_accuracy"]
 
 
-def grid_search_svr(
-    x: np.ndarray,
-    y: np.ndarray,
-    x_val: np.ndarray,
-    y_val: np.ndarray,
-    hs: Sequence[float],
-    epsilons: Sequence[float],
-    c_value: float = 1.0,
-    trainer_kwargs: dict | None = None,
-    rtol: float | None = None,
-) -> tuple[object, dict]:
-    """(h, ε) grid for ε-SVR — ε sweeps in place of C (paper §3.3 pattern).
-
-    Per h: ONE compression + ONE factorization serve the whole warm-started
-    ε sweep (the task's linear term and prox threshold change, the kernel
-    side never does).  Scores are negated validation RMSE.
-    """
-    from repro.core.engine import HSSSVMEngine
-    from repro.core.kernelfn import KernelSpec
-    from repro.core.svm import resolve_rtol, run_grid_search
-
-    kw = resolve_rtol(trainer_kwargs, rtol)
-    return run_grid_search(
-        lambda h: HSSSVMEngine(spec=KernelSpec(h=h), task="svr",
-                               svr_c=c_value, **kw),
-        x, y, x_val, y_val, hs, epsilons, score_fn=svr_score)
-
-
-def grid_search_oneclass(
-    x: np.ndarray,
-    x_val: np.ndarray,
-    y_val: np.ndarray,
-    hs: Sequence[float],
-    nus: Sequence[float],
-    trainer_kwargs: dict | None = None,
-    rtol: float | None = None,
-) -> tuple[object, dict]:
-    """(h, ν) grid for one-class SVM — ν sweeps in place of C.
-
-    Training is unsupervised (no y); ``y_val`` holds ±1 inlier/outlier
-    labels scored by balanced accuracy.  Per h: one compression + one
-    factorization for the whole warm-started ν sweep.
-    """
-    from repro.core.engine import HSSSVMEngine
-    from repro.core.kernelfn import KernelSpec
-    from repro.core.svm import resolve_rtol, run_grid_search
-
-    kw = resolve_rtol(trainer_kwargs, rtol)
-    return run_grid_search(
-        lambda h: HSSSVMEngine(spec=KernelSpec(h=h), task="oneclass", **kw),
-        x, None, x_val, y_val, hs, nus, score_fn=oneclass_score)
+# The holdout rule ``engine.grid_search`` scores each task by.  "gp" has no
+# entry: it is scored by the training log marginal, with no validation split.
+HOLDOUT_SCORES = {
+    "svm": accuracy_score,
+    "svr": neg_rmse_score,
+    "krr": neg_rmse_score,
+    "oneclass": oneclass_score,
+}

@@ -22,7 +22,7 @@ The tree is built once per dataset on the host (numpy); everything after is
 JAX.  Datasets whose size is not ``leaf_size * 2**levels`` are padded with
 *inert* far-away points (see ``pad_dataset``): their kernel rows are ~0, the
 SVM box constraint pins their dual variables to 0, so the padded problem's
-solution restricted to real points equals the original one (core/svm.py).
+solution restricted to real points equals the original one (core/engine.py).
 """
 from __future__ import annotations
 

@@ -116,7 +116,7 @@ def batched_scores(xq: Array, xs: Array, zy: Array, biases: Array, *,
 # --------------------------------------------------------------------- #
 def _ovo_vote_np(scores: np.ndarray, pairs: np.ndarray, n_classes: int
                  ) -> np.ndarray:
-    """Numpy twin of ``multiclass.ovo_vote`` (same tie-break, host-side).
+    """Numpy twin of ``tasks.ovo_vote`` (same tie-break, host-side).
 
     The per-class scatter-adds are expressed as matmuls against fixed
     (P, k) incidence matrices — ``np.add.at`` is an order of magnitude

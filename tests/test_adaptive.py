@@ -3,8 +3,8 @@
 Fast tier: rank detection flows through compress -> HSSMatrix rank vectors,
 masked arrays are structurally consistent (dead slots exactly zero), the
 shrink-to-fit pass is EXACT (masked/shrunk-vs-full matmat and solve parity),
-the mask-aware factorization solves the same system, and the engine /
-trainers plumb rtol end-to-end with rank reporting.
+the mask-aware factorization solves the same system, and the engine and
+the grid search plumb rtol end-to-end with rank reporting.
 
 Slow tier (8 emulated devices, subprocess like tests/test_engine.py): the
 sharded adaptive build detects the same ranks as the local build, stays
@@ -22,10 +22,9 @@ import numpy as np
 import pytest
 
 from repro.core import compression, factorization, tree as tree_mod
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.hss import shrink_to_fit
 from repro.core.kernelfn import KernelSpec, gaussian_block_xla
-from repro.core.svm import HSSSVMTrainer, grid_search
 from repro.data import synthetic
 
 
@@ -185,13 +184,13 @@ def test_engine_adaptive_matches_fixed_accuracy_with_smaller_ranks():
 def test_trainer_adaptive_prepare_shrinks():
     xtr, ytr, _, _ = synthetic.train_test(
         "blobs", 1024, 256, seed=0, n_features=2, sep=2.5)
-    tr = HSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=2.0),
         comp=compression.CompressionParams.accurate(), leaf_size=128,
         max_it=5)
-    rep = tr.prepare(xtr, ytr)
+    rep = engine.prepare(xtr, ytr)
     assert rep.rank_sum_post < rep.rank_sum_pre
-    model, _ = tr.train(1.0)
+    model, _ = engine.train(1.0)
     acc = float(jnp.mean(model.predict(jnp.asarray(xtr)) == ytr))
     assert acc > 0.9, acc
 
@@ -201,7 +200,7 @@ def test_grid_search_rtol_plumbing():
     xtr, ytr, xte, yte = synthetic.train_test(
         "blobs", 512, 128, seed=1, n_features=2, sep=2.5)
     model, info = grid_search(
-        xtr, ytr, xte, yte, hs=[2.0], cs=[1.0],
+        xtr, ytr, xte, yte, hs=[2.0], knobs=[1.0],
         trainer_kwargs=dict(leaf_size=64, max_it=5,
                             comp=compression.CompressionParams(rank=32)),
         rtol=1e-2)
@@ -210,18 +209,16 @@ def test_grid_search_rtol_plumbing():
 
 
 def test_multiclass_adaptive_shared_factorization():
-    from repro.core.multiclass import MulticlassHSSSVMTrainer
-
     xtr, ytr, xte, yte = synthetic.train_test(
         "multiclass_blobs", 1024, 256, seed=0, n_classes=3, n_features=2,
         sep=4.0)
-    tr = MulticlassHSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=2.0),
         comp=compression.CompressionParams(rank=48, n_near=48, n_far=64,
                                            rtol=1e-4),
         leaf_size=128, max_it=10)
-    model = tr.fit(xtr, ytr, c_value=1.0)
-    assert tr.report.rank_sum_post < tr.report.rank_sum_pre
+    model = engine.fit(xtr, ytr, c_value=1.0)
+    assert engine.report.rank_sum_post < engine.report.rank_sum_pre
     acc = float(jnp.mean(model.predict(jnp.asarray(xte))
                          == jnp.asarray(yte)))
     assert acc > 0.9, acc

@@ -170,8 +170,9 @@ def test_distributed_svm_solve_matches_local():
 
 @pytest.mark.slow
 def test_distributed_admm_c_grid_matches_single_device():
-    """admm_train_distributed on 8 host devices == the 1-device mesh, per C,
-    including the warm-start chaining across the grid."""
+    """The engine's warm-started C-grid on an 8-device mesh == the same
+    engine on a 1-device mesh, per C, including the warm-start chaining
+    across the grid."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -179,30 +180,33 @@ def test_distributed_admm_c_grid_matches_single_device():
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.mesh import make_mesh
-        from repro.core import compression, factorization, tree as tree_mod
-        from repro.core.distributed import admm_train_distributed
+        from repro.core import compression
+        from repro.core.engine import HSSSVMEngine
         from repro.core.kernelfn import KernelSpec
         from repro.data import synthetic
 
         n = 1024
         x, y = synthetic.blobs(n, n_features=4, sep=1.6, seed=0)
-        t = tree_mod.build_tree(x, leaf_size=64)
-        xp = jnp.asarray(x[t.perm])
-        yp = jnp.asarray(y[t.perm])
-        hss = compression.compress(
-            xp, t, KernelSpec(h=1.0),
-            compression.CompressionParams(rank=24, n_near=32, n_far=48))
-        fac = factorization.factorize(hss, beta=100.0)
-
         c_grid = [0.5, 1.0, 2.0]
-        res1 = admm_train_distributed(
-            fac, yp, c_grid, make_mesh((1,), ("data",)), max_it=10)
-        res8 = admm_train_distributed(
-            fac, yp, c_grid, make_mesh((8,), ("data",)), max_it=10)
+
+        def c_grid_duals(mesh):
+            eng = HSSSVMEngine(
+                spec=KernelSpec(h=1.0),
+                comp=compression.CompressionParams(rank=24, n_near=32,
+                                                   n_far=48),
+                leaf_size=64, beta=100.0, max_it=10, mesh=mesh)
+            eng.prepare(x, y)
+            out, warm = [], None
+            for c in c_grid:
+                _, warm = eng.train(c, warm=warm)
+                out.append(np.asarray(warm[0]))
+            return out
+
+        res1 = c_grid_duals(make_mesh((1,), ("data",)))
+        res8 = c_grid_duals(make_mesh((8,), ("data",)))
         for i in range(len(c_grid)):
             np.testing.assert_allclose(
-                np.asarray(res8[i][0]), np.asarray(res1[i][0]),
-                rtol=1e-4, atol=1e-5)
+                res8[i], res1[i], rtol=1e-4, atol=1e-5)
         print("ADMM_GRID_OK")
     """)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -1,7 +1,8 @@
 """HSSSVMEngine: one code path for local and mesh-parallel training.
 
-Fast tier: the local engine must reproduce the per-subsystem trainers
-(binary + multiclass) and auto-detect the problem type.
+Fast tier: the local engine must agree with the dense exact-kernel ADMM
+reference (binary + one-vs-rest multiclass) and auto-detect the problem
+type.
 
 Slow tier (8 emulated devices, subprocess like tests/test_dist.py): the
 mesh-parallel build — compress_sharded / factorize_sharded — must match the
@@ -21,13 +22,13 @@ import numpy as np
 import pytest
 
 from repro.core import admm as admm_mod
+from repro.core import baselines
 from repro.core import engine as engine_mod
 from repro.core import tasks as tasks_mod
 from repro.core.compression import CompressionParams
 from repro.core.engine import HSSSVMEngine
-from repro.core.kernelfn import KernelSpec
-from repro.core.multiclass import MulticlassHSSSVMTrainer
-from repro.core.svm import HSSSVMTrainer, compute_bias_batched
+from repro.core.kernelfn import KernelSpec, kernel_block
+from repro.core.tasks import compute_bias_batched
 from repro.data import synthetic
 from repro.launch.mesh import make_mesh
 
@@ -41,38 +42,53 @@ def _run_sub(code: str) -> subprocess.CompletedProcess:
 
 
 # --------------------------------------------------------------------- #
-# fast tier: local engine vs the per-subsystem trainers                 #
+# fast tier: local engine vs the dense exact-kernel reference          #
 # --------------------------------------------------------------------- #
 def test_engine_local_binary_matches_trainer():
+    """Predictions agree with the dense exact-kernel ADMM (same β, C and
+    MaxIt); the bias is paper eq. (7) evaluated densely on K̃ with the
+    engine's own duals."""
     xtr, ytr, xte, yte = synthetic.train_test("blobs", 1024, 256, seed=0,
                                               sep=1.6)
-    kw = dict(spec=KernelSpec(h=1.0), comp=COMP, leaf_size=64, max_it=10)
-    trainer = HSSSVMTrainer(**kw)
-    ref_model = trainer.fit(xtr, ytr, c_value=1.0)
-    engine = HSSSVMEngine(**kw)
-    model = engine.fit(xtr, ytr, c_value=1.0)
+    spec = KernelSpec(h=1.0)
+    engine = HSSSVMEngine(spec=spec, comp=COMP, leaf_size=64, max_it=10)
+    engine.prepare(xtr, ytr)
+    model, (z, _) = engine.train(1.0)
     assert model.binary
     assert engine.n_problems == 1
-    pred_ref = np.asarray(ref_model.predict(jnp.asarray(xte)))
+    x, y = jnp.asarray(xtr), jnp.asarray(ytr)
+    z_ref, b_ref = baselines.dense_admm_fit(x, y, spec, c_value=1.0,
+                                            beta=engine.fac.beta, max_it=10)
+    pred_ref = np.asarray(baselines.dense_predict(
+        x, y, z_ref, b_ref, spec, jnp.asarray(xte)))
     pred = np.asarray(model.predict(jnp.asarray(xte)))
-    # identical pipeline (same compression, factorization, ADMM): identical
-    # predictions, not merely similar accuracy
     assert (pred == pred_ref).mean() > 0.99, (pred != pred_ref).sum()
-    np.testing.assert_allclose(float(model.biases[0]), float(ref_model.bias),
+    bias_ref = baselines._dense_bias(engine.hss.todense(),
+                                     engine.problem_labels[0], z[:, 0], 1.0)
+    np.testing.assert_allclose(float(model.biases[0]), float(bias_ref),
                                rtol=1e-4, atol=1e-5)
 
 
 def test_engine_local_multiclass_matches_trainer():
     xtr, ytr, xte, yte = synthetic.train_test(
         "multiclass_blobs", 1024, 256, seed=0, n_classes=4, sep=3.0)
-    kw = dict(spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=10)
-    ref = MulticlassHSSSVMTrainer(**kw).fit(xtr, ytr, c_value=1.0)
-    engine = HSSSVMEngine(**kw)
+    spec = KernelSpec(h=1.5)
+    engine = HSSSVMEngine(spec=spec, comp=COMP, leaf_size=64, max_it=10)
     model = engine.fit(xtr, ytr, c_value=1.0)
     assert not model.binary
     assert engine.n_problems == 4
-    pred_ref = np.asarray(ref.predict(jnp.asarray(xte)))
-    pred = np.asarray(model.predict(jnp.asarray(xte)))
+    # one-vs-rest reference: argmax of per-class dense exact-kernel columns
+    x, xt = jnp.asarray(xtr), jnp.asarray(xte)
+    k_test = kernel_block(spec, xt, x)
+    classes = np.unique(ytr)
+    cols = []
+    for c in classes:
+        y = jnp.asarray(np.where(ytr == c, 1.0, -1.0), jnp.float32)
+        z, b = baselines.dense_admm_fit(x, y, spec, c_value=1.0,
+                                        beta=engine.fac.beta, max_it=10)
+        cols.append(np.asarray(k_test @ (y * z) + b))
+    pred_ref = classes[np.argmax(np.stack(cols, axis=1), axis=1)]
+    pred = np.asarray(model.predict(xt))
     assert (pred == pred_ref).mean() > 0.99
 
 
@@ -464,7 +480,7 @@ def test_engine_end_to_end_1_vs_8_devices():
 @pytest.mark.slow
 def test_engine_multiclass_8_devices():
     """k-class engine under the mesh: sharded (d, P) iterates, accuracy
-    matching the local multiclass trainer."""
+    matching the local (meshless) engine."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -475,7 +491,6 @@ def test_engine_multiclass_8_devices():
         from repro.core.compression import CompressionParams
         from repro.core.engine import HSSSVMEngine
         from repro.core.kernelfn import KernelSpec
-        from repro.core.multiclass import MulticlassHSSSVMTrainer
         from repro.data import synthetic
 
         xtr, ytr, xte, yte = synthetic.train_test(
@@ -483,7 +498,7 @@ def test_engine_multiclass_8_devices():
         kw = dict(spec=KernelSpec(h=1.5),
                   comp=CompressionParams(rank=24, n_near=32, n_far=48),
                   leaf_size=64, max_it=10)
-        ref = MulticlassHSSSVMTrainer(**kw).fit(xtr, ytr, c_value=1.0)
+        ref = HSSSVMEngine(**kw).fit(xtr, ytr, c_value=1.0)
         acc_ref = float(jnp.mean(ref.predict(jnp.asarray(xte))
                                  == jnp.asarray(yte)))
         mesh = make_mesh((8,), ("data",))

@@ -146,11 +146,11 @@ def test_bf16_inputs_bias_extraction_accuracy():
     """compute_bias_batched keeps f32 accumulation when its inputs arrive
     bf16: the bias einsums pin preferred_element_type=float32, so the only
     error vs the f32 path is INPUT rounding (~1e-2), never the ~1e-1 drift
-    of a bf16 accumulator over d≈1000 terms.  Pins the core/svm.py fix;
+    of a bf16 accumulator over d≈1000 terms.  Pins the core/tasks.py rule;
     the jaxpr assertion proves no contraction anywhere in the bias graph
     accumulates below f32."""
     from repro.analysis import jaxpr_check
-    from repro.core.svm import compute_bias_batched
+    from repro.core.tasks import compute_bias_batched
 
     hss = _hss(n=1024, leaf=64, rank=24)
     d = hss.n

@@ -22,8 +22,6 @@ from repro.core import admm as admm_mod
 from repro.core.compression import CompressionParams
 from repro.core.engine import HSSSVMEngine
 from repro.core.kernelfn import KernelSpec
-from repro.core.multiclass import MulticlassHSSSVMTrainer
-from repro.core.svm import HSSSVMTrainer
 from repro.data import synthetic
 
 COMP = CompressionParams(rank=32, n_near=48, n_far=64)
@@ -32,14 +30,15 @@ COMP = CompressionParams(rank=32, n_near=48, n_far=64)
 def test_golden_binary_accuracy_and_residual_decay():
     xtr, ytr, xte, yte = synthetic.train_test("blobs", 1024, 256, seed=0,
                                               sep=1.6)
-    trainer = HSSSVMTrainer(spec=KernelSpec(h=1.0), comp=COMP,
-                            leaf_size=128, max_it=10)
-    trainer.prepare(xtr, ytr)
-    model, _ = trainer.train(1.0)
+    engine = HSSSVMEngine(spec=KernelSpec(h=1.0), comp=COMP,
+                          leaf_size=128, max_it=10)
+    engine.prepare(xtr, ytr)
+    model, _ = engine.train(1.0)
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == yte))
     assert acc >= 0.93, acc                       # measured 0.9531
 
-    fac, y, mask = trainer._fac, trainer._y, trainer._cmask
+    fac = engine.fac
+    y, mask = engine.problem_labels[0], engine.problem_masks[0]
     _, trace = admm_mod.admm_svm(fac.solve, y, 1.0 * mask, fac.beta, max_it=10)
     primal = np.asarray(trace.primal_res)
     dual = np.asarray(trace.dual_res)
@@ -54,14 +53,15 @@ def test_golden_binary_accuracy_and_residual_decay():
 def test_golden_multiclass_accuracy_and_residual_decay():
     xtr, ytr, xte, yte = synthetic.train_test(
         "multiclass_blobs", 1024, 256, seed=0, n_classes=4, sep=3.0)
-    trainer = MulticlassHSSSVMTrainer(spec=KernelSpec(h=1.5), comp=COMP,
-                                      leaf_size=128, max_it=10)
-    trainer.prepare(xtr, ytr)
-    model, _ = trainer.train(1.0)
+    engine = HSSSVMEngine(spec=KernelSpec(h=1.5), comp=COMP,
+                          leaf_size=128, max_it=10)
+    engine.prepare(xtr, ytr)
+    model, _ = engine.train(1.0)
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == jnp.asarray(yte)))
     assert acc >= 0.92, acc                       # measured 0.9492
 
-    fac, ys, pmask = trainer._fac, trainer._ys, trainer._pmask
+    fac, ys, pmask = (engine.fac, engine.problem_labels,
+                      engine.problem_masks)
     _, trace = admm_mod.admm_svm_batched(
         fac.solve_mat, ys, 1.0 * pmask, fac.beta, max_it=10)
     primal = np.asarray(trace.primal_res)         # (10, 4)

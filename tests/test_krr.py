@@ -23,7 +23,7 @@ import pytest
 
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core.compression import CompressionParams
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec, kernel_matvec_streamed
 from repro.core.krr import gp_log_marginal, krr_solve
 from repro.core.lanczos import lanczos, top_eigenpairs, tridiag_eigh
@@ -124,6 +124,40 @@ def test_gp_evidence_ranks_true_noise_first():
         engine.train(lam)
         lmls[lam] = engine.log_marginal(lam, n_probes=4, num_iters=25)
     assert lmls[0.01] > lmls[1.0], lmls
+
+
+@pytest.mark.parametrize("task", ["krr", "gp"])
+def test_grid_search_scores_each_regression_task(monkeypatch, task):
+    """The one grid driver: one compression per h for the whole λ sweep;
+    KRR scored by negated holdout RMSE, GP by the training log marginal
+    with no validation split (λ near the noise variance 0.01 wins)."""
+    calls = {"compress": 0}
+    real_compress = compression.compress
+
+    def counting_compress(*a, **k):
+        calls["compress"] += 1
+        return real_compress(*a, **k)
+
+    monkeypatch.setattr(compression, "compress", counting_compress)
+    xtr, ytr, xte, yte = synthetic.train_test("noisy_sine", 512, 128,
+                                              seed=0, noise=0.1)
+    gp = task == "gp"
+    model, info = grid_search(
+        xtr, ytr, None if gp else xte, None if gp else yte, hs=[1.0],
+        knobs=[0.01, 1.0], task=task,
+        trainer_kwargs=dict(comp=COMP_ACC, leaf_size=64))
+    assert calls["compress"] == 1, calls
+    assert set(info["results"]) == {(1.0, 0.01), (1.0, 1.0)}
+    assert info["best_h"] == 1.0
+    if gp:
+        assert info["best_lam"] == 0.01, info["results"]
+        assert info["best_log_marginal"] == max(
+            r["log_marginal"] for r in info["results"].values())
+    else:
+        pred = np.asarray(model.predict(jnp.asarray(xte)))
+        rmse = float(np.sqrt(np.mean((pred - yte) ** 2)))
+        assert -info["best_accuracy"] == pytest.approx(rmse, rel=1e-6)
+        assert rmse < 0.15, rmse
 
 
 # --------------------------------------------------------------------- #

@@ -4,15 +4,14 @@ The load-bearing assertion (ISSUE acceptance): ONE HSS compression and ONE
 factorization per (h, beta) serve ALL k class subproblems AND the whole C
 grid — verified by call counting, plus batched-vs-sequential equivalence.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import admm as admm_mod
 from repro.core import compression, factorization
-from repro.core import multiclass as mc
 from repro.core.compression import CompressionParams
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec
 from repro.data import synthetic
 from repro.launch.mesh import make_mesh
@@ -30,11 +29,11 @@ def blobs4():
 @pytest.fixture(scope="module")
 def trained4(blobs4):
     xtr, ytr, _, _ = blobs4
-    trainer = mc.MulticlassHSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=10)
-    trainer.prepare(xtr, ytr)
-    model, warm = trainer.train(1.0)
-    return trainer, model, warm
+    engine.prepare(xtr, ytr)
+    model, warm = engine.train(1.0)
+    return engine, model, warm
 
 
 def test_one_compression_one_factorization_serve_all_classes_and_c_grid(
@@ -54,12 +53,12 @@ def test_one_compression_one_factorization_serve_all_classes_and_c_grid(
     monkeypatch.setattr(compression, "compress", counting_compress)
     monkeypatch.setattr(factorization, "factorize", counting_factorize)
 
-    trainer = mc.MulticlassHSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=10)
-    trainer.prepare(xtr, ytr)
+    engine.prepare(xtr, ytr)
     warm = None
     for c in (0.5, 1.0, 2.0):                    # C grid x 4 classes = 12 runs
-        model, warm = trainer.train(c, warm=warm)
+        model, warm = engine.train(c, warm=warm)
     assert calls["compress"] == 1, calls
     assert calls["factorize"] == 1, calls
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == jnp.asarray(yte)))
@@ -68,21 +67,21 @@ def test_one_compression_one_factorization_serve_all_classes_and_c_grid(
 
 def test_multiclass_accuracy_and_shapes(blobs4, trained4):
     xtr, ytr, xte, yte = blobs4
-    trainer, model, warm = trained4
-    assert trainer.n_problems == 4
+    engine, model, warm = trained4
+    assert engine.n_problems == 4
     assert model.z_y.shape[1] == 4 and model.biases.shape == (4,)
     scores = model.decision_function(jnp.asarray(xte))
     assert scores.shape == (xte.shape[0], 4)
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == jnp.asarray(yte)))
     assert acc > 0.9, acc
     # warm-start state has one column per class
-    assert warm[0].shape == warm[1].shape == (trainer._ys.shape[1], 4)
+    assert warm[0].shape == warm[1].shape == (engine.problem_labels.shape[1], 4)
 
 
 def test_batched_admm_matches_sequential_per_class(trained4):
     """The (d, k)-block iteration must equal k independent binary runs."""
-    trainer, _, _ = trained4
-    fac, ys, pmask = trainer._fac, trainer._ys, trainer._pmask
+    engine, _, _ = trained4
+    fac, ys, pmask = engine.fac, engine.problem_labels, engine.problem_masks
     state_b, trace_b = admm_mod.admm_svm_batched(
         fac.solve_mat, ys, 1.0 * pmask, fac.beta, max_it=10)
     for i in range(ys.shape[0]):
@@ -97,12 +96,12 @@ def test_batched_admm_matches_sequential_per_class(trained4):
 
 
 def test_pads_carry_zero_weight(trained4):
-    trainer, model, _ = trained4
+    engine, model, _ = trained4
     n_pad = model.z_y.shape[0] - 1000
     assert n_pad > 0
     # padded coordinates sit at the end in pre-permutation order; in permuted
     # order find them via the participation mask instead
-    dead = np.asarray(trainer._pmask[0]) == 0
+    dead = np.asarray(engine.problem_masks[0]) == 0
     assert dead.sum() == n_pad
     np.testing.assert_array_equal(np.asarray(model.z_y)[dead], 0.0)
 
@@ -110,17 +109,17 @@ def test_pads_carry_zero_weight(trained4):
 def test_one_vs_one_pairs_and_accuracy():
     xtr, ytr, xte, yte = synthetic.train_test(
         "multiclass_blobs", 512, 128, seed=1, n_classes=3, sep=3.0)
-    trainer = mc.MulticlassHSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=10,
         strategy="ovo")
-    trainer.prepare(xtr, ytr)
-    assert trainer.n_problems == 3          # 3*(3-1)/2 pairs
-    model, _ = trainer.train(1.0)
+    engine.prepare(xtr, ytr)
+    assert engine.n_problems == 3          # 3*(3-1)/2 pairs
+    model, _ = engine.train(1.0)
     assert model.pairs.shape == (3, 2)
     # points outside a pair are pinned to the [0, 0] box -> zero coefficient
     z_y = np.asarray(model.z_y)
     for p in range(3):
-        outsiders = np.asarray(trainer._pmask[p]) == 0
+        outsiders = np.asarray(engine.problem_masks[p]) == 0
         np.testing.assert_array_equal(z_y[outsiders, p], 0.0)
     acc = float(jnp.mean(model.predict(jnp.asarray(xte)) == jnp.asarray(yte)))
     assert acc > 0.9, acc
@@ -130,9 +129,9 @@ def test_predict_returns_original_label_values():
     xtr, ytr, xte, yte = synthetic.train_test(
         "multiclass_blobs", 512, 128, seed=2, n_classes=3, sep=3.5)
     ytr2, yte2 = ytr * 3 + 5, yte * 3 + 5       # labels {5, 8, 11}
-    trainer = mc.MulticlassHSSSVMTrainer(
+    engine = HSSSVMEngine(
         spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=10)
-    model = trainer.fit(xtr, ytr2, c_value=1.0)
+    model = engine.fit(xtr, ytr2, c_value=1.0)
     pred = np.asarray(model.predict(jnp.asarray(xte)))
     assert set(np.unique(pred)) <= {5, 8, 11}
     assert float(np.mean(pred == yte2)) > 0.85
@@ -141,8 +140,8 @@ def test_predict_returns_original_label_values():
 def test_grid_search_multiclass_shares_compression():
     xtr, ytr, xte, yte = synthetic.train_test(
         "spirals", 1024, 256, seed=0, n_classes=3)
-    model, info = mc.grid_search_multiclass(
-        xtr, ytr, xte, yte, hs=[0.2], cs=[0.5, 2.0, 8.0],
+    model, info = grid_search(
+        xtr, ytr, xte, yte, hs=[0.2], knobs=[0.5, 2.0, 8.0],
         trainer_kwargs=dict(comp=COMP, leaf_size=64, max_it=10))
     assert len(info["results"]) == 3
     assert info["best_accuracy"] > 0.85
@@ -151,22 +150,23 @@ def test_grid_search_multiclass_shares_compression():
     assert model.n_classes == 3
 
 
-def test_multiclass_distributed_matches_local(trained4):
-    """Data-parallel batched C-grid == local batched run (1-device mesh)."""
-    from repro.core.distributed import admm_train_multiclass_distributed
-
-    trainer, _, _ = trained4
-    fac, ys, pmask = trainer._fac, trainer._ys, trainer._pmask
-    mesh = make_mesh((jax.device_count(),), ("data",))
-    out = admm_train_multiclass_distributed(
-        fac, ys, [0.5, 1.0], mesh, max_it=8, pmask=pmask)
-    st1, _ = admm_mod.admm_svm_batched(
-        fac.solve_mat, ys, 0.5 * pmask, fac.beta, max_it=8)
-    st2, _ = admm_mod.admm_svm_batched(
-        fac.solve_mat, ys, 1.0 * pmask, fac.beta, max_it=8,
-        z0=st1.z, mu0=st1.mu)
+def test_multiclass_distributed_matches_local(blobs4):
+    """The engine on a 1-device mesh (sharded build, mesh-placed labels and
+    iterates) trains the same warm-started C-grid as the engine with no
+    mesh."""
+    xtr, ytr, _, _ = blobs4
+    kw = dict(spec=KernelSpec(h=1.5), comp=COMP, leaf_size=64, max_it=8)
+    local = HSSSVMEngine(**kw)
+    local.prepare(xtr, ytr)
+    meshed = HSSSVMEngine(mesh=make_mesh((1,), ("data",)), **kw)
+    meshed.prepare(xtr, ytr)
+    warm_local = warm_mesh = None
+    for c in (0.5, 1.0):
+        _, warm_local = local.train(c, warm=warm_local)
+        _, warm_mesh = meshed.train(c, warm=warm_mesh)
     np.testing.assert_allclose(
-        np.asarray(out[-1][0]), np.asarray(st2.z), rtol=2e-4, atol=2e-5)
+        np.asarray(warm_mesh[0]), np.asarray(warm_local[0]), rtol=2e-4,
+        atol=2e-5)
 
 
 @pytest.mark.parametrize("rows,seed", [(2048, (15, 1)), (4096, (15, 2))])

@@ -255,9 +255,9 @@ def test_threaded_driver_resolves_without_manual_flush():
 # decode details                                                         #
 # --------------------------------------------------------------------- #
 def test_ovo_host_decode_matches_device_vote():
-    """The tick's numpy OVO decode must replicate multiclass.ovo_vote's
+    """The tick's numpy OVO decode must replicate tasks.ovo_vote's
     tie-break (votes + 1e-3·tanh(margin)) exactly."""
-    from repro.core.multiclass import ovo_vote
+    from repro.core.tasks import ovo_vote
     from repro.serve.engine import _ovo_vote_np
 
     r = np.random.default_rng(9)
@@ -278,13 +278,8 @@ def test_block_kwarg_is_one_shared_constant():
     import inspect
 
     from repro.core.kernelfn import kernel_matvec_streamed
-    from repro.core.multiclass import MulticlassSVMModel
-    from repro.core.svm import SVMModel
 
-    for fn in (SVMModel.predict, SVMModel.decision_function,
-               MulticlassSVMModel.predict,
-               MulticlassSVMModel.decision_function,
-               EngineModel.predict, EngineModel.decision_function):
+    for fn in (EngineModel.predict, EngineModel.decision_function):
         assert inspect.signature(fn).parameters["block"].default \
             == DEFAULT_SCORE_BLOCK, fn
     assert inspect.signature(kernel_matvec_streamed).parameters[

@@ -26,7 +26,7 @@ from repro.core import admm as admm_mod
 from repro.core import compression, factorization, tree as tree_mod
 from repro.core import tasks as tasks_mod
 from repro.core.compression import CompressionParams
-from repro.core.engine import HSSSVMEngine
+from repro.core.engine import HSSSVMEngine, grid_search
 from repro.core.kernelfn import KernelSpec, gaussian_block_xla
 from repro.data import synthetic
 from tests import proptest as pt
@@ -252,9 +252,9 @@ def test_oneclass_one_compression_one_factorization_per_h(monkeypatch):
 def test_grid_search_svr_shares_compression():
     xtr, ytr, xte, yte = synthetic.train_test("noisy_sine", 1024, 256,
                                               seed=0, noise=0.1)
-    model, info = tasks_mod.grid_search_svr(
-        xtr, ytr, xte, yte, hs=[1.0], epsilons=[0.05, 0.1, 0.3],
-        c_value=2.0, trainer_kwargs=dict(comp=COMP, leaf_size=64, max_it=10))
+    model, info = grid_search(
+        xtr, ytr, xte, yte, hs=[1.0], knobs=[0.05, 0.1, 0.3], task="svr",
+        trainer_kwargs=dict(svr_c=2.0, comp=COMP, leaf_size=64, max_it=10))
     assert len(info["results"]) == 3
     assert -info["best_accuracy"] < 0.2     # scores are negated RMSE
     comp_times = {v["compression_s"] for v in info["results"].values()}
@@ -268,8 +268,9 @@ def test_grid_search_oneclass_shares_compression():
                                            outlier_frac=0.1, seed=0)
     xval, yval = synthetic.blobs_with_outliers(512, n_features=4,
                                                outlier_frac=0.1, seed=2)
-    model, info = tasks_mod.grid_search_oneclass(
-        xtr, xval, yval, hs=[2.0], nus=[0.05, 0.1, 0.2],
+    model, info = grid_search(
+        xtr, None, xval, yval, hs=[2.0], knobs=[0.05, 0.1, 0.2],
+        task="oneclass",
         trainer_kwargs=dict(comp=COMP, leaf_size=64, max_it=30))
     assert len(info["results"]) == 3
     assert info["best_accuracy"] > 0.8
